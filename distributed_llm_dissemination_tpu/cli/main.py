@@ -35,6 +35,7 @@ from ..runtime import (
     RetransmitReceiverNode,
 )
 from ..transport import TcpTransport
+from ..utils import env as env_util
 from ..utils import logging as ulog
 
 
@@ -782,9 +783,6 @@ def build_placement(args, conf: cfg.Config):
         return None
     import jax as _jax
 
-    from ..parallel.multihost import honor_jax_platforms
-
-    honor_jax_platforms()
     from ..parallel.mesh import assignment_to_placement, mesh_from_conf
     from ..parallel.multihost import host_aligned_device_order
 
@@ -830,13 +828,9 @@ def build_spmd_fabric(args, conf: cfg.Config):
     if conf.mesh is None or not conf.mesh.fabric:
         return None, None
     from ..parallel.mesh import fabric_placement, mesh_from_conf
-    from ..parallel.multihost import (
-        honor_jax_platforms,
-        host_aligned_device_order,
-    )
+    from ..parallel.multihost import host_aligned_device_order
     from ..parallel.spmd_fabric import SpmdFabric
 
-    honor_jax_platforms()
     mesh = mesh_from_conf(
         conf.mesh, host_aligned_device_order(conf, conf.assignment)
     )
@@ -853,6 +847,20 @@ def build_spmd_fabric(args, conf: cfg.Config):
         stages={str(n): s for n, s in placement.node_to_stage.items()},
     )
     return fabric, placement
+
+
+def device_path_degradations() -> dict:
+    """Every fallback off the device path this process took, by site
+    (the ``device.degraded.*`` counters).  "Delivery beats staging" stays
+    the runtime's behaviour — a layer whose device landing failed is
+    still acked from host RAM, a boot whose streamed assembly failed
+    still boots — but a process that was ASKED for ``-hbm`` must not end
+    as a clean run when any of them fired: ``run_receiver`` exits
+    non-zero on a non-empty answer."""
+    from ..utils import trace
+
+    return {k: v for k, v in trace.counter_totals().items()
+            if k.startswith("device.degraded.")}
 
 
 def run_receiver(args, conf: cfg.Config, node: Node, layers) -> int:
@@ -1043,13 +1051,41 @@ def run_receiver(args, conf: cfg.Config, node: Node, layers) -> int:
                       window_s=args.daemon)
         print(f"daemon: serving jobs for {args.daemon:g}s", flush=True)
         time.sleep(args.daemon)
+    if args.hbm:
+        ulog.log.info("final layer placement",
+                      layers=receiver.layer_placement())
+        degraded = device_path_degradations()
+        if degraded:
+            ulog.log.error("device path degraded under -hbm; exiting "
+                           "non-zero", **degraded)
+            return 1
     return 0
+
+
+def holds_device(args, conf: cfg.Config, node_conf) -> bool:
+    """Whether this process's role reaches the device plane: it joins a
+    fabric (or the pod-wide JAX runtime), stages into HBM, boots the
+    model, or gathers pod shards on the mesh.  Everything else — the
+    leader of a TCP topology, a ``-boot none`` seeder, ``-l`` — only
+    ever SEEDS with JAX and must leave the chip to the process that
+    needs it (``utils.env.pin_jax_to_cpu``)."""
+    if args.l:
+        return False
+    if conf.distributed is not None or (conf.mesh is not None
+                                        and conf.mesh.fabric):
+        return True
+    if node_conf.is_leader:
+        return False
+    return (args.hbm or conf.pods is not None
+            or (args.boot or conf.model or "none") != "none")
 
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     ulog.configure(node=str(args.id), verbose=args.v)
     conf = cfg.read_json(args.f)
+    # Before anything can import jax: where compiled programs persist.
+    env_util.place_compile_cache()
 
     if args.submit or args.jobs:
         # One-shot service tools: no fabrication, no role loop — talk
@@ -1072,6 +1108,10 @@ def main(argv=None) -> int:
 
     if args.c:
         return run_client(args, conf)
+
+    node_conf = cfg.get_node_conf(conf, args.id)
+    if not holds_device(args, conf, node_conf):
+        env_util.pin_jax_to_cpu()
 
     if (conf.mesh is not None and conf.mesh.fabric
             and conf.distributed is None):
@@ -1097,12 +1137,10 @@ def main(argv=None) -> int:
         # configured Mesh can span hosts.  Gated on the config section so
         # pure-TCP nodes never pay the jax import; external clients never
         # join (they are auxiliary byte servers, not mesh ranks).
-        from ..parallel.multihost import honor_jax_platforms, maybe_initialize
+        from ..parallel.multihost import maybe_initialize
 
-        honor_jax_platforms()
         maybe_initialize(conf, args.id)
 
-    node_conf = cfg.get_node_conf(conf, args.id)
     if (args.m == 3 and node_conf.is_leader and conf.mesh is not None
             and conf.mesh.topology() is not None):
         # Adversarial-holdings topology solves need the exact LP; its
@@ -1166,6 +1204,16 @@ def main(argv=None) -> int:
         ulog.log.warn("TEST fault injection armed", spec=fault_spec)
     try:
         layers = fabricate()
+        if "jax" in sys.modules:
+            # Which backend this process took (seeding a Model section
+            # initialises one): "one process per chip" is checkable
+            # from the logs.  Model-less TCP nodes stay jax-free.
+            import jax
+
+            devs = jax.devices()
+            ulog.log.info("jax backend", platform=devs[0].platform,
+                          device_kind=devs[0].device_kind,
+                          devices=len(devs))
         # Hierarchical control (docs/hierarchy.md): a grouped member's
         # control parent is its SUB-LEADER — announces, acks,
         # heartbeats, and metric reports all fold there; the root only

@@ -39,6 +39,7 @@ from ..runtime import (
     RetransmitReceiverNode,
 )
 from ..transport.inmem import InmemTransport
+from ..utils import env as env_util
 from ..utils import logging as ulog
 
 _LEADERS = {
@@ -96,9 +97,6 @@ def run_pod(conf: cfg.Config, mode: int = 3, boot: str = "",
     configured node (seeders contribute from their own stages)."""
     if conf.mesh is None:
         raise SystemExit("podrun needs a Mesh section in the config")
-    from ..parallel.multihost import honor_jax_platforms
-
-    honor_jax_platforms()
     from ..parallel.fabric import FabricPlane
     from ..parallel.mesh import fabric_placement, mesh_from_conf
 
@@ -241,6 +239,8 @@ def run_pod(conf: cfg.Config, mode: int = 3, boot: str = "",
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     ulog.configure(node="pod", verbose=args.v)
+    # Before anything can import jax: where compiled programs persist.
+    env_util.place_compile_cache()
     conf = cfg.read_json(args.f)
     run_pod(conf, mode=args.m, boot=args.boot, gen=max(0, args.gen),
             report=args.report)

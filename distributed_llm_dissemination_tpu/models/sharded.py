@@ -34,7 +34,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..parallel.compat import axis_size, shard_map
 from ..parallel.mesh import make_mesh
 from ..parallel.ring_attention import ring_attention
 from .llama import ModelConfig, rms_norm, rope, route_topk
@@ -230,10 +229,10 @@ def _local_loss(cfg: ModelConfig, pp_size: int, params, inputs, targets,
     # unsharded loss on 11 mesh shapes to ~1e-6).
     pp_idx = lax.axis_index("pp")
     denom = (
-        axis_size("dp")
-        * axis_size("sp")
-        * axis_size("tp")
-        * axis_size("ep")
+        lax.axis_size("dp")
+        * lax.axis_size("sp")
+        * lax.axis_size("tp")
+        * lax.axis_size("ep")
     )
     return jnp.where(pp_idx == 0, nll, 0.0) / denom
 
@@ -271,7 +270,7 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, lr: float = 1e-3,
         )
         return new_params, loss
 
-    step = shard_map(
+    step = jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(specs, P("dp", "sp"), P("dp", "sp")),
@@ -350,7 +349,7 @@ def build_adamw_train_step(cfg: ModelConfig, mesh: Mesh, lr: float = 1e-3,
                              is_leaf=lambda o: isinstance(o, tuple))
         return new_params, {"m": new_m, "v": new_v, "step": t}, loss
 
-    step = shard_map(
+    step = jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(specs, state_specs, P("dp", "sp"), P("dp", "sp")),
@@ -425,7 +424,7 @@ def build_pp_forward(cfg: ModelConfig, mesh: Mesh, pp_axis: str):
             preferred_element_type=jnp.float32,
         )
 
-    f = shard_map(
+    f = jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(P(pp_axis), P(pp_axis), P(), P()),
@@ -513,7 +512,7 @@ def build_pp_decode(cfg: ModelConfig, mesh: Mesh, pp_axis: str,
         )
         return jnp.concatenate([toks.T, last[:, None]], axis=1)
 
-    f = shard_map(
+    f = jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(P(pp_axis), P(pp_axis), P(), P()),

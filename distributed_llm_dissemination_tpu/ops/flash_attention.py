@@ -199,17 +199,10 @@ def _block_attention_pallas(qg, k, v, q_off, k_off, interpret):
     # are [TILE, 1] (sublane-aligned); squeezed off on return.
     # Inside shard_map the outputs vary over every mesh axis the inputs
     # do (vma): required by pallas_call when the mesh checks vma.
-    typeof = getattr(jax, "typeof", None)
-    vma = frozenset()
-    if typeof is not None:
-        for x in (qg, k, v):
-            vma |= getattr(typeof(x), "vma", frozenset()) or frozenset()
+    vma = frozenset().union(*(jax.typeof(x).vma for x in (qg, k, v)))
 
     def _struct(shape):
-        try:
-            return jax.ShapeDtypeStruct(shape, jnp.float32, vma=vma)
-        except TypeError:  # older jax: no vma kwarg
-            return jax.ShapeDtypeStruct(shape, jnp.float32)
+        return jax.ShapeDtypeStruct(shape, jnp.float32, vma=vma)
 
     out_shape = [
         _struct((b, kvh, g, sq, hd)),
@@ -221,11 +214,8 @@ def _block_attention_pallas(qg, k, v, q_off, k_off, interpret):
     # into o/m/l).  Interpret mode (CPU tests) ignores compiler params.
     kwargs = {}
     if not interpret:
-        params_cls = getattr(pltpu, "CompilerParams",
-                             getattr(pltpu, "TPUCompilerParams", None))
-        if params_cls is not None:
-            kwargs["compiler_params"] = params_cls(
-                dimension_semantics=("parallel", "parallel", "arbitrary"))
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"))
     pv, m, l = pl.pallas_call(
         functools.partial(_attn_kernel, tile_q=tile_q, tile_k=tile_k),
         grid=grid,

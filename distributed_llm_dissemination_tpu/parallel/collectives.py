@@ -40,13 +40,15 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .compat import shard_map
 
 # XLA's CPU backend deadlocks when two collective EXECUTIONS over
 # overlapping device sets interleave: each execution's per-device worker
-# threads can join the other's rendezvous (observed live on the 0.4.x
-# line — "waiting for all participants to arrive at rendezvous
-# RendezvousKey{run_id=861}" next to run_id=862, both wedged forever).
+# threads can join the other's rendezvous ("waiting for all participants
+# to arrive at rendezvous RendezvousKey{run_id=861}" next to run_id=862,
+# both wedged forever).  Re-checked on jax 0.9.0: 6,400 lock-free
+# concurrent gathers did not reproduce it, but one of seven lock-free
+# runs of the device-plane tests lost a test to its 30 s timeout — the
+# lock stays until that is explained.
 # Concurrent plans DO dispatch gathers concurrently (handler threads,
 # the in-flight window), so on the CPU backend every gather runs
 # dispatch→completion under one process-wide lock.  Accelerator
@@ -108,7 +110,7 @@ def _gather_padded(mesh: Mesh, axis: str, pad: int, k: int, dtype):
             return lax.all_gather(frag.reshape(k, pad), axis)  # (n, k, pad)
 
         fn = jax.jit(
-            lambda v: shard_map(
+            lambda v: jax.shard_map(
                 per_device, mesh=mesh,
                 in_specs=P(axis), out_specs=P(),
                 check_vma=False,
@@ -259,6 +261,7 @@ def gather_byte_shards(parts, total: int, verify_digest=None,
         out = bytes(by_k[0])
     elif len(jax.devices()) < n:
         trace.count("shard.gather_host_fallback")
+        trace.count("device.degraded.shard_gather_host")
         log.warn("fewer devices than shards; gathering on host instead "
                  "of the mesh", shards=n, devices=len(jax.devices()))
         out = b"".join(bytes(by_k[k]) for k in range(n))
@@ -336,7 +339,7 @@ def _decode_gathered(wire: bytes, gathered_dev, total: int, codec: str,
 def _allgather_fn(mesh: Mesh, axis: str):
     @jax.jit
     def gather(v):
-        return shard_map(
+        return jax.shard_map(
             lambda s: lax.all_gather(s, axis, tiled=True),
             mesh=mesh,
             in_specs=P(axis),
@@ -373,7 +376,7 @@ def _ring_broadcast_fn(mesh: Mesh, axis: str, src: int):
 
     @jax.jit
     def broadcast(v):
-        return shard_map(
+        return jax.shard_map(
             per_device,
             mesh=mesh,
             in_specs=P(axis),
@@ -401,7 +404,7 @@ def ring_broadcast(
 def _permute_fn(mesh: Mesh, axis: str, perm: Tuple[Tuple[int, int], ...]):
     @jax.jit
     def permute(v):
-        return shard_map(
+        return jax.shard_map(
             lambda s: lax.ppermute(s, axis, perm),
             mesh=mesh,
             in_specs=P(axis),
@@ -434,7 +437,7 @@ def _one_to_all_fn(mesh: Mesh, axis: str, src: int):
             contrib = jnp.where(idx == src, s, jnp.zeros_like(s))
             return lax.psum(contrib, axis)
 
-        return shard_map(
+        return jax.shard_map(
             per_device, mesh=mesh, in_specs=P(axis), out_specs=P(),
             check_vma=False,
         )(v)

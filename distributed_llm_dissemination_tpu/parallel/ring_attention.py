@@ -37,21 +37,18 @@ import numpy as np
 from jax import lax
 
 from ..ops.flash_attention import _NEG_INF, block_attention, merge_partials
-from .compat import axis_size
 
 
 def _vary(axis, *xs):
     """Mark freshly-created accumulators as device-varying over ``axis``
     (needed whenever the surrounding shard_map checks vma)."""
-    if hasattr(lax, "pcast"):
-        return tuple(lax.pcast(x, (axis,), to="varying") for x in xs)
-    return xs
+    return tuple(lax.pcast(x, (axis,), to="varying") for x in xs)
 
 
 def _ring_forward(q, k, v, axis, s_local):
     """The forward ring; returns out plus the per-row log-sum-exp and the
     kernel-layout tensors the custom backward needs."""
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     my = lax.axis_index(axis)
     b, sq, h, hd = q.shape
     kvh = k.shape[2]
@@ -148,7 +145,7 @@ def _ring_attention_bwd(axis, s_local, res, dout):
     """Flash-style ring backward: p = exp(s - lse) is recomputed per
     block; dK/dV ride the rotating carry and return home after n hops."""
     qg, kt, vt, out_g, lse = res
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     my = lax.axis_index(axis)
     b, kvh, group, sq, hd = qg.shape
     scale = 1.0 / np.sqrt(hd)

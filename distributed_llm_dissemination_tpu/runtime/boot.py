@@ -24,13 +24,12 @@ blobs``) — the disseminated bytes never make a host round-trip.
 from __future__ import annotations
 
 import dataclasses
-import os
 import threading
 import time
 from typing import Any, Dict, Optional, Sequence
 
 from ..core.types import LayersSrc
-from ..utils import env as env_util
+from ..utils import env as env_util, trace
 from ..utils.logging import log
 
 # The boot's jitted programs are MODULE-LEVEL singletons (llama.forward_jit
@@ -40,57 +39,9 @@ from ..utils.logging import log
 _stage_fwd_lock = threading.Lock()
 _stage_fwd = None
 
-# ---------------------------------------------- persistent compilation cache
-#
-# DLD_COMPILE_CACHE_DIR points JAX's persistent compilation cache at a
-# directory shared ACROSS runs: the hint-time precompile of run N writes
-# it, the boot of run N+1 reads it — a warm host pays zero XLA compile at
-# boot, and even a cold host's one-time compile overlaps the wire (the
-# BootHint precompile thread).  Thresholds are dropped to zero so the
-# boot's whole program set (decode jits included) is cached, not just the
-# multi-second forward.  Every boot entry point calls this; it is
-# idempotent and re-points (with a cache reset) when the env var changes
-# — tests isolate their cache dirs that way.
-_cache_lock = threading.Lock()
-_cache_applied: Optional[str] = None
-
-
-def ensure_compile_cache() -> str:
-    """Apply ``DLD_COMPILE_CACHE_DIR`` to JAX's persistent compilation
-    cache config (idempotent; safe pre- and post-backend-init).  Returns
-    the active cache dir ("" = disabled)."""
-    global _cache_applied
-    target = os.environ.get("DLD_COMPILE_CACHE_DIR", "")
-    with _cache_lock:
-        if _cache_applied == target:
-            return target
-        import jax
-
-        try:
-            if _cache_applied is not None:
-                # Re-point: drop the old singleton cache object so the
-                # new dir really takes (jax initializes it lazily once).
-                try:
-                    from jax._src import compilation_cache as _cc
-
-                    _cc.reset_cache()
-                except Exception:  # noqa: BLE001 — older jax: no reset
-                    pass
-            jax.config.update("jax_compilation_cache_dir", target or None)
-            if target:
-                for opt, val in (
-                    ("jax_persistent_cache_min_compile_time_secs", 0.0),
-                    ("jax_persistent_cache_min_entry_size_bytes", -1),
-                ):
-                    try:
-                        jax.config.update(opt, val)
-                    except Exception:  # noqa: BLE001 — option drift: the
-                        pass  # defaults still cache the big programs
-                log.info("persistent compilation cache enabled", dir=target)
-        except Exception as e:  # noqa: BLE001 — caching is an optimization
-            log.warn("persistent compilation cache unavailable", err=repr(e))
-        _cache_applied = target
-    return target
+# BootResult.via of a boot whose layer params were assembled from host
+# bytes — what a receiver asked for -hbm must never see.
+VIA_HOST_ASSEMBLY = "host assembly"
 
 
 def blob_donate_ok(src) -> bool:
@@ -166,6 +117,10 @@ class BootResult:
     # stage's stacked layer dict, on its stage's devices) and what
     # pod-level pipelined serving (runtime/pp_serve.py) consumes.
     params: Any = None
+    # How the layer params were assembled ("streamed per-layer", "device
+    # bitcast", "host assembly", ...): a receiver asked for -hbm reads it
+    # to tell a device-path boot from one that fell back to the host.
+    via: str = ""
 
 
 def classify_held_blobs(cfg, held_ids) -> tuple:
@@ -220,7 +175,7 @@ def verify_blob_digest(blob_id: int, src, digest_lookup,
     expected = digest_lookup(blob_id)
     if expected is None or src.inmem_data is None:
         return
-    from ..utils import integrity, trace
+    from ..utils import integrity
 
     ok, dt, got = integrity.digest_check(
         memoryview(src.inmem_data)[src.offset : src.offset + src.data_size],
@@ -384,7 +339,6 @@ def boot_from_layers(
     from ..models import quant, serde
     from ..models.llama import forward_jit
 
-    ensure_compile_cache()
     t0 = time.monotonic()
     head_id = serde.head_blob_id(cfg)
     layer_ids, full = classify_held_blobs(cfg, layers)
@@ -482,6 +436,7 @@ def boot_from_layers(
         except Exception as e:  # noqa: BLE001 — bulk assembly still works
             log.warn("streamed assembly failed; bulk assembly instead",
                      err=repr(e))
+            trace.count("device.degraded.stream_assembly")
             stacked = None
     if stacked is None and all(
             dev_blobs[lid] is not None for lid in layer_ids):
@@ -510,7 +465,7 @@ def boot_from_layers(
             else jnp.asarray(a)
             for name, a in host.items()
         }
-        via = "host assembly"
+        via = VIA_HOST_ASSEMBLY
 
     if full:
         head_on_device = dev_blobs[head_id] is not None
@@ -547,7 +502,7 @@ def boot_from_layers(
                  layers=len(layer_ids), via=via, ttft_ms=round(dt * 1000, 1),
                  stream_wait_ms=round(stream_wait_s * 1000, 1))
         res = BootResult("full", dt, layer_ids, logits=logits,
-                         params=params)
+                         params=params, via=via)
         decode_after_boot(cfg, res, generate_tokens, tokens=tokens)
         return res
 
@@ -563,7 +518,7 @@ def boot_from_layers(
              layers=len(layer_ids), via=via, ttft_ms=round(dt * 1000, 1),
              stream_wait_ms=round(stream_wait_s * 1000, 1))
     return BootResult("stage", dt, layer_ids, activations=acts,
-                      params=stacked)
+                      params=stacked, via=via)
 
 
 def precompile_boot(
@@ -579,10 +534,10 @@ def precompile_boot(
     ``blob_ids`` BEFORE the bytes arrive — XLA compiles from shapes
     alone, so a receiver that gets a ``BootHintMsg`` at distribution
     start can overlap the whole compile with the network transfer and
-    the post-startup boot hits warm caches.  With a persistent
-    compilation cache (``DLD_COMPILE_CACHE_DIR``) the compiles also
-    WRITE that cache, so the next run's precompile — or boot — is a
-    disk hit instead of an XLA compile.
+    the post-startup boot hits warm caches.  The compiles also WRITE
+    JAX's persistent compilation cache (placed at process entry,
+    ``utils.env.place_compile_cache``), so the next run's precompile —
+    or boot — is a disk hit instead of an XLA compile.
 
     Compiles the same module-level callables ``boot_from_layers`` calls
     (``llama.forward_jit`` / ``_stage_forward_jitted`` and, for
@@ -604,7 +559,6 @@ def precompile_boot(
     from ..models import quant, serde
     from ..models.llama import forward_jit
 
-    cache_dir = ensure_compile_cache()
     if streamed is None:
         streamed = env_util.stream_boot_enabled()
     head_id = serde.head_blob_id(cfg)
@@ -723,4 +677,4 @@ def precompile_boot(
         compiled.append("stage_forward")
     return {"compiled": compiled,
             "compile_s": round(time.monotonic() - t0, 2),
-            "persistent_cache": bool(cache_dir)}
+            "persistent_cache": bool(jax.config.jax_compilation_cache_dir)}

@@ -124,6 +124,13 @@ _GAP_NACK_DEFAULT_S = 3.0
 _JOURNAL_CRC_MAX_RECORDS = 1024
 
 
+def _device_names(arr) -> list:
+    """``["tpu:0", ...]`` for a device array (``[]`` for None) — the form
+    the staging logs and ``layer_placement`` report placement in."""
+    if arr is None:
+        return []
+    return sorted(f"{d.platform}:{d.id}" for d in arr.devices())
+
 
 class ReceiverNode:
     """Mode 0 receiver (node.go:1299-1418).
@@ -1724,6 +1731,22 @@ class ReceiverNode:
             window.drain(timeout=5.0)
             window.close()
 
+    def layer_placement(self) -> dict:
+        """Where every held layer ended: the location its ack carried,
+        and the devices of whatever device copy is still resident (a
+        booted layer's wire blob may have been consumed by its decode).
+        JSON-ready — the CLI logs it when the process was asked for
+        ``-hbm``."""
+        with self._lock:
+            return {
+                str(lid): {
+                    "location": src.meta.location.name,
+                    "bytes": src.data_size,
+                    "devices": _device_names(src.device_array),
+                }
+                for lid, src in self.layers.items()
+            }
+
     def _stage_to_hbm(self, layer_id, src, ingest=None) -> "LayerLocation":
         """Move a completed layer host→HBM when enabled; returns the
         location to ack with.  jax is imported lazily so host-only nodes
@@ -1755,11 +1778,13 @@ class ReceiverNode:
             log.info("layer staged to HBM", layerID=layer_id,
                      via="incremental ingest" if ingest is not None else "bulk",
                      stage_ms=round(dt * 1000, 1),
-                     gbps=round(src.data_size / max(dt, 1e-9) / 1e9, 3))
+                     gbps=round(src.data_size / max(dt, 1e-9) / 1e9, 3),
+                     devices=_device_names(src.device_array))
             return LayerLocation.HBM
         except Exception as e:  # noqa: BLE001 — delivery beats staging
             log.error("HBM staging failed; acking host RAM",
                       layerID=layer_id, err=repr(e))
+            trace.count("device.degraded.stage_inmem")
             return LayerLocation.INMEM
         finally:
             ev.set()
@@ -1783,6 +1808,7 @@ class ReceiverNode:
             except Exception as e:  # noqa: BLE001 — fall back to bulk path
                 log.error("ingest finalize failed; bulk staging instead",
                           layerID=layer_id, err=repr(e))
+                trace.count("device.degraded.ingest_finalize")
         if (self.placement is not None
                 and layer_id in self.placement.layer_to_stage):
             from ..parallel.ingest import ingest_bytes
@@ -2875,7 +2901,7 @@ class ReceiverNode:
         return self._boot_drained.wait(timeout=timeout)
 
     def _boot_inner(self) -> None:
-        from .boot import boot_from_layers
+        from .boot import VIA_HOST_ASSEMBLY, boot_from_layers
 
         try:
             res = boot_from_layers(
@@ -2914,6 +2940,10 @@ class ReceiverNode:
             self._boot_finished.set()  # serve waiters proceed either way
         with self._lock:
             self._boot_report = (res.seconds, res.kind)
+        if self.stage_hbm and res.via == VIA_HOST_ASSEMBLY:
+            log.error("boot assembled on the host although -hbm staging "
+                      "was asked for", kind=res.kind)
+            trace.count("device.degraded.host_assembly")
         self._send_to_leader(
             BootReadyMsg(self.node.my_id, res.seconds, res.kind))
         if self.boot_generate > 0:
@@ -2926,6 +2956,7 @@ class ReceiverNode:
                 decode_after_boot(self.boot_cfg, res, self.boot_generate)
             except Exception as e:  # noqa: BLE001 — serving is best-effort here
                 log.error("post-boot decode failed", err=repr(e))
+                trace.count("device.degraded.post_boot_decode")
 
     # ------------------------------------------------- pod serving (spmd)
 
@@ -3495,6 +3526,7 @@ class FlowRetransmitReceiverNode(RetransmitReceiverNode):
                 except Exception as e:  # noqa: BLE001 — delivery beats staging
                     log.error("device ingest unavailable for layer",
                               layerID=layer_id, err=repr(e))
+                    trace.count("device.degraded.ingest_unavailable")
                     self._ingest_dead.add(layer_id)
                     return None
                 self._ingests[layer_id] = ing
@@ -3505,6 +3537,7 @@ class FlowRetransmitReceiverNode(RetransmitReceiverNode):
         waiter into the bulk-staging fallback) and stop feeding it."""
         log.error("incremental device ingest failed; will stage at "
                   "completion", layerID=layer_id, err=repr(err))
+        trace.count("device.degraded.ingest_write")
         ing.fail()
         with self._ingests_lock:
             self._ingest_dead.add(layer_id)

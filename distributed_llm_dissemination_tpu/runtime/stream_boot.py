@@ -174,9 +174,6 @@ class StreamingBootStager:
     # ------------------------------------------------------------- worker
 
     def _run(self) -> None:
-        from .boot import ensure_compile_cache
-
-        ensure_compile_cache()
         while True:
             item = self._q.get()
             if item is None:
@@ -193,6 +190,7 @@ class StreamingBootStager:
                 log.warn("streamed boot staging failed for blob; bulk "
                          "assembly will cover it", blobID=blob_id,
                          err=repr(e))
+                trace.count("device.degraded.stream_stage")
             dt = time.monotonic() - t0
             with self._lock:
                 # Store only while the submission marker stands — an

@@ -93,8 +93,7 @@ def _ring_devices(n):
 
 def _run_ring(q, k, v, n, s_local):
     mesh = Mesh(np.array(_ring_devices(n)), ("sp",))
-    from distributed_llm_dissemination_tpu.parallel.compat import shard_map
-    f = shard_map(
+    f = jax.shard_map(
         functools.partial(ring_attention, axis="sp", s_local=s_local),
         mesh=mesh,
         in_specs=(P(None, "sp"), P(None, "sp"), P(None, "sp")),

@@ -1,20 +1,16 @@
-"""Stale-artifact gating (VERDICT r4 ask#6): committed measurement
-artifacts must carry the CURRENT harness hash or a documented ``stale``
-marker — a recorded report can no longer silently masquerade as
-evidence for code it never ran."""
+"""Stale-artifact gating: a measurement artifact must carry the CURRENT
+harness hash or a documented ``stale`` marker — a recorded report can
+not silently masquerade as evidence for code it never ran.  (The
+on-chip evidence itself is not a committed file: ``chip_smoke.py`` is
+re-run on the chip for every PR; ``tests/test_chip_smoke.py`` keeps its
+plumbing honest on the CPU.)"""
 
-import json
-import os
 import re
-import subprocess
-import sys
 
 from distributed_llm_dissemination_tpu.utils.provenance import (
     artifact_is_current,
     harness_hash,
 )
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_harness_hash_is_stable_and_code_sensitive(tmp_path):
@@ -37,46 +33,3 @@ def test_artifact_gate_semantics():
     assert ok and why.startswith("documented-stale")
     ok, _ = artifact_is_current({"stale": "   "})  # blank marker: no pass
     assert not ok
-
-
-def test_committed_tpu_smoke_is_current_or_documented_stale():
-    path = os.path.join(REPO, "TPU_SMOKE.json")
-    assert os.path.exists(path), "TPU_SMOKE.json must be committed"
-    with open(path) as f:
-        report = json.load(f)
-    ok, why = artifact_is_current(report)
-    assert ok, f"committed TPU_SMOKE.json fails the provenance gate: {why}"
-
-
-def test_round5_plus_bench_artifacts_carry_provenance():
-    """BENCH_r01..r04 predate the hash (historical records); anything
-    newer must carry the stamp bench.py now embeds.  The driver wraps
-    bench.py's JSON line under a 'parsed' key, so a freshly captured
-    artifact may carry the hash there — accepted, same provenance."""
-    for name in sorted(os.listdir(REPO)):
-        m = re.fullmatch(r"BENCH_r(\d+)\.json", name)
-        if not m or int(m.group(1)) <= 4:
-            continue
-        with open(os.path.join(REPO, name)) as f:
-            rec = json.load(f)
-        parsed = rec.get("parsed") or {}
-        assert ("harness_hash" in rec or rec.get("stale")
-                or "harness_hash" in parsed), (
-            f"{name} lacks provenance (harness_hash or stale marker)")
-
-
-def test_tpu_smoke_check_flag_gates_artifacts(tmp_path):
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    good = tmp_path / "good.json"
-    good.write_text(json.dumps({"harness_hash": harness_hash()}))
-    bad = tmp_path / "bad.json"
-    bad.write_text(json.dumps({"harness_hash": "dead" * 4}))
-    cli = [sys.executable, "-m",
-           "distributed_llm_dissemination_tpu.cli.tpu_smoke", "--check"]
-    assert subprocess.run(cli + [str(good)], env=env,
-                          capture_output=True).returncode == 0
-    assert subprocess.run(cli + [str(bad)], env=env,
-                          capture_output=True).returncode == 1
-    assert subprocess.run(cli + [str(tmp_path / "missing.json")], env=env,
-                          capture_output=True).returncode == 1

@@ -4,7 +4,8 @@ staging, and donated staging.
 Three properties under test (ISSUE 3):
 - warm-vs-cold persistent cache: a boot whose in-memory jit caches are
   gone still pays zero NEW compile-cache writes — every program is
-  served from ``DLD_COMPILE_CACHE_DIR``;
+  served from JAX's persistent cache, placed at process entry
+  (``utils.env.place_compile_cache``);
 - per-layer staging order-invariance: blobs streamed in ANY completion
   order assemble to byte-identical params (and to the bulk, unstreamed
   assembly);
@@ -30,7 +31,6 @@ from distributed_llm_dissemination_tpu.models import quant, serde
 from distributed_llm_dissemination_tpu.models.llama import CONFIGS, forward_jit, init_params
 from distributed_llm_dissemination_tpu.runtime.boot import (
     boot_from_layers,
-    ensure_compile_cache,
     precompile_boot,
 )
 from distributed_llm_dissemination_tpu.runtime.stream_boot import (
@@ -313,55 +313,93 @@ def _misses(records, name):
             if "CACHE MISS" in r.upper() and f"'{name}'" in r]
 
 
-def test_persistent_cache_warm_boot_serves_forward_from_disk(
-        monkeypatch, tmp_path):
-    """Cold boot populates DLD_COMPILE_CACHE_DIR; after clearing every
-    in-memory jit cache (the warm-HOST shape), a second boot's forward
-    is a persistent-cache HIT, never a miss — and the logits are
-    identical."""
+_WARM_COLD_CHILD = r"""
+import dataclasses, json, logging, os, sys
+from distributed_llm_dissemination_tpu.utils.env import (
+    DEFAULT_COMPILE_CACHE_DIR, place_compile_cache)
+placed = place_compile_cache()  # process entry, before jax
+import jax, numpy as np
+from distributed_llm_dissemination_tpu.core.types import (
+    LayerLocation, LayerMeta, LayerSrc)
+from distributed_llm_dissemination_tpu.models import serde
+from distributed_llm_dissemination_tpu.models.llama import CONFIGS
+from distributed_llm_dissemination_tpu.runtime.boot import boot_from_layers
+
+records = []
+class H(logging.Handler):
+    def emit(self, r):
+        records.append(r.getMessage())
+lg = logging.getLogger("jax._src.compiler")
+lg.addHandler(H()); lg.setLevel(logging.DEBUG)
+
+cfg = dataclasses.replace(CONFIGS["tiny"], vocab=304)
+ids = list(range(cfg.n_layers)) + [serde.head_blob_id(cfg)]
+blobs = {b: serde.seeded_blob(cfg, b, 0) for b in ids}
+def boot():
+    return boot_from_layers(cfg, {b: LayerSrc(
+        inmem_data=bytearray(d), data_size=len(d),
+        meta=LayerMeta(location=LayerLocation.INMEM))
+        for b, d in blobs.items()})
+def fwd(kind):
+    return [r for r in records if "'jit_forward_jit'" in r
+            and kind in r.upper()]
+records.clear(); r1 = boot(); cold_miss = len(fwd("CACHE MISS"))
+entries = sorted(f for f in os.listdir(placed) if f.endswith("-cache"))
+jax.clear_caches()  # the warm-HOST shape: no in-memory executables
+records.clear(); r2 = boot()
+print(json.dumps({
+    "placed": placed, "jax_dir": jax.config.jax_compilation_cache_dir,
+    "default_dir": DEFAULT_COMPILE_CACHE_DIR,
+    "cold_miss": cold_miss, "entries": len(entries),
+    "forward_entry": any(e.startswith("jit_forward_jit") for e in entries),
+    "warm_hit": len(fwd("CACHE HIT")), "warm_miss": len(fwd("CACHE MISS")),
+    "equal": bool(np.array_equal(
+        np.asarray(jax.device_get(r1.logits), np.float32),
+        np.asarray(jax.device_get(r2.logits), np.float32)))}))
+"""
+
+
+def test_persistent_cache_warm_boot_serves_forward_from_disk(tmp_path):
+    """A fresh process with ``JAX_COMPILATION_CACHE_DIR=<X>`` set from
+    outside: the cold boot populates <X> (and only <X>); after clearing
+    every in-memory jit cache (the warm-HOST shape), a second boot's
+    forward is a persistent-cache HIT, never a miss — and the logits
+    are identical."""
+    import json
+    import subprocess
+    import sys
+
     cachedir = tmp_path / "pcache"
-    cachedir.mkdir()
-    monkeypatch.setenv("DLD_COMPILE_CACHE_DIR", str(cachedir))
-    cfg = dataclasses.replace(CFG, vocab=304)  # unique shapes: cold
-    ids = list(range(cfg.n_layers)) + [serde.head_blob_id(cfg)]
-    # Fabricate once: blob generation compiles its own (RNG) programs,
-    # which must not muddy the boot-program oracle below.
-    blobs = {bid: serde.seeded_blob(cfg, bid, SEED) for bid in ids}
-
-    def boot():
-        return boot_from_layers(
-            cfg, {bid: blob_layer(b) for bid, b in blobs.items()})
-
-    with _pcache_log() as records:
-        res1 = boot()
-    assert _misses(records, "jit_forward_jit"), (
-        "oracle broken: cold boot logged no forward cache miss")
-    assert _cache_entries(cachedir), "cold boot wrote no cache entries"
-
-    jax.clear_caches()  # the warm-HOST shape: no in-memory executables
-    with _pcache_log() as records:
-        res2 = boot()
-    assert _hits(records, "jit_forward_jit"), (
-        "warm boot's forward was not served from the persistent cache")
-    assert not _misses(records, "jit_forward_jit")
-    np.testing.assert_array_equal(
-        np.asarray(jax.device_get(res1.logits), np.float32),
-        np.asarray(jax.device_get(res2.logits), np.float32))
+    env = dict(os.environ)
+    env["JAX_COMPILATION_CACHE_DIR"] = str(cachedir)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+         env.get("PYTHONPATH", "")])
+    out = subprocess.run([sys.executable, "-c", _WARM_COLD_CHILD], env=env,
+                         capture_output=True, text=True, timeout=100)
+    assert out.returncode == 0, out.stderr[-2000:]
+    rec = json.loads(out.stdout.strip().splitlines()[-1])
+    assert rec["placed"] == rec["jax_dir"] == str(cachedir)
+    assert rec["placed"] != rec["default_dir"]
+    assert rec["cold_miss"], "oracle broken: cold boot logged no miss"
+    assert rec["entries"] and rec["forward_entry"], rec
+    assert rec["warm_hit"] and not rec["warm_miss"], rec
+    assert rec["equal"]
 
 
-def test_precompile_writes_cache_boot_reads_it(monkeypatch, tmp_path):
+def test_precompile_writes_cache_boot_reads_it():
     """The cross-run story in one process: hint-time precompile_boot
-    WRITES the cache; with in-memory caches dropped, the boot's forward
-    comes from disk."""
-    cachedir = tmp_path / "pcache2"
-    cachedir.mkdir()
-    monkeypatch.setenv("DLD_COMPILE_CACHE_DIR", str(cachedir))
+    WRITES the session's persistent cache; with in-memory caches
+    dropped, the boot's forward comes from disk."""
+    cachedir = jax.config.jax_compilation_cache_dir
+    assert cachedir, "conftest places the compile cache before jax"
     cfg = dataclasses.replace(CFG, vocab=336)
     ids = list(range(cfg.n_layers)) + [serde.head_blob_id(cfg)]
     rec = precompile_boot(cfg, ids)
     assert rec["compiled"] == ["forward"]
     assert rec["persistent_cache"] is True
-    assert _cache_entries(cachedir)
+    assert any(e.startswith("jit_forward_jit")
+               for e in _cache_entries(cachedir))
     jax.clear_caches()
     layers = {bid: blob_layer(serde.seeded_blob(cfg, bid, SEED))
               for bid in ids}
@@ -372,15 +410,37 @@ def test_precompile_writes_cache_boot_reads_it(monkeypatch, tmp_path):
         "boot did not read the precompile's persistent-cache entry")
 
 
-def test_ensure_compile_cache_repoints_on_env_change(monkeypatch, tmp_path):
-    a, b = tmp_path / "a", tmp_path / "b"
-    a.mkdir(), b.mkdir()
-    monkeypatch.setenv("DLD_COMPILE_CACHE_DIR", str(a))
-    assert ensure_compile_cache() == str(a)
-    monkeypatch.setenv("DLD_COMPILE_CACHE_DIR", str(b))
-    assert ensure_compile_cache() == str(b)
-    jax.jit(lambda x: x * 3 + jnp.float32(1.5))(jnp.arange(9.0))
-    assert _cache_entries(b), "re-pointed cache dir got no writes"
+def test_compile_cache_placement_rules(monkeypatch):
+    """Where JAX_COMPILATION_CACHE_DIR is set it wins untouched; where
+    it is not, ONE fixed git-ignored path inside the checkout — and no
+    code path points JAX's cache anywhere by itself."""
+    import subprocess
+
+    from distributed_llm_dissemination_tpu.utils import env as env_util
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for var in ("JAX_COMPILATION_CACHE_DIR",
+                "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
+                "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"):
+        monkeypatch.delenv(var, raising=False)
+    placed = env_util.place_compile_cache()
+    assert placed == env_util.DEFAULT_COMPILE_CACHE_DIR
+    assert os.path.dirname(placed) == repo
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert os.path.basename(placed) + "/" in f.read().split()
+    assert os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] == "0"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/where/else")
+    monkeypatch.setenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "2")
+    assert env_util.place_compile_cache() == "/some/where/else"
+    assert os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] == "2"
+    hits = subprocess.run(
+        ["grep", "-rlE", "--include=*.py",
+         'jax_compilation_cache_dir", |DLD_COMPILE_' 'CACHE_DIR',
+         os.path.join(repo, "distributed_llm_dissemination_tpu"),
+         os.path.join(repo, "chip_smoke.py"),
+         os.path.join(repo, "bench.py")],
+        capture_output=True, text=True).stdout.split()
+    assert not hits, hits
 
 
 # -------------------------------------------- streamed precompile coverage
