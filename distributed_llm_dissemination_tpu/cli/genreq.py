@@ -20,11 +20,13 @@ from __future__ import annotations
 import argparse
 import json
 import sys
+import time
 
 from ..core import config as cfg_mod
 from ..runtime.client import GenRequester
 from ..transport.tcp import TcpTransport
 from ..utils import logging as ulog
+from ..utils import trace
 from ..utils.logging import log
 
 
@@ -94,15 +96,22 @@ def main(argv=None) -> int:
     transport = TcpTransport(by_id[my_id].addr)
     transport.addr_registry.update({nc.id: nc.addr for nc in conf.nodes})
     requester = GenRequester(transport, my_id=my_id)
+    t_sent = time.monotonic()
     try:
-        tokens = requester.request(args.node, prompt, args.n,
-                                   timeout=args.t, temperature=args.temp,
-                                   seed=args.seed)
+        with trace.span("serve.request", id=f"req.{my_id}", node=my_id,
+                        dest=args.node):
+            tokens = requester.request(args.node, prompt, args.n,
+                                       timeout=args.t,
+                                       temperature=args.temp,
+                                       seed=args.seed)
     except (RuntimeError, TimeoutError, OSError, ConnectionError) as e:
         log.error("generation request failed", err=str(e))
         print(json.dumps({"error": str(e)}))
         return 1
     finally:
+        # This request's spans, as the last log records: the requester
+        # may be a resident process that never resets its registry.
+        trace.dump_spans(log, since=t_sent)
         requester.close()
         transport.close()
     rec = {"node": args.node, "prompt": prompt, "tokens": tokens}

@@ -37,6 +37,7 @@ from ..runtime import (
 from ..transport import TcpTransport
 from ..utils import env as env_util
 from ..utils import logging as ulog
+from ..utils import trace
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -857,8 +858,6 @@ def device_path_degradations() -> dict:
     still boots — but a process that was ASKED for ``-hbm`` must not end
     as a clean run when any of them fired: ``run_receiver`` exits
     non-zero on a non-empty answer."""
-    from ..utils import trace
-
     return {k: v for k, v in trace.counter_totals().items()
             if k.startswith("device.degraded.")}
 
@@ -1112,6 +1111,9 @@ def main(argv=None) -> int:
     node_conf = cfg.get_node_conf(conf, args.id)
     if not holds_device(args, conf, node_conf):
         env_util.pin_jax_to_cpu()
+    else:
+        # The process that holds the device counts what it compiles.
+        trace.watch_compiles()
 
     if (conf.mesh is not None and conf.mesh.fabric
             and conf.distributed is None):
@@ -1230,6 +1232,9 @@ def main(argv=None) -> int:
             return run_leader(args, conf, node, layers)
         return run_receiver(args, conf, node, layers)
     finally:
+        # The run's interval spans and counters, as this role's last
+        # log records (docs/observability.md).
+        trace.dump_spans(ulog.log)
         transport.close()
         if conf.distributed is not None:
             # Orderly pod-runtime teardown: interpreter exit destroying

@@ -88,6 +88,22 @@ def fabric_bandwidths(conf: cfg.Config) -> Dict[int, int]:
     return {nc.id: (ici if ici > 0 else nc.network_bw) for nc in conf.nodes}
 
 
+# The summary's ``plan_phases`` keeps the three names its readers look
+# up (cli/ttd_matrix.py's fabric table, the benchmark's pod_phase reader)
+# beside the span names they are sums of.
+_PLAN_PHASE_NAMES = {"upload": "fabric.upload",
+                     "collective": "fabric.collective",
+                     "splice": "fabric.splice"}
+
+
+def plan_phases(totals: dict) -> dict:
+    out = dict(totals)
+    for short, name in _PLAN_PHASE_NAMES.items():
+        if name in totals:
+            out[short] = totals[name]
+    return out
+
+
 def run_pod(conf: cfg.Config, mode: int = 3, boot: str = "",
             timeout: float = 600.0, gen: int = 0,
             on_delivered=None, report: str = "") -> Dict[str, float]:
@@ -100,6 +116,9 @@ def run_pod(conf: cfg.Config, mode: int = 3, boot: str = "",
     from ..parallel.fabric import FabricPlane
     from ..parallel.mesh import fabric_placement, mesh_from_conf
 
+    from ..utils import trace as utrace
+
+    utrace.watch_compiles()  # this process holds the pod's devices
     mesh = mesh_from_conf(conf.mesh)
     node_ids = [nc.id for nc in conf.nodes]
     placement = fabric_placement(node_ids, conf.assignment, mesh,
@@ -159,7 +178,6 @@ def run_pod(conf: cfg.Config, mode: int = 3, boot: str = "",
         # the ttd_matrix fabric row reads these out of the summary line.
         from ..parallel import plan_cache
         from ..utils import telemetry as utelemetry
-        from ..utils import trace as utrace
 
         plan_cache.log_stats()
         # The whole pod lives in this ONE process, so the process
@@ -170,7 +188,7 @@ def run_pod(conf: cfg.Config, mode: int = 3, boot: str = "",
         summary = {"mode": mode, "ttd_s": round(ttd, 6),
                    "nodes": len(node_ids), "fabric": True,
                    "collective_cache": plan_cache.stats(),
-                   "plan_phases": utrace.phase_totals(),
+                   "plan_phases": plan_phases(utrace.phase_totals()),
                    "telemetry": {"counters": tel_snap.get("counters"),
                                  "hists": tel_snap.get("hists")}}
         pred_ms = getattr(leader, "predicted_ttd_ms", 0)
@@ -193,9 +211,10 @@ def run_pod(conf: cfg.Config, mode: int = 3, boot: str = "",
             stores = {r.node.my_id: r.layers for r in receivers}
             assembled = assemble_pp_params(boot_cfg, placement, results,
                                            stores, conf.model_codec)
-            served = pod_forward(boot_cfg, placement, results, stores,
-                                 codec=conf.model_codec,
-                                 assembled=assembled)
+            with utrace.span("serve.pod_forward", node=leader_conf.id):
+                served = pod_forward(boot_cfg, placement, results, stores,
+                                     codec=conf.model_codec,
+                                     assembled=assembled)
             if served is not None:
                 _, pod_s = served
                 summary["pod_forward_s"] = round(pod_s, 6)
@@ -203,9 +222,11 @@ def run_pod(conf: cfg.Config, mode: int = 3, boot: str = "",
             if served is not None and gen > 0:
                 from ..runtime.pp_serve import pod_decode
 
-                dec = pod_decode(boot_cfg, placement, results, stores,
-                                 max_new=gen, codec=conf.model_codec,
-                                 assembled=assembled)
+                with utrace.span("serve.pod_decode", node=leader_conf.id,
+                                 new_tokens=gen):
+                    dec = pod_decode(boot_cfg, placement, results, stores,
+                                     max_new=gen, codec=conf.model_codec,
+                                     assembled=assembled)
                 if dec is not None:
                     toks, dec_s = dec
                     summary["pod_decode_s"] = round(dec_s, 6)
@@ -228,6 +249,9 @@ def run_pod(conf: cfg.Config, mode: int = 3, boot: str = "",
         print(json.dumps(summary), flush=True)
         return summary
     finally:
+        # The pod's interval spans (every seat's: they carry ``node``)
+        # and counters, as the run's last log records.
+        utrace.dump_spans(ulog.log)
         if leader is not None:
             leader.close()
         for r in receivers:

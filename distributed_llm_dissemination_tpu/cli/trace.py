@@ -17,9 +17,19 @@ Mapping:
 - reassembly progress (``layer fragment stored``) becomes a per-layer
   counter ("C") track.
 
+- the entry points' ``"spans"`` dumps (``utils/trace.dump_spans``)
+  become one slice per interval span on a per-thread track, placed on
+  the wall clock by the ``"span counters"`` record's clock pair.
+
 Usage:
     python -m distributed_llm_dissemination_tpu.cli.trace logs/ -o run.trace.json
     python -m ....trace merged.jsonl            # from collect_logs output
+    python -m ....trace --xplane <profiler dir or .xplane.pb>
+
+``--xplane`` reads a JAX profiler capture instead of logs: the program's
+span annotations sit in its host plane on the same clock as the device
+planes, so every idle gap of the device is split by what the host was
+doing in it (``idle_gap_table``).
 """
 
 from __future__ import annotations
@@ -30,6 +40,12 @@ import sys
 from typing import Iterable, List
 
 from .collect_logs import iter_records
+
+# The layers of the span vocabulary (docs/observability.md): an event of
+# a profiler's host plane whose name starts with one of them is one of
+# the program's spans.
+SPAN_LAYERS = ("plan.", "wire.", "ingest.", "decode.", "boot.", "serve.",
+               "fabric.")
 
 # message -> (slice name, duration field)
 _DURATION_RULES = {
@@ -180,6 +196,36 @@ def span_flow_events(records, offsets: dict) -> List[dict]:
     return events
 
 
+def interval_span_events(records, offsets: dict) -> List[dict]:
+    """One slice per interval span of the nodes' ``"spans"`` dumps.  A
+    span's ends are CLOCK_MONOTONIC; the same node's ``"span counters"``
+    record read both clocks at once, which places them on the wall
+    clock (then shifted like every other record of that node)."""
+    wall_minus_mono = {}
+    for rec in records:
+        if (rec.get("message") == "span counters"
+                and isinstance(rec.get("mono"), (int, float))
+                and isinstance(rec.get("wall_ms"), (int, float))):
+            wall_minus_mono[rec.get("node", "?")] = (
+                rec["wall_ms"] - rec["mono"] * 1000.0)
+    events: List[dict] = []
+    for rec in records:
+        pid = rec.get("node", "?")
+        if rec.get("message") != "spans" or pid not in wall_minus_mono:
+            continue
+        shift = wall_minus_mono[pid] + offsets.get(pid, 0.0)
+        for sp in rec.get("spans") or ():
+            events.append({
+                "ph": "X", "pid": pid, "tid": sp.get("thread", "spans"),
+                "name": sp["name"],
+                "ts": (sp["t0"] * 1000.0 + shift) * 1000.0,
+                "dur": (sp["t1"] - sp["t0"]) * 1e6,
+                "args": {k: v for k, v in sp.items()
+                         if k not in ("name", "t0", "t1", "thread")},
+            })
+    return events
+
+
 def to_trace_events(records: Iterable[dict],
                     align_clocks: bool = True) -> List[dict]:
     """Chrome trace events from merged log records.
@@ -196,6 +242,7 @@ def to_trace_events(records: Iterable[dict],
     # Flow arrows from the span timeline (docs/observability.md) ride
     # alongside the log-derived slices; same clock alignment.
     events: List[dict] = list(span_flow_events(records, offsets))
+    events += interval_span_events(records, offsets)
     seen_pids = set()
     for rec in records:
         msg = rec.get("message")
@@ -217,8 +264,8 @@ def to_trace_events(records: Iterable[dict],
             })
 
         # Known duration-carrying messages get curated slice names; any
-        # other record with a duration_ms field (e.g. emitted by
-        # utils.trace.span) becomes a slice named by its message.
+        # other record with a duration_ms field becomes a slice named by
+        # its message.
         rule = _DURATION_RULES.get(msg)
         if rule is None and isinstance(rec.get("duration_ms"), (int, float)):
             rule = (msg, "duration_ms")
@@ -261,15 +308,146 @@ def to_trace_events(records: Iterable[dict],
     return events
 
 
+# ------------------------------------------------ profiler capture → gaps
+
+
+def _merged(intervals) -> list:
+    out: list = []
+    for s, e in sorted(intervals):
+        if out and s <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], e)
+        else:
+            out.append([s, e])
+    return out
+
+
+def _overlap(merged: list, lo: float, hi: float) -> float:
+    return sum(max(0.0, min(e, hi) - max(s, lo)) for s, e in merged)
+
+
+# Where a TPU capture holds the executed operations: the planes of the
+# chips, and on each the lines of the ops (the other lines are steps,
+# modules and name scopes that span them).
+DEVICE_PLANE = "/device:TPU:"
+OP_LINES = ("XLA Ops", "Async XLA Ops")
+
+
+def idle_gap_table(planes, device_plane: str = DEVICE_PLANE,
+                   op_lines=OP_LINES,
+                   window_event: str = "", from_span: str = "",
+                   to_span: str = "", top: int = 5) -> dict:
+    """Split the device's idle gaps by the program's spans.
+
+    ``planes``: ``[(plane name, [(line name, [(event name, start_ns,
+    duration_ns)])])]`` of one profiler capture.  Busy is the union of
+    the operations on the ``device_plane`` planes' ``op_lines``; a gap
+    is the rest of the window (``window_event``: a host annotation that
+    brackets it, else first to last operation; ``from_span`` /
+    ``to_span`` narrow it to the first start of one span name and the
+    last end of another, which is how a phase of a cold start is cut
+    out: ``wire.recv`` to ``ingest.ack`` is the delivery).  The program's spans
+    are the host planes' events named in ``SPAN_LAYERS``.  For the
+    ``top`` longest gaps and for all gaps together: the seconds each
+    span name overlaps (spans overlap each other, so these may add up
+    to more than the gap) and the seconds no span covers."""
+    busy, spans, window = [], {}, None
+    for pname, lines in planes:
+        device = pname.startswith(device_plane)
+        for lname, events in lines:
+            if device and lname.split("/", 1)[0] in op_lines:
+                busy += [(s, s + d) for _, s, d in events if d > 0]
+                continue
+            for name, s, d in events:
+                if name.startswith(SPAN_LAYERS):
+                    spans.setdefault(name, []).append((s, s + d))
+                elif window_event and name == window_event:
+                    window = (s, s + d)
+    if not busy:
+        raise SystemExit(f"no operation on any {device_plane}* plane")
+    busy = _merged(busy)
+    w0, w1 = window or (busy[0][0], busy[-1][1])
+    if from_span in spans:
+        w0 = min(s for s, _ in spans[from_span])
+    if to_span in spans:
+        w1 = max(e for _, e in spans[to_span])
+    edges = [w0] + [x for s, e in busy for x in (max(s, w0), min(e, w1))
+                    if s < w1 and e > w0] + [w1]
+    gaps = [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
+            if edges[i + 1] > edges[i]]
+    by_name = {name: _merged(iv) for name, iv in spans.items()}
+    covered = _merged(iv for ivs in spans.values() for iv in ivs)
+
+    def split(pieces) -> dict:
+        row = {name: sum(_overlap(m, lo, hi) for lo, hi in pieces) * 1e-9
+               for name, m in by_name.items()}
+        row = {k: round(v, 6) for k, v in sorted(
+            row.items(), key=lambda kv: -kv[1]) if v > 0}
+        idle = sum(hi - lo for lo, hi in pieces)
+        row["uncovered"] = round(
+            (idle - sum(_overlap(covered, lo, hi) for lo, hi in pieces))
+            * 1e-9, 6)
+        return {"idle_s": round(idle * 1e-9, 6), "by_span_s": row}
+
+    longest = sorted(gaps, key=lambda g: g[0] - g[1])[:top]
+    return {"window_s": round((w1 - w0) * 1e-9, 6),
+            "busy_s": round(_overlap(busy, w0, w1) * 1e-9, 6),
+            "span_names": sorted(by_name),
+            "all_gaps": split(gaps),
+            "longest_gaps": [dict(start_s=round((lo - w0) * 1e-9, 6),
+                                  **split([(lo, hi)]))
+                             for lo, hi in longest]}
+
+
+def load_xplane(path: str) -> list:
+    """A profiler capture (its directory, or the ``.xplane.pb``) as
+    ``idle_gap_table`` wants it."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    if os.path.isdir(path):
+        hits = sorted(glob.glob(os.path.join(
+            path, "**", "*.xplane.pb"), recursive=True))
+        if not hits:
+            raise SystemExit(f"no .xplane.pb under {path}")
+        path = hits[-1]
+    return [(plane.name,
+             [(line.name, [(e.name, float(e.start_ns), float(e.duration_ns))
+                           for e in line.events]) for line in plane.lines])
+            for plane in ProfileData.from_file(path).planes]
+
+
 def main(argv: list[str] | None = None) -> int:
     p = argparse.ArgumentParser(prog="trace", description=__doc__)
-    p.add_argument("paths", nargs="+", help="log files or directories")
+    p.add_argument("paths", nargs="*", help="log files or directories")
+    p.add_argument("--xplane", default="",
+                   help="a JAX profiler capture (directory or .xplane.pb):"
+                        " print its idle gaps split by the program's "
+                        "spans, as JSON, instead of converting logs")
+    p.add_argument("--window", default="",
+                   help="a host annotation that brackets the window "
+                        "(default: first to last device operation)")
+    p.add_argument("--from-span", default="",
+                   help="start the window at this span's first start")
+    p.add_argument("--to-span", default="",
+                   help="end the window at this span's last end")
     p.add_argument("-o", "--output", default="-",
                    help="trace JSON output (default: stdout)")
     p.add_argument("--raw-clocks", action="store_true",
                    help="skip clock-offset correction (render each "
                         "node's timestamps as logged)")
     args = p.parse_args(argv)
+    if args.xplane:
+        json.dump(idle_gap_table(load_xplane(args.xplane),
+                                 window_event=args.window,
+                                 from_span=args.from_span,
+                                 to_span=args.to_span),
+                  sys.stdout, indent=1)
+        print()
+        return 0
+    if not args.paths:
+        p.error("give log paths, or --xplane")
 
     events = to_trace_events(iter_records(args.paths),
                              align_clocks=not args.raw_clocks)

@@ -138,7 +138,9 @@ def _pick(logits, step_key, temperature: float):
 def _prefill_fn(cfg: ModelConfig, p: int):
     @jax.jit
     def prefill(params, prompt, cache):
-        return _forward_with_cache(params, prompt, jnp.arange(p), cache, cfg)
+        with jax.named_scope("generate.prefill"):
+            return _forward_with_cache(params, prompt, jnp.arange(p),
+                                       cache, cfg)
 
     return prefill
 
@@ -150,10 +152,11 @@ def _decode_fn(cfg: ModelConfig, p: int, max_new: int, temperature: float):
         def step(carry, scanned):
             cache, token, pos = carry
             step_key, = scanned
-            logits, cache = _forward_with_cache(
-                params, token[:, None], pos[None], cache, cfg
-            )
-            nxt = _pick(logits, step_key, temperature)
+            with jax.named_scope("generate.step"):
+                logits, cache = _forward_with_cache(
+                    params, token[:, None], pos[None], cache, cfg
+                )
+                nxt = _pick(logits, step_key, temperature)
             return (cache, nxt, pos + 1), token
 
         (_, last, _), toks = jax.lax.scan(
@@ -177,10 +180,11 @@ def _decode_step_fn(cfg: ModelConfig, temperature: float):
 
     @jax.jit
     def step(params, cache, token, pos, step_key):
-        logits, cache = _forward_with_cache(
-            params, token[:, None], pos[None], cache, cfg
-        )
-        return _pick(logits, step_key, temperature), cache
+        with jax.named_scope("generate.step"):
+            logits, cache = _forward_with_cache(
+                params, token[:, None], pos[None], cache, cfg
+            )
+            return _pick(logits, step_key, temperature), cache
 
     return step
 

@@ -310,4 +310,5 @@ def loss_fn(params: Dict[str, Any], tokens: jax.Array, cfg: ModelConfig) -> jax.
 
 @functools.partial(jax.jit, static_argnums=(2,))
 def forward_jit(params, tokens, cfg: ModelConfig):
-    return forward(params, tokens, cfg)
+    with jax.named_scope("model.forward"):
+        return forward(params, tokens, cfg)

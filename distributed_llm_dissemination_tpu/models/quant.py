@@ -289,15 +289,16 @@ def _decode_qblobs_impl(blobs_u8, specs: Tuple[Spec, ...], dtype_name: str):
         rows, cols = _rows_cols(shape)
         sb = rows * _SCALE_DT().itemsize  # one wire format: host's widths
         leaves = []
-        for blob in blobs_u8:
-            sraw = jax.lax.slice(blob, (off,), (off + sb,))
-            scale = serde._bytes_to_wide(sraw, sdt)  # (rows,)
-            qraw = jax.lax.slice(blob, (off + sb,),
-                                 (off + sb + rows * cols,))
-            q = serde._bytes_to_wide(qraw, jnp.int8).reshape(rows, cols)
-            x = (q.astype(jnp.float32) * scale[:, None]).astype(dt)
-            leaves.append(x.reshape(shape))
-        out[name] = jnp.stack(leaves)
+        with jax.named_scope(f"decode.qblobs/{name}"):
+            for blob in blobs_u8:
+                sraw = jax.lax.slice(blob, (off,), (off + sb,))
+                scale = serde._bytes_to_wide(sraw, sdt)  # (rows,)
+                qraw = jax.lax.slice(blob, (off + sb,),
+                                     (off + sb + rows * cols,))
+                q = serde._bytes_to_wide(qraw, jnp.int8).reshape(rows, cols)
+                x = (q.astype(jnp.float32) * scale[:, None]).astype(dt)
+                leaves.append(x.reshape(shape))
+            out[name] = jnp.stack(leaves)
         off += sb + rows * cols
     return out
 

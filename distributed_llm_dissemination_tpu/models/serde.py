@@ -235,10 +235,11 @@ def _decode_blobs_impl(blobs_u8: Tuple[jax.Array, ...], specs: Tuple[Spec, ...],
     for name, shape in specs:
         n = int(np.prod(shape)) * dt.itemsize
         leaves = []
-        for blob in blobs_u8:
-            leaf = jax.lax.slice(blob, (off,), (off + n,))
-            leaves.append(_bytes_to_wide(leaf, dt).reshape(shape))
-        out[name] = jnp.stack(leaves)
+        with jax.named_scope(f"decode.blobs/{name}"):
+            for blob in blobs_u8:
+                leaf = jax.lax.slice(blob, (off,), (off + n,))
+                leaves.append(_bytes_to_wide(leaf, dt).reshape(shape))
+            out[name] = jnp.stack(leaves)
         off += n
     return out
 

@@ -187,8 +187,8 @@ class PlanWindow:
     fail.  ``submit`` blocks (backpressure) when the window is full, so
     device memory stays bounded.
 
-    The collective wall time of each plan (submit → device-ready) lands
-    in the ``collective`` phase bucket (``utils.trace``)."""
+    The collective wall time of each plan (submit → device-ready) is a
+    ``fabric.collective`` span (``utils.trace``)."""
 
     def __init__(self, max_plans: int = 4, byte_budget: int = 2 << 30):
         self.max_plans = max(1, max_plans)
@@ -238,11 +238,18 @@ class PlanWindow:
                 label, arr, nbytes, on_ready, on_error, t0 = self._q[0]
             err = None
             try:
-                jax.block_until_ready(arr)
+                # the retirement thread's share of ``fabric.collective``
+                # (which starts at submit, on the submitter's thread)
+                with trace.span("fabric.collective.wait",
+                                id=f"plan.{label}"):
+                    jax.block_until_ready(arr)
             except Exception as e:  # noqa: BLE001 — surface via callback
                 err = e
-            dt = time.monotonic() - t0
-            trace.add_phase("collective", dt)
+            t1 = time.monotonic()
+            dt = t1 - t0
+            # submit → device-ready, ended by the block_until_ready above
+            trace.span_at("fabric.collective", t0, t1, id=f"plan.{label}",
+                          bytes=nbytes)
             with self._cond:
                 # Popped for CAPACITY before the callback runs (the next
                 # submit may proceed), but drain() also waits on

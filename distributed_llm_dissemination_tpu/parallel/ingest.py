@@ -104,9 +104,10 @@ def _concat_pad_impl(pieces, pad: int):
     """Splice offset-ordered pieces into one padded span buffer — a single
     compiled HBM-local concat (cached per piece-shape tuple, which repeats
     across a run's layers: every layer of a model shares its flow split)."""
-    buf = pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces)
-    if buf.shape[0] < pad:
-        buf = jnp.pad(buf, (0, pad - buf.shape[0]))
+    with jax.named_scope("ingest.splice"):
+        buf = pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces)
+        if buf.shape[0] < pad:
+            buf = jnp.pad(buf, (0, pad - buf.shape[0]))
     return buf
 
 
@@ -147,10 +148,18 @@ class ShardedLayerIngest:
     """
 
     def __init__(self, total_bytes: int, devices: Sequence[jax.Device],
-                 stream: Optional[bool] = None):
+                 stream: Optional[bool] = None, trace_id=None, node=None):
         if total_bytes <= 0:
             raise ValueError("empty layer")
         self.total = total_bytes
+        # What this ingest's spans (``ingest.write``,
+        # ``ingest.finalize.*``) are filed under: the blob's pair id and
+        # the seat, when the owner knows them.
+        self.trace_id = trace_id
+        self.node = node
+        # Seconds ``_span_buffers`` spent blocked on coverage and
+        # in-flight writes (no byte moves in them).
+        self.waited_s = 0.0
         self.devices = list(devices)
         n = len(self.devices)
         # One span per device; spans differ by <=1 byte, buffers are padded
@@ -249,6 +258,11 @@ class ShardedLayerIngest:
             raise ValueError(
                 f"fragment [{offset}, {end}) outside layer of {self.total} bytes"
             )
+        with trace.span("ingest.write", id=self.trace_id, node=self.node,
+                        offset=offset, bytes=length):
+            self._write(offset, end, data, is_device)
+
+    def _write(self, offset: int, end: int, data, is_device: bool) -> None:
         with self._lock:
             if self._closed:
                 # A late duplicate racing finalize: its bytes are already
@@ -371,10 +385,13 @@ class ShardedLayerIngest:
         staged halves of the terminal gather.  The shared head of
         ``finalize`` and ``finalize_many``."""
         with self._lock:
-            self._complete.wait_for(
-                lambda: self._failed or self._cov.complete(self.total),
-                timeout=timeout,
-            )
+            with trace.span("ingest.finalize.wait", id=self.trace_id,
+                            node=self.node) as waited:
+                self._complete.wait_for(
+                    lambda: self._failed or self._cov.complete(self.total),
+                    timeout=timeout,
+                )
+            self.waited_s = waited.seconds
             self._closed = True  # any write from here on is a no-op
             if self._failed:
                 raise RuntimeError("ingest failed; fall back to bulk staging")
@@ -393,7 +410,9 @@ class ShardedLayerIngest:
             # _closed guarantees nothing writes the buffers ever again.
             return [hostmem.adopt_as_device_array(b, d)
                     for b, d in zip(self._host, self.devices)]
-        bufs = [self._splice(r, pieces[r]) for r in range(n)]
+        with trace.span("ingest.finalize.splice", id=self.trace_id,
+                        node=self.node):
+            bufs = [self._splice(r, pieces[r]) for r in range(n)]
         with self._lock:
             # Early free: the piece originals are only retained for
             # salvage; the spliced buffers carry the same committed
@@ -410,8 +429,7 @@ class ShardedLayerIngest:
         The returned array's device work may still be in flight — callers
         that must not ack unreal bytes block on it (or hand it to a
         ``fabric.PlanWindow``)."""
-        with trace.phase("splice"):
-            bufs = self._span_buffers(timeout)
+        bufs = self._span_buffers(timeout)
         n = len(self.devices)
         if n == 1:  # split_offsets(total, 1): pad == gpad == total
             return bufs[0]
@@ -451,14 +469,13 @@ def finalize_many(ingests: Sequence["ShardedLayerIngest"],
         # No gather to batch: each finalize is already collective-free.
         return [ing.finalize(timeout) for ing in ingests]
     k = len(ingests)
-    with trace.phase("splice"):
-        per_ingest = [ing._span_buffers(timeout) for ing in ingests]
-        # Device-local stacking: K gpad-sized tiles back to back.  The
-        # inputs are committed to device r, so the concat runs there.
-        shards = [
-            jnp.concatenate([per_ingest[i][r] for i in range(k)])
-            for r in range(n)
-        ]
+    per_ingest = [ing._span_buffers(timeout) for ing in ingests]
+    # Device-local stacking: K gpad-sized tiles back to back.  The
+    # inputs are committed to device r, so the concat runs there.
+    shards = [
+        jnp.concatenate([per_ingest[i][r] for i in range(k)])
+        for r in range(n)
+    ]
     mesh = flat_mesh(first.devices)
     v = jax.make_array_from_single_device_arrays(
         (n * k * first.gpad,), NamedSharding(mesh, P("ingest")), shards

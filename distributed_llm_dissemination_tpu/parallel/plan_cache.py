@@ -29,6 +29,7 @@ import threading
 import time
 from typing import Callable, Dict, Hashable
 
+from ..utils import trace
 from ..utils.logging import log
 
 # Pads below this round up to exactly this (one bucket for all tiny
@@ -73,7 +74,8 @@ class ExecutableCache:
                 return self._store[key]
             self.misses += 1
             t0 = time.monotonic()
-            built = builder()
+            with trace.span("fabric.compile", kind=self.kind):
+                built = builder()
             dt = time.monotonic() - t0
             self.build_s += dt
             self._store[key] = built

@@ -231,7 +231,8 @@ class SpmdFabric:
             log.error("spmd fabric plan failed", plan=plan_id, err=repr(e))
             res.resolve(error=e)
             return
-        trace.add_phase("collective", _time.monotonic() - t0)
+        trace.span_at("fabric.collective", t0, _time.monotonic(),
+                      id=f"plan.{plan_id}", node=self.my_node, bytes=_sz)
         res.resolve(value=value)
 
     def _run(self) -> None:
@@ -401,7 +402,8 @@ class SpmdFabric:
         from ..utils import trace
 
         shards = []
-        with trace.phase("upload"):
+        with trace.span("fabric.upload", id=f"plan.{msg.plan_id}",
+                        node=self.my_node):
             for rank, dev in enumerate(flat):
                 if dev.process_index != proc:
                     continue

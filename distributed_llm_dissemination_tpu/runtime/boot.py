@@ -177,12 +177,13 @@ def verify_blob_digest(blob_id: int, src, digest_lookup,
         return
     from ..utils import integrity
 
-    ok, dt, got = integrity.digest_check(
-        memoryview(src.inmem_data)[src.offset : src.offset + src.data_size],
-        expected)
+    with trace.span("wire.digest", bytes=src.data_size, at="boot"):
+        ok, dt, got = integrity.digest_check(
+            memoryview(src.inmem_data)[
+                src.offset : src.offset + src.data_size],
+            expected)
     if ok is None:
         return  # xxh3 stamp, no xxhash here: advisory skip
-    trace.add_phase("integrity_digest", dt)
     if not ok:
         trace.count("integrity.digest_mismatch")
         raise ValueError(
@@ -401,100 +402,106 @@ def boot_from_layers(
     stream_wait_s = 0.0
     if stager is not None:
         t_w = time.monotonic()
-        streamed = stager.collect(held)
+        with trace.span("boot.wait_stream", node=node_id, blobs=len(held)):
+            streamed = stager.collect(held)
         stream_wait_s = time.monotonic() - t_w
     stacked = None
     via = ""
-    if streamed:
-        try:
-            missing = [lid for lid in layer_ids if lid not in streamed]
-            for lid in missing:
-                # Infill: the stager missed this blob (a per-blob
-                # failure, or collect hit its timeout) — run the SAME
-                # per-blob staging here, so one bad blob costs one
-                # inline decode, never a whole-model host reassembly
-                # (the stager may already have released the OTHER
-                # blobs' device copies).
-                streamed[lid] = stage_blob_leaves(
-                    cfg, lid, layers[lid], codec=codec, sharding=sharding)
-            specs = serde.layer_param_specs(cfg)
-            stacked = {
-                name: jnp.concatenate(
-                    [streamed[lid][name] for lid in layer_ids])
-                for name, _ in specs
-            }
-            # The decoded params exist: blobs whose device copy the boot
-            # may consume are released now (same bookkeeping as the
-            # donated bulk decode — host fallbacks keep serving late
-            # readers).
-            for lid in held:
-                if lid in streamed and blob_donate_ok(layers[lid]):
+    with trace.span("boot.assemble", node=node_id, blobs=len(held)) as asm:
+        if streamed:
+            try:
+                missing = [lid for lid in layer_ids if lid not in streamed]
+                for lid in missing:
+                    # Infill: the stager missed this blob (a per-blob
+                    # failure, or collect hit its timeout) — run the SAME
+                    # per-blob staging here, so one bad blob costs one
+                    # inline decode, never a whole-model host reassembly
+                    # (the stager may already have released the OTHER
+                    # blobs' device copies).
+                    streamed[lid] = stage_blob_leaves(
+                        cfg, lid, layers[lid], codec=codec, sharding=sharding)
+                specs = serde.layer_param_specs(cfg)
+                with jax.named_scope("boot.assemble"):
+                    stacked = {
+                        name: jnp.concatenate(
+                            [streamed[lid][name] for lid in layer_ids])
+                        for name, _ in specs
+                    }
+                # The decoded params exist: blobs whose device copy the boot
+                # may consume are released now (same bookkeeping as the
+                # donated bulk decode — host fallbacks keep serving late
+                # readers).
+                for lid in held:
+                    if lid in streamed and blob_donate_ok(layers[lid]):
+                        layers[lid].device_array = None
+                        dev_blobs[lid] = None
+                via = ("streamed per-layer" if not missing
+                       else f"streamed per-layer (+{len(missing)} infilled)")
+            except Exception as e:  # noqa: BLE001 — bulk assembly still works
+                log.warn("streamed assembly failed; bulk assembly instead",
+                         err=repr(e))
+                trace.count("device.degraded.stream_assembly")
+                stacked = None
+        if stacked is None and all(
+                dev_blobs[lid] is not None for lid in layer_ids):
+            donate = all(blob_donate_ok(layers[lid]) for lid in layer_ids)
+            stacked = quant.stacked_from_device(
+                cfg, [dev_blobs[lid] for lid in layer_ids], codec, donate=donate
+            )
+            via = "device bitcast" if codec == "raw" else f"device {codec} dequant"
+            if donate:
+                for lid in layer_ids:
                     layers[lid].device_array = None
                     dev_blobs[lid] = None
-            via = ("streamed per-layer" if not missing
-                   else f"streamed per-layer (+{len(missing)} infilled)")
-        except Exception as e:  # noqa: BLE001 — bulk assembly still works
-            log.warn("streamed assembly failed; bulk assembly instead",
-                     err=repr(e))
-            trace.count("device.degraded.stream_assembly")
-            stacked = None
-    if stacked is None and all(
-            dev_blobs[lid] is not None for lid in layer_ids):
-        donate = all(blob_donate_ok(layers[lid]) for lid in layer_ids)
-        stacked = quant.stacked_from_device(
-            cfg, [dev_blobs[lid] for lid in layer_ids], codec, donate=donate
-        )
-        via = "device bitcast" if codec == "raw" else f"device {codec} dequant"
-        if donate:
-            for lid in layer_ids:
-                layers[lid].device_array = None
-                dev_blobs[lid] = None
-            via += " (donated)"
-    elif stacked is None:
-        blobs = {
-            lid: (
-                layers[lid].inmem_data
-                if layers[lid].inmem_data is not None
-                else layers[lid].read_bytes()
-            )
-            for lid in layer_ids
-        }
-        host = quant.stacked_from_blobs_host(cfg, blobs, layer_ids, codec)
-        stacked = {
-            name: jax.device_put(a, sharding) if sharding is not None
-            else jnp.asarray(a)
-            for name, a in host.items()
-        }
-        via = VIA_HOST_ASSEMBLY
-
-    if full:
-        head_on_device = dev_blobs[head_id] is not None
-        if head_id in streamed:
-            head = {name: a[0] for name, a in streamed[head_id].items()}
-            head_on_device = True  # streamed leaves are already placed
-        else:
-            head = decode_head(cfg, layers[head_id], codec,
-                               donate=blob_donate_ok(layers[head_id]))
-        if not head_on_device:
-            # Host-decoded leaves: place per the stage sharding.
-            head = {
+                via += " (donated)"
+        elif stacked is None:
+            blobs = {
+                lid: (
+                    layers[lid].inmem_data
+                    if layers[lid].inmem_data is not None
+                    else layers[lid].read_bytes()
+                )
+                for lid in layer_ids
+            }
+            host = quant.stacked_from_blobs_host(cfg, blobs, layer_ids, codec)
+            stacked = {
                 name: jax.device_put(a, sharding) if sharding is not None
                 else jnp.asarray(a)
-                for name, a in head.items()
+                for name, a in host.items()
             }
-        params = {
-            "embed": head["embed"],
-            "layers": stacked,
-            "ln_f": head["ln_f"],
-            "lm_head": head["lm_head"],
-        }
+            via = VIA_HOST_ASSEMBLY
+
+        if full:
+            head_on_device = dev_blobs[head_id] is not None
+            if head_id in streamed:
+                head = {name: a[0] for name, a in streamed[head_id].items()}
+                head_on_device = True  # streamed leaves are already placed
+            else:
+                head = decode_head(cfg, layers[head_id], codec,
+                                   donate=blob_donate_ok(layers[head_id]))
+            if not head_on_device:
+                # Host-decoded leaves: place per the stage sharding.
+                head = {
+                    name: jax.device_put(a, sharding) if sharding is not None
+                    else jnp.asarray(a)
+                    for name, a in head.items()
+                }
+            params = {
+                "embed": head["embed"],
+                "layers": stacked,
+                "ln_f": head["ln_f"],
+                "lm_head": head["lm_head"],
+            }
+        asm.set(via=via)
+    if full:
         if tokens is None:
             tokens = jnp.zeros((1, 16), jnp.int32)
         # forward_jit is the module-level jitted forward: when a
         # BootHintMsg precompile already lowered this shape, the call
         # below is a cache hit and TTFT drops by the compile time.
-        logits = forward_jit(params, tokens, cfg)
-        jax.block_until_ready(logits)
+        with trace.span("boot.first_forward", node=node_id, kind="full"):
+            logits = forward_jit(params, tokens, cfg)
+            jax.block_until_ready(logits)
         # TTFT stops HERE: the decode below is serving time, not boot
         # time — it must not contaminate the metric reported next to TTD.
         dt = time.monotonic() - t0
@@ -511,8 +518,9 @@ def boot_from_layers(
     x = jnp.zeros((1, 16, cfg.d_model), cfg.dtype)
     if sharding is not None:
         x = jax.device_put(x, sharding)
-    acts = _stage_forward_jitted()(stacked, x, cfg)
-    jax.block_until_ready(acts)
+    with trace.span("boot.first_forward", node=node_id, kind="stage"):
+        acts = _stage_forward_jitted()(stacked, x, cfg)
+        jax.block_until_ready(acts)
     dt = time.monotonic() - t0
     log.info("pipeline stage booted from disseminated layers", kind="stage",
              layers=len(layer_ids), via=via, ttft_ms=round(dt * 1000, 1),

@@ -5612,11 +5612,20 @@ class FlowRetransmitLeaderNode(RetransmitLeaderNode):
                                    for d in demands}
         if gaps_by_pair:
             jobs = self._remap_resumed_jobs(jobs, gaps_by_pair)
-        solve_ms = round((time.monotonic() - t0) * 1000, 3)
+        t_solved = time.monotonic()
+        solve_ms = round((t_solved - t0) * 1000, 3)
+        plan = f"plan.g{self._plan_gen}"
+        trace.span_at("plan.solve", t0, t_solved, id=plan,
+                      node=self.node.my_id, predicted_ms=t)
         with self._lock:
             if not self.predicted_ttd_ms and t > 0:
                 self.predicted_ttd_ms = t
                 self.solve_ms = solve_ms
+                # the run's first plan: the timer started → its jobs are
+                # about to go out
+                if self._t_start is not None:
+                    trace.span_at("plan.dispatch", self._t_start, t_solved,
+                                  id=plan, node=self.node.my_id)
         log.info(
             "Job assignment completed",
             computation_ms=solve_ms,

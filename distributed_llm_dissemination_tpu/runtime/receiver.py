@@ -1248,6 +1248,18 @@ class ReceiverNode:
         if self._boot_stager is not None:
             self._boot_stager.invalidate(lid)
 
+    def _pair(self, lid) -> str:
+        """The span id every span of one of this node's blobs shares."""
+        return telemetry.span_id(self.node.my_id, lid)
+
+    def _note_queue_wait(self, msg: LayerMsg) -> None:
+        """``wire.queue``: the transport landed the frame → a handler
+        took its ``LayerMsg`` off the queue (now)."""
+        if msg.landed_mono:
+            trace.span_at("wire.queue", msg.landed_mono, _time.monotonic(),
+                          id=msg.span_id or self._pair(msg.layer_id),
+                          node=self.node.my_id, src=msg.src_id)
+
     def _expected_digest(self, lid):
         """The leader-stamped digest for a layer, falling back to this
         node's own announced digest (a seeder re-verifying its copy).
@@ -1336,10 +1348,11 @@ class ReceiverNode:
         with self._lock:
             if lid in self._digest_ok:
                 return True
-        ok, dt, got = integrity.digest_check(data, expected)
+        with trace.span("wire.digest", id=self._pair(lid),
+                        node=self.node.my_id, bytes=len(data)):
+            ok, dt, got = integrity.digest_check(data, expected)
         if ok is None:
             return True  # xxh3 stamp, no xxhash here: advisory skip
-        trace.add_phase("integrity_digest", dt)
         if ok:
             with self._lock:
                 self._digest_ok.add(lid)
@@ -1405,8 +1418,9 @@ class ReceiverNode:
         raw = plane.delta_reconstruct(lid, data, codec)
         if raw is None:
             return None
-        ok, dt, got = integrity.digest_check(memoryview(raw), full)
-        trace.add_phase("integrity_digest", dt)
+        with trace.span("wire.digest", id=self._pair(lid),
+                        node=self.node.my_id, bytes=len(raw)):
+            ok, dt, got = integrity.digest_check(memoryview(raw), full)
         if ok is False:
             trace.count("integrity.digest_mismatch")
             log.error("delta reconstruction failed the canonical "
@@ -1678,8 +1692,10 @@ class ReceiverNode:
             with self._lock:
                 digest = self.layer_digests.get(lid, "")
             if digest:
-                ok, dt, got = integrity.digest_check(
-                    memoryview(data), digest)
+                with trace.span("wire.digest", id=self._pair(lid),
+                                node=self.node.my_id, bytes=len(data)):
+                    ok, dt, got = integrity.digest_check(
+                        memoryview(data), digest)
                 if ok is False:
                     trace.count("pod.materialize_failed")
                     log.error("pod-gathered tree failed the stamped "
@@ -1687,7 +1703,6 @@ class ReceiverNode:
                               "holding", layerID=lid, expected=digest,
                               got=got)
                     return
-                trace.add_phase("integrity_digest", dt)
         with self._lock:
             src = self.layers.get(lid)
             if src is not None and not src.meta.shard:
@@ -1773,12 +1788,17 @@ class ReceiverNode:
                 return src.meta.location
         try:
             t0 = _time.monotonic()
-            self._stage_layer_device(layer_id, src, ingest)
+            wait_s = self._stage_layer_device(layer_id, src, ingest)
             dt = _time.monotonic() - t0
+            # ``stage_ms`` is the whole call; ``wait_ms`` the part of it
+            # the finalize spent blocked on coverage and in-flight
+            # writes, which moves no byte: ``gbps`` leaves it out.
             log.info("layer staged to HBM", layerID=layer_id,
                      via="incremental ingest" if ingest is not None else "bulk",
                      stage_ms=round(dt * 1000, 1),
-                     gbps=round(src.data_size / max(dt, 1e-9) / 1e9, 3),
+                     wait_ms=round(wait_s * 1000, 1),
+                     gbps=round(src.data_size / max(dt - wait_s, 1e-9)
+                                / 1e9, 3),
                      devices=_device_names(src.device_array))
             return LayerLocation.HBM
         except Exception as e:  # noqa: BLE001 — delivery beats staging
@@ -1791,20 +1811,24 @@ class ReceiverNode:
             with self._lock:
                 self._hbm_staging.pop(layer_id, None)
 
-    def _stage_layer_device(self, layer_id, src, ingest=None) -> None:
+    def _stage_layer_device(self, layer_id, src, ingest=None) -> float:
         """The actual device landing (called once per layer, under the
         staging guard).  Priority: finalize an incremental ingest (the
         bytes are already on-mesh — one ICI all-gather remains); else a
         one-shot sharded ingest onto the stage's devices; else the plain
-        single-device mover."""
+        single-device mover.  Returns the seconds an ingest finalize
+        spent blocked on coverage (0 on the other paths)."""
         if ingest is not None:
             try:
-                arr = ingest.finalize()
-                arr.block_until_ready()
+                with trace.span("ingest.finalize", id=self._pair(layer_id),
+                                node=self.node.my_id, bytes=src.data_size):
+                    arr = ingest.finalize()
+                    with trace.span("ingest.finalize.ready"):
+                        arr.block_until_ready()
                 with self._lock:
                     src.device_array = arr
                     src.meta.location = LayerLocation.HBM
-                return
+                return ingest.waited_s
             except Exception as e:  # noqa: BLE001 — fall back to bulk path
                 log.error("ingest finalize failed; bulk staging instead",
                           layerID=layer_id, err=repr(e))
@@ -1820,8 +1844,9 @@ class ReceiverNode:
             with self._lock:
                 src.device_array = arr
                 src.meta.location = LayerLocation.HBM
-            return
+            return 0.0
         self._mover.stage(src)
+        return 0.0
 
     def handle_layer(self, msg: LayerMsg) -> None:
         """Store to RAM, ack the leader (node.go:1354-1384).  A re-plan
@@ -1834,6 +1859,7 @@ class ReceiverNode:
         BEFORE it is stored or acked — a
         mismatch (per-fragment CRC passed, so the SOURCE's bytes are
         bad) drops the frame and NACKs the sender for a retransmit."""
+        self._note_queue_wait(msg)
         with self._lock:
             src = self.layers.get(msg.layer_id)
         stored = False
@@ -2261,7 +2287,10 @@ class ReceiverNode:
             from ..parallel.ingest import finalize_many
 
             try:
-                arrs = finalize_many([ing for _, ing, _, _, _ in ready])
+                with trace.span("fabric.splice",
+                                id=f"batch.{msgs[0].batch_id}",
+                                node=self.node.my_id, plans=len(ready)):
+                    arrs = finalize_many([ing for _, ing, _, _, _ in ready])
             except Exception as e:  # noqa: BLE001 — solo finalize still works
                 log.warn("batched finalize unavailable; per-plan gathers",
                          batch=msgs[0].batch_id, err=repr(e))
@@ -2309,7 +2338,9 @@ class ReceiverNode:
             from ..parallel.ingest import ShardedLayerIngest
 
             devices = self.placement.devices_for_node(self.node.my_id)
-            ingest = ShardedLayerIngest(msg.total_size, devices)
+            ingest = ShardedLayerIngest(
+                msg.total_size, devices,
+                trace_id=self._pair(msg.layer_id), node=self.node.my_id)
             for off, data in local:
                 ingest.write(off, data)
         except Exception as e:  # noqa: BLE001 — fall through to host path
@@ -2326,27 +2357,35 @@ class ReceiverNode:
         upload_s = 0.0
         try:
             try:
-                for off, arr in self.fabric.collect(
-                    msg.plan_id, len(msg.layout),
-                    timeout=self.FABRIC_COLLECT_TIMEOUT,
-                ):
-                    if ingest_alive:
-                        try:
-                            t_up = _time.monotonic()
-                            ingest.write(off, arr)
-                            upload_s += _time.monotonic() - t_up
-                            continue
-                        except Exception as e:  # noqa: BLE001
-                            log.error("fabric ingest write failed; will "
-                                      "assemble on host",
-                                      layerID=msg.layer_id, err=repr(e))
-                            ingest_alive = False
-                    import jax
-                    import numpy as np
+                # ``fabric.collect``: this plan's contributions, as they
+                # arrive — the waits for the sender seats included; each
+                # fragment's write is its child ``fabric.upload``.
+                with trace.span("fabric.collect",
+                                id=f"plan.{msg.plan_id}",
+                                node=self.node.my_id,
+                                fragments=len(msg.layout)):
+                    for off, arr in self.fabric.collect(
+                        msg.plan_id, len(msg.layout),
+                        timeout=self.FABRIC_COLLECT_TIMEOUT,
+                    ):
+                        if ingest_alive:
+                            try:
+                                t_up = _time.monotonic()
+                                with trace.span("fabric.upload"):
+                                    ingest.write(off, arr)
+                                upload_s += _time.monotonic() - t_up
+                                continue
+                            except Exception as e:  # noqa: BLE001
+                                log.error("fabric ingest write failed; will "
+                                          "assemble on host",
+                                          layerID=msg.layer_id, err=repr(e))
+                                ingest_alive = False
+                        import jax
+                        import numpy as np
 
-                    host_frags.append(
-                        (off, np.asarray(jax.device_get(arr)).tobytes())
-                    )
+                        host_frags.append(
+                            (off, np.asarray(jax.device_get(arr)).tobytes())
+                        )
             finally:
                 self.fabric.discard(msg.plan_id)
         except Exception as e:  # noqa: BLE001 — bytes missing: can't deliver
@@ -2354,10 +2393,6 @@ class ReceiverNode:
                       layerID=msg.layer_id, plan=msg.plan_id, err=repr(e))
             self._request_replan()
             return None
-        if upload_s:
-            from ..utils import trace as _trace
-
-            _trace.add_phase("upload", upload_s)
         if ingest_alive:
             return "ingest", (ingest, host_frags, local, upload_s)
         return "host", (local, ingest, host_frags)
@@ -2367,7 +2402,9 @@ class ReceiverNode:
         """Dispatch one plan's finalize gather and hand it to the shared
         in-flight window (the ack fires at retirement)."""
         try:
-            device_arr = ingest.finalize()
+            with trace.span("fabric.splice", id=f"plan.{msg.plan_id}",
+                            node=self.node.my_id):
+                device_arr = ingest.finalize()
         except Exception as e:  # noqa: BLE001
             log.error("fabric finalize failed; assembling on host",
                       layerID=msg.layer_id, err=repr(e))
@@ -2529,6 +2566,7 @@ class ReceiverNode:
         decodes are bounded (SERVE_MAX_CONCURRENT): each holds a KV
         cache, so an unauthenticated flood must hit an immediate
         "busy" refusal, not an unbounded thread/HBM pile-up."""
+        t_arrived = _time.monotonic()
         with self._lock:
             if self._serve_active >= self.SERVE_MAX_CONCURRENT:
                 busy = True
@@ -2550,7 +2588,7 @@ class ReceiverNode:
 
         def _run():
             try:
-                self._serve_generate_req(msg)
+                self._serve_generate_req(msg, t_arrived)
             finally:
                 with self._lock:
                     self._serve_active -= 1
@@ -2560,9 +2598,12 @@ class ReceiverNode:
             name=f"genreq-{self.node.my_id}-{msg.req_id}",
         ).start()
 
-    def _serve_generate_req(self, msg: GenerateReqMsg) -> None:
+    def _serve_generate_req(self, msg: GenerateReqMsg,
+                            t_arrived: float) -> None:
         t0 = _time.monotonic()
         me = self.node.my_id
+        # Every span of one request shares its id.
+        req = f"req.{msg.src_id}.{msg.req_id}"
 
         def reply(tokens=None, error=""):
             # Telemetry (docs/rollout.md): per-REPLICA request latency
@@ -2573,11 +2614,13 @@ class ReceiverNode:
             # replica whose answers crawl out a congested NIC is slow
             # as far as its users (and its SLO) are concerned.
             try:
-                self.node.transport.send(
-                    msg.src_id,
-                    GenerateRespMsg(self.node.my_id, msg.req_id,
-                                    tokens or [], error),
-                )
+                with trace.span("serve.reply", id=req, node=me,
+                                failed=bool(error)):
+                    self.node.transport.send(
+                        msg.src_id,
+                        GenerateRespMsg(self.node.my_id, msg.req_id,
+                                        tokens or [], error),
+                    )
             except (OSError, KeyError, ConnectionError) as e:
                 log.error("generate response send failed",
                           requester=msg.src_id, req=msg.req_id, err=repr(e))
@@ -2632,6 +2675,10 @@ class ReceiverNode:
             reply(error="temperature must be finite and >= 0, "
                         f"got {msg.temperature}")
             return
+        # ``serve.queue``: the request arrived at this node → its
+        # generation starts (the wait for the boot included).
+        t_gen = _time.monotonic()
+        trace.span_at("serve.queue", t_arrived, t_gen, id=req, node=me)
         try:
             import jax
             import jax.numpy as jnp
@@ -2645,31 +2692,34 @@ class ReceiverNode:
             temp = float(msg.temperature)
             prompt_arr = jnp.asarray([list(msg.prompt)], jnp.int32)
             prng = jax.random.key(int(msg.seed)) if temp > 0 else None
-            if os.environ.get("DLD_TOKEN_FLIP", "0") == "1":
-                # Per-TOKEN flip granularity (docs/rollout.md): re-read
-                # the serving tree before every decode step, so an
-                # in-flight generation picks a freshly committed
-                # version up at the NEXT token instead of finishing a
-                # long request on the old one.  Guarded per step: the
-                # tree's blob-version map must be uniform, or the step
-                # refuses (a mixed tree can't happen through the
-                # atomic flip — this is the invariant made executable).
-                def params_fn():
-                    with self._lock:
-                        cur = self.boot_result
-                        version = self.serving_version
-                        tree = dict(self._serving_tree_versions)
-                    ensure_uniform_version(tree, version)
-                    return cur.params, version
+            with trace.span("serve.generate", id=req, node=me,
+                            prompt_tokens=len(msg.prompt),
+                            new_tokens=int(msg.max_new)):
+                if os.environ.get("DLD_TOKEN_FLIP", "0") == "1":
+                    # Per-TOKEN flip granularity (docs/rollout.md): re-read
+                    # the serving tree before every decode step, so an
+                    # in-flight generation picks a freshly committed
+                    # version up at the NEXT token instead of finishing a
+                    # long request on the old one.  Guarded per step: the
+                    # tree's blob-version map must be uniform, or the step
+                    # refuses (a mixed tree can't happen through the
+                    # atomic flip — this is the invariant made executable).
+                    def params_fn():
+                        with self._lock:
+                            cur = self.boot_result
+                            version = self.serving_version
+                            tree = dict(self._serving_tree_versions)
+                        ensure_uniform_version(tree, version)
+                        return cur.params, version
 
-                toks = generate_stepwise(
-                    params_fn, prompt_arr, cfg, int(msg.max_new),
-                    temperature=temp, key=prng)
-            else:
-                toks = generate(
-                    res.params, prompt_arr, cfg, int(msg.max_new),
-                    temperature=temp, key=prng)
-            out = [int(t) for t in jax.device_get(toks)[0]]
+                    toks = generate_stepwise(
+                        params_fn, prompt_arr, cfg, int(msg.max_new),
+                        temperature=temp, key=prng)
+                else:
+                    toks = generate(
+                        res.params, prompt_arr, cfg, int(msg.max_new),
+                        temperature=temp, key=prng)
+                out = [int(t) for t in jax.device_get(toks)[0]]
         except Exception as e:  # noqa: BLE001 — must answer, not vanish
             log.error("generation request failed", requester=msg.src_id,
                       req=msg.req_id, err=repr(e))
@@ -2789,20 +2839,17 @@ class ReceiverNode:
         from .boot import precompile_boot
 
         try:
-            t0 = _time.monotonic()
-            rec = precompile_boot(
-                self.boot_cfg, blob_ids,
-                placement=self.placement, node_id=self.node.my_id,
-                codec=self.boot_codec, device_blobs=self.stage_hbm,
-            )
-            # Compile-overlap accounting: the whole warmup counts into
-            # the precompile bucket; the run overlapped the wire exactly
-            # when it finished before startup arrived.
-            dt = _time.monotonic() - t0
-            overlapped = not self._startup_seen.is_set()
-            trace.add_phase("boot_precompile", dt)
-            if overlapped:
-                trace.add_phase("boot_precompile_in_wire", dt)
+            with trace.span("boot.precompile",
+                            node=self.node.my_id) as sp:
+                rec = precompile_boot(
+                    self.boot_cfg, blob_ids,
+                    placement=self.placement, node_id=self.node.my_id,
+                    codec=self.boot_codec, device_blobs=self.stage_hbm,
+                )
+                # Compile-overlap accounting: the warmup overlapped the
+                # wire exactly when it finished before startup arrived.
+                overlapped = not self._startup_seen.is_set()
+                sp.set(in_wire=overlapped)
             log.info("boot programs precompiled during dissemination",
                      in_wire=overlapped, **rec)
         except Exception as e:  # noqa: BLE001 — advisory: boot compiles cold
@@ -3521,7 +3568,8 @@ class FlowRetransmitReceiverNode(RetransmitReceiverNode):
                     from ..parallel.ingest import ShardedLayerIngest
 
                     ing = ShardedLayerIngest(
-                        total_size, self.placement.devices_for_layer(layer_id)
+                        total_size, self.placement.devices_for_layer(layer_id),
+                        trace_id=self._pair(layer_id), node=self.node.my_id,
                     )
                 except Exception as e:  # noqa: BLE001 — delivery beats staging
                     log.error("device ingest unavailable for layer",
@@ -3710,6 +3758,7 @@ class FlowRetransmitReceiverNode(RetransmitReceiverNode):
         span's device through the layer's ``ShardedLayerIngest`` as it
         arrives, so HBM ingest overlaps the network receive; completion
         runs one ICI all-gather instead of a full-layer device_put."""
+        self._note_queue_wait(msg)
         lid = msg.layer_id
         frag = msg.layer_src
         if (frag.offset < 0
@@ -4079,7 +4128,8 @@ class FlowRetransmitReceiverNode(RetransmitReceiverNode):
         telemetry.span_event(span, "staged", node=self.node.my_id,
                              dest=self.node.my_id, layer=lid,
                              shard=shard)
-        self._send_ack(lid, loc, shard=shard)
+        with trace.span("ingest.ack", id=span, node=self.node.my_id):
+            self._send_ack(lid, loc, shard=shard)
         if shard:
             # Fabric-assisted pod delivery (docs/fabric.md): a verified
             # pod slice enters the on-mesh reconstruction — the FULL

@@ -531,35 +531,39 @@ def contribute_device_plan(
     import jax
     import numpy as np
 
-    devices = placement.devices_for_node(node.my_id)
-    dev_src = getattr(layer, "device_array", None)
-    if dev_src is not None and not (
-        getattr(dev_src, "ndim", 0) == 1 and dev_src.dtype == np.uint8
-    ):
-        dev_src = None  # only raw uint8 blobs slice meaningfully by byte
+    # The sender seat's half of a fabric plan: its upload (or on-device
+    # slice) of every range it contributes, until each is published.
+    with trace.span("fabric.publish", id=f"plan.{msg.plan_id}",
+                    node=node.my_id, ranges=len(mine)):
+        devices = placement.devices_for_node(node.my_id)
+        dev_src = getattr(layer, "device_array", None)
+        if dev_src is not None and not (
+            getattr(dev_src, "ndim", 0) == 1 and dev_src.dtype == np.uint8
+        ):
+            dev_src = None  # only raw uint8 blobs slice meaningfully by byte
 
-    if dev_src is None and sum(size for _, size in mine) * 2 >= layer.data_size:
-        # Contributing most of the layer: upload it whole ONCE and cache
-        # the device copy on the record — a mode-0/1 seeder serving k
-        # destinations (k plans, each a full-layer layout) then pays one
-        # host→HBM upload instead of k, and every later plan or re-plan
-        # slices device-side.  Small byte-range jobs (mode-3 splits) keep
-        # the range-only upload below.
-        dev_src = _upload_cache.get_or_put(layer, msg.layer_id, devices[0])
+        if dev_src is None and sum(size for _, size in mine) * 2 >= layer.data_size:
+            # Contributing most of the layer: upload it whole ONCE and cache
+            # the device copy on the record — a mode-0/1 seeder serving k
+            # destinations (k plans, each a full-layer layout) then pays one
+            # host→HBM upload instead of k, and every later plan or re-plan
+            # slices device-side.  Small byte-range jobs (mode-3 splits) keep
+            # the range-only upload below.
+            dev_src = _upload_cache.get_or_put(layer, msg.layer_id, devices[0])
 
-    for k, (off, size) in enumerate(mine):
-        dev = devices[k % len(devices)]
-        if dev_src is not None:
-            piece = jax.device_put(dev_src[off : off + size], dev)
-        else:
-            # read_span: only the contributed range touches host RAM (a
-            # disk seeder of a multi-GiB layer serves small ranges).
-            piece = jax.device_put(
-                np.frombuffer(layer.read_span(off, size), np.uint8), dev
-            )
-        fabric.publish(msg.plan_id, off, piece)
-        log.debug("published fabric contribution", layerID=msg.layer_id,
-                  plan=msg.plan_id, offset=off, size=size)
+        for k, (off, size) in enumerate(mine):
+            dev = devices[k % len(devices)]
+            if dev_src is not None:
+                piece = jax.device_put(dev_src[off : off + size], dev)
+            else:
+                # read_span: only the contributed range touches host RAM (a
+                # disk seeder of a multi-GiB layer serves small ranges).
+                piece = jax.device_put(
+                    np.frombuffer(layer.read_span(off, size), np.uint8), dev
+                )
+            fabric.publish(msg.plan_id, off, piece)
+            log.debug("published fabric contribution", layerID=msg.layer_id,
+                      plan=msg.plan_id, offset=off, size=size)
 
 
 def handle_flow_retransmit(

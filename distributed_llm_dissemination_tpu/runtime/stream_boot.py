@@ -41,12 +41,6 @@ from typing import Dict, Optional
 from ..utils import env as env_util, trace
 from ..utils.logging import log
 
-# Phase buckets (utils.trace): summed per-blob staging seconds, and the
-# subset that ran while the wire was still active (before startup) —
-# the stage-overlap-achieved evidence the TTFT breakdown table reads.
-PHASE_STREAM_STAGE = "boot_stream_stage"
-PHASE_STREAM_IN_WIRE = "boot_stream_in_wire"
-
 
 class StreamingBootStager:
     """Decode/stage completed blobs concurrently with the receive.
@@ -184,34 +178,45 @@ class StreamingBootStager:
             blob_id, src = item
             leaves = None
             t0 = time.monotonic()
-            try:
-                leaves = self._stage_one(blob_id, src)
-            except Exception as e:  # noqa: BLE001 — boot falls back to bulk
-                log.warn("streamed boot staging failed for blob; bulk "
-                         "assembly will cover it", blobID=blob_id,
-                         err=repr(e))
-                trace.count("device.degraded.stream_stage")
-            dt = time.monotonic() - t0
-            with self._lock:
-                # Store only while the submission marker stands — an
-                # invalidate() (corrupt blob demoted mid-stage) discards
-                # this result; the redelivered copy re-stages.
-                if leaves is not None and blob_id not in self._submitted:
-                    log.warn("discarding staged leaves for invalidated "
-                             "blob", blobID=blob_id)
-                    leaves = None
-                if leaves is not None:
-                    self._staged[blob_id] = leaves
-                in_wire = not self._startup_seen
-                self._pending -= 1
-                if self._pending == 0:
-                    self._done.notify_all()
+            with trace.span("decode.stage", id=self._pair(blob_id),
+                            node=self.node_id) as sp:
+                try:
+                    leaves = self._stage_one(blob_id, src)
+                except Exception as e:  # noqa: BLE001 — boot falls back to bulk
+                    log.warn("streamed boot staging failed for blob; bulk "
+                             "assembly will cover it", blobID=blob_id,
+                             err=repr(e))
+                    trace.count("device.degraded.stream_stage")
+                    sp.set(error=repr(e))
+                dt = time.monotonic() - t0
+                with self._lock:
+                    # Store only while the submission marker stands — an
+                    # invalidate() (corrupt blob demoted mid-stage)
+                    # discards this result; the redelivered copy
+                    # re-stages.
+                    if leaves is not None and blob_id not in self._submitted:
+                        log.warn("discarding staged leaves for invalidated "
+                                 "blob", blobID=blob_id)
+                        leaves = None
+                    if leaves is not None:
+                        self._staged[blob_id] = leaves
+                    in_wire = not self._startup_seen
+                    self._pending -= 1
+                    if self._pending == 0:
+                        self._done.notify_all()
+                # stage-overlap-achieved: the blob staged before startup
+                sp.set(in_wire=in_wire)
             if leaves is not None:
-                trace.add_phase(PHASE_STREAM_STAGE, dt)
-                if in_wire:
-                    trace.add_phase(PHASE_STREAM_IN_WIRE, dt)
                 log.info("layer boot-staged (streamed)", blobID=blob_id,
                          stage_ms=round(dt * 1000, 1), in_wire=in_wire)
+
+    def _pair(self, blob_id) -> Optional[str]:
+        """The blob's pair id (every span of one blob shares it)."""
+        if self.node_id is None:
+            return None
+        from ..utils import telemetry
+
+        return telemetry.span_id(self.node_id, blob_id)
 
     def _sharding(self):
         if (self.placement is not None
@@ -293,6 +298,12 @@ class StreamingBootStager:
     def _gather_one(self, blob_id: int) -> None:
         from ..parallel.collectives import gather_byte_shards
 
+        with trace.span("decode.stage", id=self._pair(blob_id),
+                        node=self.node_id, gather=True) as sp:
+            self._gather_and_stage(blob_id, gather_byte_shards, sp)
+
+    def _gather_and_stage(self, blob_id: int, gather_byte_shards,
+                          sp) -> None:
         t0 = time.monotonic()
         with self._lock:
             rec = self._shards.get(blob_id)
@@ -335,10 +346,8 @@ class StreamingBootStager:
             self._pending -= 1
             if self._pending == 0:
                 self._done.notify_all()
+        sp.set(in_wire=in_wire)
         if out is not None:
-            trace.add_phase(PHASE_STREAM_STAGE, dt)
-            if in_wire:
-                trace.add_phase(PHASE_STREAM_IN_WIRE, dt)
             log.info("layer materialized from shards (on-mesh gather)",
                      blobID=blob_id, gather_ms=round(dt * 1000, 1),
                      in_wire=in_wire, bytes=len(out),

@@ -165,10 +165,11 @@ class InmemTransport(Transport):
                 log.error("recv_tamper hook failed", err=repr(e))
         if reason is None and integrity.wire_crc_enabled():
             t0 = _time.thread_time()
-            ok = integrity.verify_stamp(data, crc=crc, xxh3=xxh3)
+            with trace.span("wire.crc", node=self.node_id,
+                            bytes=len(data)):
+                ok = integrity.verify_stamp(data, crc=crc, xxh3=xxh3)
             if ok is not None:
                 dt = _time.thread_time() - t0
-                trace.add_phase("integrity_crc_recv", dt)
                 telemetry.link_add(message.src_id, self.node_id,
                                    verify_s=dt)
                 if not ok:

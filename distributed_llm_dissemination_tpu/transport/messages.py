@@ -525,6 +525,10 @@ class LayerMsg:
     # recomputation of the deterministic id.
     span_id: str = ""
     span_parent: str = ""
+    # Local only, never on the wire: ``time.monotonic()`` when the
+    # receiving transport finished landing this frame — the start of
+    # its ``wire.queue`` span (0 = not stamped).
+    landed_mono: float = dataclasses.field(default=0.0, compare=False)
 
     msg_type = MsgType.LAYER
 

@@ -641,11 +641,12 @@ class TcpTransport(Transport):
             # seconds, not wall — on a contended host a wall span around
             # a GIL-released hash mostly measures the scheduler.
             t0 = time.thread_time()
-            ok = integrity.verify_stamp(view, crc=header.crc,
-                                        xxh3=header.xxh3)
+            with trace.span("wire.crc", id=self._pair_id(header),
+                            node=self.node_id, bytes=header.layer_size):
+                ok = integrity.verify_stamp(view, crc=header.crc,
+                                            xxh3=header.xxh3)
             if ok is not None:
                 crc_ms = (time.thread_time() - t0) * 1000
-                trace.add_phase("integrity_crc_recv", crc_ms / 1000)
                 if not ok:
                     reason = "crc"
         if reason is None:
@@ -681,7 +682,6 @@ class TcpTransport(Transport):
             rx_stripe_frames=1 if header.stripe_n > 1 else 0,
             rx_placed_frames=1 if placed else 0,
             wire_s=dur_ms / 1000.0, verify_s=crc_ms / 1000.0)
-        telemetry.observe_ms("tcp.rx_frame_ms", dur_ms)
 
     def _receive_layer(self, conn: socket.socket, envelope: dict) -> None:
         header = LayerHeader.from_payload(envelope["payload"])
@@ -704,7 +704,7 @@ class TcpTransport(Transport):
         if placed is not None:
             view, token, abort = placed
             try:
-                self._recv_body(conn, view, header.layer_size)
+                self._recv_body(conn, view, header)
             except BaseException:
                 abort()  # roll the claim back or the layer wedges forever
                 raise
@@ -734,7 +734,7 @@ class TcpTransport(Transport):
                 meta=LayerMeta(location=LayerLocation.INMEM),
             )
             src.placed_token = token
-            self._queue.put(LayerMsg(header.src_id, header.layer_id, src,
+            self._deliver_layer(LayerMsg(header.src_id, header.layer_id, src,
                                      header.total_size,
                                      job_id=header.job_id,
                                      shard=header.shard,
@@ -755,11 +755,11 @@ class TcpTransport(Transport):
             # at :152-164).
             try:
                 _send_frame(pipe_sock, envelope)
-                self._recv_body(conn, view, header.layer_size, pipe_sock)
+                self._recv_body(conn, view, header, pipe_sock)
             finally:
                 pipe_sock.close()
         else:
-            self._recv_body(conn, view, header.layer_size)
+            self._recv_body(conn, view, header)
 
         # The pipe already teed the bytes downstream chunk-by-chunk — a
         # corrupt relay can't be recalled, but the downstream transport
@@ -784,7 +784,7 @@ class TcpTransport(Transport):
             offset=header.offset,
             meta=LayerMeta(location=LayerLocation.INMEM),
         )
-        self._queue.put(
+        self._deliver_layer(
             LayerMsg(header.src_id, header.layer_id, layer_src,
                      header.total_size, job_id=header.job_id,
                      shard=header.shard, codec=header.codec,
@@ -794,23 +794,48 @@ class TcpTransport(Transport):
 
     # --------------------------------------------------------- striped rx
 
+    def _pair_id(self, header: LayerHeader) -> str:
+        """The span id of the delivery pair a frame belongs to: the
+        sender's advisory tag, else minted from what this end knows."""
+        if header.span_id:
+            return header.span_id
+        if self.node_id is None:
+            return f"?.{header.layer_id}"
+        return telemetry.span_id(self.node_id, header.layer_id)
+
     def _recv_body(self, conn: socket.socket, view: memoryview,
-                   n: int, pipe_sock=None) -> None:
+                   header: LayerHeader, pipe_sock=None) -> None:
         """Land a frame body's bytes in ``view`` (socket → destination
         buffer in ONE copy), optionally teeing each chunk to a
         cut-through pipe downstream.  The one receive loop shared by the
-        striped and un-striped paths."""
+        striped and un-striped paths; one ``wire.recv`` span per frame,
+        first to last byte.  The span is wall time, and ``recv_into``
+        blocks while the sender has nothing in flight: its ``cpu`` field
+        is the thread's own CPU seconds inside it (the copy out of the
+        socket), the rest is waiting for the sender."""
+        n = header.layer_size
         got = 0
-        while got < n:
-            if pipe_sock is None:
-                r = conn.recv_into(view[got:], n - got)
-            else:
-                r = conn.recv_into(view[got:], min(_CHUNK, n - got))
-            if r == 0:
-                raise ConnectionError("connection closed mid-body")
-            if pipe_sock is not None:
-                pipe_sock.sendall(view[got : got + r])
-            got += r
+        with trace.span("wire.recv", id=self._pair_id(header),
+                        node=self.node_id, src=header.src_id,
+                        offset=header.offset, bytes=n) as sp:
+            cpu0 = time.thread_time()
+            while got < n:
+                if pipe_sock is None:
+                    r = conn.recv_into(view[got:], n - got)
+                else:
+                    r = conn.recv_into(view[got:], min(_CHUNK, n - got))
+                if r == 0:
+                    raise ConnectionError("connection closed mid-body")
+                if pipe_sock is not None:
+                    pipe_sock.sendall(view[got : got + r])
+                got += r
+            sp.set(cpu=round(time.thread_time() - cpu0, 6))
+
+    def _deliver_layer(self, msg: LayerMsg) -> None:
+        """Hand a landed frame to the receiver's handler queue, stamped
+        with when it landed (the start of its ``wire.queue`` span)."""
+        msg.landed_mono = time.monotonic()
+        self._queue.put(msg)
 
     def _stripe_pipe_sock(self, header: LayerHeader, envelope: dict):
         """Cut-through relay for a STRIPED frame: every stripe of the
@@ -902,8 +927,7 @@ class TcpTransport(Transport):
             if placed is not None:
                 view, token, abort = placed
                 try:
-                    self._recv_body(conn, view, header.layer_size,
-                                           pipe_sock)
+                    self._recv_body(conn, view, header, pipe_sock)
                 except BaseException:
                     abort()
                     raise
@@ -922,7 +946,7 @@ class TcpTransport(Transport):
                 )
                 src.placed_token = token
                 self._log_stripe(header, t0, placed=True, crc_ms=crc_ms)
-                self._queue.put(LayerMsg(
+                self._deliver_layer(LayerMsg(
                     header.src_id, header.layer_id, src, header.total_size,
                     stripe_idx=header.stripe_idx, stripe_n=header.stripe_n,
                     stripe_off=header.stripe_off, job_id=header.job_id,
@@ -935,14 +959,13 @@ class TcpTransport(Transport):
                 # bounce THIS stripe as its own fragment — the receiver's
                 # interval reassembly (or its re-ack path) absorbs it.
                 buf = alloc_recv_buffer(header.layer_size)
-                self._recv_body(conn, memoryview(buf),
-                                header.layer_size, pipe_sock)
+                self._recv_body(conn, memoryview(buf), header, pipe_sock)
                 ok, crc_ms = self._frame_ok(header, memoryview(buf))
                 if not ok:
                     return
                 landed = True
                 self._log_stripe(header, t0, placed=False, crc_ms=crc_ms)
-                self._queue.put(LayerMsg(
+                self._deliver_layer(LayerMsg(
                     header.src_id, header.layer_id,
                     LayerSrc(inmem_data=buf, data_size=header.layer_size,
                              offset=header.offset,
@@ -989,7 +1012,7 @@ class TcpTransport(Transport):
             view = memoryview(rec["buf"])[
                 header.stripe_off : header.stripe_off + header.layer_size]
             try:
-                self._recv_body(conn, view, header.layer_size, pipe_sock)
+                self._recv_body(conn, view, header, pipe_sock)
             except BaseException:
                 with self._lock:
                     rec["inflight"] -= 1
@@ -1020,7 +1043,7 @@ class TcpTransport(Transport):
                     if done is not None:
                         self._stripe_done[key] = time.monotonic()
             if done is not None:
-                self._queue.put(LayerMsg(
+                self._deliver_layer(LayerMsg(
                     header.src_id, header.layer_id,
                     LayerSrc(inmem_data=done["buf"], data_size=done["span"],
                              offset=done["base"],

@@ -20,9 +20,19 @@ old ``utils/trace.py`` globals lacked:
 - **Always-on and cheap**: a dict update under one lock per frame-scale
   event (frames are MiB-scale, so the accounting is noise — measured in
   TTD_MATRIX.md's telemetry-overhead row).  ``DLD_TELEMETRY=0`` disables
-  the LINK recorder and histograms (the overhead A/B knob); phase
-  buckets and event counters stay on — pre-existing harness tables
-  depend on them.
+  the LINK recorder, histograms, lifecycle instants and the interval
+  ring (the overhead A/B knob; ``DLD_SPANS=0`` the last two alone);
+  the phase totals and event counters stay on: harness tables read
+  them.
+- **One span store**: every timed piece of work is an INTERVAL span
+  (``record_span``: name, pair id, parent, start and end on
+  CLOCK_MONOTONIC, thread, node).  Its duration is added to a per-name
+  sum and count (``phase_totals()``: O(1), cumulative over the run),
+  and the record goes into a bounded ring, the window that the entry
+  points write out as their last log records
+  (``utils/trace.dump_spans``).  The pair-lifecycle instants
+  (``span_event``) have a bounded ring of their own, so a run of many
+  frames cannot push them out.
 
 The registry feeds three consumers: ``MetricsReportMsg`` (periodic
 node → leader shipping, ``runtime/receiver.MetricsReporter``), the
@@ -93,17 +103,50 @@ SPAN_PHASES: Tuple[str, ...] = (
     "verified", "staged", "acked", "flipped")
 
 
+# The INTERVAL span vocabulary (docs/observability.md has the table:
+# where each is recorded and what reads it).  ``layer.what[.part]``; a
+# dotted suffix is a child of the span it extends.  The tier-1 static
+# drift check pins every name here to a live ``trace.span`` /
+# ``trace.span_at`` call site and to PERF.md, and every such call site
+# to a name here.
+SPAN_NAMES: Tuple[str, ...] = (
+    "plan.solve", "plan.dispatch",
+    "wire.recv", "wire.crc", "wire.digest", "wire.queue",
+    "ingest.write", "ingest.finalize", "ingest.finalize.wait",
+    "ingest.finalize.splice", "ingest.finalize.ready", "ingest.ack",
+    "decode.stage",
+    "boot.wait_stream", "boot.assemble", "boot.first_forward",
+    "boot.precompile",
+    "serve.queue", "serve.generate", "serve.reply", "serve.request",
+    "serve.pod_forward", "serve.pod_decode",
+    "fabric.compile", "fabric.publish", "fabric.collect", "fabric.upload",
+    "fabric.collective", "fabric.collective.wait", "fabric.splice")
+# Durations still filed through ``trace.add_phase`` (no start kept):
+# sender-side checksum CPU seconds, and the codec plane's encode.
+PHASE_NAMES: Tuple[str, ...] = ("integrity_crc_send", "codec_encode")
+# Compilation counters of the device-holding process
+# (``utils/trace.watch_compiles``).
+XLA_COUNTERS: Tuple[str, ...] = (
+    "xla.compiles", "xla.compile_ms", "xla.cache_hits", "xla.cache_misses")
+
+
 def spans_enabled() -> bool:
     """Span recording's own kill switch (``DLD_SPANS=0`` — the overhead
     A/B knob) on top of the telemetry master switch: spans are part of
-    the flight recorder, so ``DLD_TELEMETRY=0`` silences them too."""
+    the flight recorder, so ``DLD_TELEMETRY=0`` silences them too.
+    Off, no lifecycle instant and no interval record is kept and no
+    profiler annotation is opened; a span then costs its two clock
+    reads and one addition to the phase totals."""
     return (os.environ.get("DLD_SPANS", "1") != "0") and _links_enabled()
 
 
 def span_ring_size() -> int:
-    """Bounded span ring capacity per registry (``DLD_SPAN_RING``).
-    Oldest events drop first — the honest limit docs/observability.md
-    records; ``telemetry.spans_dropped`` counts every drop."""
+    """Capacity of each of the registry's two bounded rings
+    (``DLD_SPAN_RING``): the lifecycle instants' and the interval
+    spans'.  Oldest records drop first — the honest limit
+    docs/observability.md records; ``telemetry.spans_dropped`` counts
+    the instants dropped, ``telemetry.intervals_dropped`` the
+    intervals."""
     try:
         return max(64, int(os.environ.get("DLD_SPAN_RING", "4096")))
     except ValueError:
@@ -129,8 +172,6 @@ class Telemetry:
         self._lock = threading.Lock()
         self._counters: Dict[str, int] = {}
         self._gauges: Dict[str, float] = {}
-        # name -> [sum_s, n]  (the trace.py phase buckets live here now)
-        self._phases: Dict[str, list] = {}
         # name -> {"buckets": [..], "sum_ms": float, "n": int}
         self._hists: Dict[str, dict] = {}
         # (src, dest, job) -> {field: number}.  job "" is the base link
@@ -138,10 +179,17 @@ class Telemetry:
         # files on its own row, so per-job splits are an additive view
         # of the base totals, never a replacement (docs/service.md).
         self._links: Dict[Tuple[int, int, str], Dict[str, float]] = {}
-        # Pair-lifecycle span events (docs/observability.md): a bounded
-        # ring of {"span", "phase", "t_ms", "node", ...} dicts.  Sized
-        # lazily at first event so tests can flip DLD_SPAN_RING.
+        # The span store (docs/observability.md).  Two bounded rings,
+        # oldest out first, sized lazily at first record so tests can
+        # flip DLD_SPAN_RING: pair-lifecycle INSTANTS {"span", "phase",
+        # "t_ms", "mono", "node", ...}, and INTERVAL spans {"name",
+        # "id", "parent", "t0", "t1", "thread", "node", "fields"} on
+        # CLOCK_MONOTONIC — the window a dump writes out.  And the
+        # intervals' cumulative totals, name -> [seconds, count], which
+        # no ring bounds.
+        self._events: Optional[collections.deque] = None
         self._spans: Optional[collections.deque] = None
+        self._phases: Dict[str, list] = {}
 
     # ------------------------------------------------------------ scalars
 
@@ -154,12 +202,11 @@ class Telemetry:
             self._gauges[name] = float(value)
 
     def add_phase(self, name: str, seconds: float) -> None:
-        with self._lock:
-            rec = self._phases.get(name)
-            if rec is None:
-                rec = self._phases[name] = [0.0, 0]
-            rec[0] += seconds
-            rec[1] += 1
+        """A duration whose start nobody kept: an interval span ending
+        now (the writer API of the old phase buckets, kept working
+        through the one store)."""
+        t1 = _time.monotonic()
+        self.record_span({"name": name, "t0": t1 - seconds, "t1": t1})
 
     def observe_ms(self, name: str, ms: float) -> None:
         """One fixed-bucket histogram sample (milliseconds)."""
@@ -197,23 +244,56 @@ class Telemetry:
         if not spans_enabled():
             return
         ev = {"span": str(span), "phase": str(phase),
-              "t_ms": round(_time.time() * 1000.0, 3)}
+              "t_ms": round(_time.time() * 1000.0, 3),
+              "mono": round(_time.monotonic(), 6)}
         if node is not None:
             ev["node"] = int(node)
         for k, v in fields.items():
             if v or v == 0 and k in ("src", "dest", "layer"):
                 ev[k] = v
         with self._lock:
-            ring = self._spans
-            if ring is None:
-                ring = self._spans = collections.deque(
-                    maxlen=span_ring_size())
-            if len(ring) == ring.maxlen:
-                self._counters["telemetry.spans_dropped"] = (
-                    self._counters.get("telemetry.spans_dropped", 0) + 1)
-            ring.append(ev)
+            if self._events is None:
+                self._events = collections.deque(maxlen=span_ring_size())
+            self._ring_append_locked(self._events, ev,
+                                     "telemetry.spans_dropped")
+
+    def _ring_append_locked(self, ring, rec: dict, dropped: str) -> None:
+        if len(ring) == ring.maxlen:
+            self._counters[dropped] = self._counters.get(dropped, 0) + 1
+        ring.append(rec)
+
+    def record_span(self, rec: dict, keep: Optional[bool] = None) -> None:
+        """One finished INTERVAL span.  ``rec`` holds ``name``, ``t0``
+        and ``t1`` (``time.monotonic()``), and whatever of ``id``,
+        ``parent``, ``thread``, ``node``, ``fields`` the writer knows
+        (``utils/trace.span`` fills them).  Its duration always joins
+        the phase totals; the record itself is kept in the ring only
+        while ``spans_enabled()`` (``keep``: the writer's own reading of
+        that switch, taken when the span opened)."""
+        if keep is None:
+            keep = spans_enabled()
+        with self._lock:
+            tot = self._phases.get(rec["name"])
+            if tot is None:
+                tot = self._phases[rec["name"]] = [0.0, 0]
+            tot[0] += rec["t1"] - rec["t0"]
+            tot[1] += 1
+            if keep:
+                if self._spans is None:
+                    self._spans = collections.deque(
+                        maxlen=span_ring_size())
+                self._ring_append_locked(self._spans, rec,
+                                         "telemetry.intervals_dropped")
 
     def span_events(self) -> List[dict]:
+        """The pair-lifecycle instants (what ships in
+        ``MetricsReportMsg`` and what the critical-path walk reads)."""
+        with self._lock:
+            return [dict(ev) for ev in (self._events or ())]
+
+    def interval_spans(self) -> List[dict]:
+        """The interval spans of the ring, oldest first.  Local only:
+        they are written out by the entry points, never shipped."""
         with self._lock:
             return [dict(ev) for ev in (self._spans or ())]
 
@@ -256,8 +336,7 @@ class Telemetry:
                 "counters": dict(self._counters),
                 "gauges": {k: round(v, 3)
                            for k, v in self._gauges.items()},
-                "phases": {name: {"ms": round(s * 1000, 1), "n": n}
-                           for name, (s, n) in sorted(self._phases.items())},
+                "phases": self._phase_totals_locked(),
                 "hists": {name: {"buckets": list(h["buckets"]),
                                  "sum_ms": round(h["sum_ms"], 1),
                                  "n": h["n"]}
@@ -268,17 +347,24 @@ class Telemetry:
                         for k, v in sorted(fields.items())}
                     for (s, d, j), fields in sorted(self._links.items())
                 },
-                "spans": [dict(ev) for ev in (self._spans or ())],
+                "spans": [dict(ev) for ev in (self._events or ())],
             }
 
     def counter_totals(self) -> dict:
         with self._lock:
             return dict(sorted(self._counters.items()))
 
+    def _phase_totals_locked(self) -> dict:
+        return {name: {"ms": round(s * 1000, 1), "n": n}
+                for name, (s, n) in sorted(self._phases.items())}
+
     def phase_totals(self) -> dict:
+        """Every interval span of the run summed by name (thread time:
+        overlapping spans add up).  Cumulative: equal to the ring summed
+        by name until the ring drops its first record, and unaffected
+        by drops after."""
         with self._lock:
-            return {name: {"ms": round(s * 1000, 1), "n": n}
-                    for name, (s, n) in sorted(self._phases.items())}
+            return self._phase_totals_locked()
 
     # -------------------------------------------------------------- reset
 
@@ -286,13 +372,17 @@ class Telemetry:
         with self._lock:
             self._counters.clear()
             self._gauges.clear()
-            self._phases.clear()
             self._hists.clear()
             self._links.clear()
+            self._events = None
             self._spans = None
+            self._phases.clear()
 
     def reset_phases(self) -> None:
+        """Drop the interval spans and their totals (the lifecycle
+        instants stay)."""
         with self._lock:
+            self._spans = None
             self._phases.clear()
 
     def reset_counters(self) -> None:
@@ -337,6 +427,14 @@ def span_event(span: str, phase: str, node=None, **fields) -> None:
 
 def span_events() -> List[dict]:
     return _default.span_events()
+
+
+def record_span(rec: dict, keep: Optional[bool] = None) -> None:
+    _default.record_span(rec, keep)
+
+
+def interval_spans() -> List[dict]:
+    return _default.interval_spans()
 
 
 def snapshot() -> dict:

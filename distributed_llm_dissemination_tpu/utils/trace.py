@@ -1,97 +1,144 @@
-"""In-process span instrumentation over the structured log stream.
+"""The program's own tracing: interval spans, counters, and their dump.
 
-The reference profiles exclusively through timestamped logs
-(``/root/reference/distributor/node.go:1168-1186`` et al.); ``span``
-standardizes that idiom: a context manager that logs completion with a
-``duration_ms`` field, which ``cli/trace.py`` renders as a timeline
-slice.  Zero infrastructure — the logs stay the single source of truth,
-merged across hosts by ``cli/collect_logs.py`` exactly like the
-reference's jq pipeline.
+One primitive (docs/observability.md).  ``span`` times a block where the
+work happens and files ``{name, id, parent, t0, t1, thread, node,
+fields}`` in the run-scoped ring of ``utils/telemetry.py``; ``span_at``
+files an interval whose ends the call site already holds (a wait that
+began on another thread, a ``t0``/``dt`` pair).  ``t0``/``t1`` are
+``time.monotonic()`` — one clock for every process of a host.  ``id`` is
+the pair id ``telemetry.span_id(dest, layer)`` wherever a blob is
+concerned (a request id for serve spans, a plan id for fabric spans), so
+all spans of one blob share an identifier; ``parent`` names the span
+that caused this one and defaults to the span open on this thread.
+
+When ``jax`` is already imported in the process, a ``span`` also runs
+inside ``jax.profiler.TraceAnnotation``: a flag check while no trace is
+running, and during a profiler capture the program's spans land in the
+trace's host plane on the same clock as the device planes.  (``span_at``
+cannot: an annotation is opened and closed by the thread that runs it.)
+
+``phase_totals()`` is every span of the run summed by name (the ring
+summed by name, while the ring has dropped nothing); ``add_phase`` is
+the older writer API and records a span too.  The entry points
+(``cli.main``, ``cli.genreq``, ``cli.podrun.run_pod``) write the ring out
+as their last log records with ``dump_spans``.
+
+``DLD_SPANS=0`` (or ``DLD_TELEMETRY=0``) is the overhead A/B switch: no
+record is kept, no annotation opened and no parent tracked; only the
+totals go on.
 """
 
 from __future__ import annotations
 
-import contextlib
+import sys
+import threading
 import time
 
-from .logging import log
+from . import telemetry as _telemetry
+
+# At most this many spans in one ``"spans"`` log record.
+DUMP_CHUNK = 256
+
+_tls = threading.local()
 
 
-@contextlib.contextmanager
-def span(name: str, **fields):
-    """Time a block and log it as a trace-friendly completion record::
+def _annotation(name: str, span_id):
+    """``jax.profiler.TraceAnnotation`` when jax is already imported (the
+    leader and the seeders of a TCP topology never import it), else
+    None."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    if span_id is None:
+        return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.TraceAnnotation(name, id=str(span_id))
 
-        with span("stage layer", layerID=3):
+
+class span:
+    """Time a block as an interval span::
+
+        with trace.span("ingest.finalize", id=pair, node=me) as sp:
             ...
+            sp.set(bytes=n)
 
-    emits ``{"message": "stage layer", "layerID": 3, "duration_ms": ...}``.
-    The record is logged even when the block raises (with ``error`` set),
-    so traces show failed work instead of omitting it.
-    """
-    t0 = time.monotonic()
-    try:
-        yield
-    except BaseException as e:
-        log.error(name, duration_ms=round((time.monotonic() - t0) * 1000, 3),
-                  error=repr(e), **fields)
-        raise
-    else:
-        log.info(name, duration_ms=round((time.monotonic() - t0) * 1000, 3),
-                 **fields)
+    A span opened inside another on the same thread takes that one's
+    name as ``parent`` and inherits its ``id`` and ``node`` unless given
+    its own.  The span is recorded even when the block raises (with
+    ``error`` among its fields), so a trace shows failed work instead of
+    omitting it."""
+
+    __slots__ = ("rec", "_on", "_ann", "_outer")
+
+    def __init__(self, name: str, id=None, parent=None, node=None,
+                 **fields):
+        self.rec = {"name": name, "id": id, "parent": parent,
+                    "node": node, "fields": fields}
+
+    def set(self, **fields) -> None:
+        self.rec["fields"].update(fields)
+
+    @property
+    def seconds(self) -> float:
+        """The span's length, once it has ended."""
+        return self.rec["t1"] - self.rec["t0"]
+
+    def __enter__(self) -> "span":
+        rec = self.rec
+        self._on = _telemetry.spans_enabled()
+        self._ann = None
+        if self._on:
+            outer = self._outer = getattr(_tls, "open", None)
+            if outer is not None:
+                for key, inherited in (("parent", outer["name"]),
+                                       ("id", outer["id"]),
+                                       ("node", outer["node"])):
+                    if rec[key] is None:
+                        rec[key] = inherited
+            _tls.open = rec
+            self._ann = _annotation(rec["name"], rec["id"])
+            if self._ann is not None:
+                self._ann.__enter__()
+        rec["t0"] = time.monotonic()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        rec = self.rec
+        rec["t1"] = time.monotonic()
+        if self._on:
+            if self._ann is not None:
+                self._ann.__exit__(exc_type, exc, tb)
+            _tls.open = self._outer
+            if exc is not None:
+                rec["fields"]["error"] = repr(exc)
+            rec["thread"] = threading.current_thread().name
+        _telemetry.record_span(rec, self._on)
+        return False
 
 
-# ----------------------------------------------------------- phase markers
-#
-# Cheap in-process phase accounting for the device-fabric plane: the
-# per-plan pipeline (compile / upload / collective / splice) runs across
-# handler threads and async device queues, so wall-clock spans alone
-# can't attribute where a TTD went.  Timed sections call ``add_phase``
-# (or use the ``phase`` context manager); harnesses read the summed
-# totals via ``phase_totals`` — podrun folds them into its summary line,
-# and ``cli/ttd_matrix.py`` renders the fabric row's phase-breakdown
-# table from them.  Sums are thread-time: concurrent phases overlap, so
-# totals may exceed the run's wall clock (the tables say so).
-#
-# STORAGE lives in the run-scoped ``utils/telemetry.py`` registry now —
-# these functions are the stable writer API (every instrumented call
-# site keeps ``trace.add_phase``/``trace.count``), but the sums are no
-# longer process-global module state: ``telemetry.reset_run()`` clears
-# them between runs (the tests' autouse fixture, a promoted standby, a
-# harness's per-trial reset), and ``telemetry.snapshot()`` ships them in
-# MetricsReportMsg / RUN_REPORT.  ``reset_run`` is re-exported here for
-# writers that already import ``trace``.
+def span_at(name: str, t0: float, t1: float, id=None, parent=None,
+            node=None, **fields) -> None:
+    """File an interval whose ends (``time.monotonic()``) the call site
+    already holds."""
+    _telemetry.record_span({
+        "name": name, "id": id, "parent": parent, "node": node,
+        "fields": fields, "t0": t0, "t1": t1,
+        "thread": threading.current_thread().name})
 
-# TTFT buckets (the boot pipeline, ISSUE 3): writers in
-# ``runtime/receiver.py`` and ``runtime/stream_boot.py``; the
-# ``cli/ttd_matrix.py`` physical row renders them as the TTFT breakdown.
-# - ``boot_precompile``          hint-time XLA compile seconds (total)
-# - ``boot_precompile_in_wire``  the subset that finished BEFORE startup
-#                                — compile-overlap-achieved
-# - ``boot_stream_stage``        per-blob streamed decode/upload seconds
-# - ``boot_stream_in_wire``      the subset that ran before startup —
-#                                stage-overlap-achieved
 
-from . import telemetry as _telemetry  # noqa: E402  (storage backend)
+def spans() -> list:
+    """The ring's interval spans, oldest first."""
+    return _telemetry.interval_spans()
 
 
 def add_phase(name: str, seconds: float) -> None:
-    """Accumulate ``seconds`` into the named phase bucket."""
+    """A duration whose start nobody kept, as a span ending now."""
     _telemetry.add_phase(name, seconds)
 
 
-@contextlib.contextmanager
-def phase(name: str):
-    """Time a block into the named phase bucket (recorded even when the
-    block raises — failed work is still attributable work)."""
-    t0 = time.monotonic()
-    try:
-        yield
-    finally:
-        add_phase(name, time.monotonic() - t0)
-
-
 def phase_totals() -> dict:
-    """``{name: {"ms": summed_milliseconds, "n": samples}}`` so far."""
+    """``{name: {"ms": summed_milliseconds, "n": samples}}``: the ring's
+    interval spans summed by name.  Thread time: concurrent spans
+    overlap, so a total may exceed the run's wall clock."""
     return _telemetry.default().phase_totals()
 
 
@@ -101,13 +148,9 @@ def reset_phases() -> None:
 
 # ------------------------------------------------------------ event counters
 #
-# Integrity-plane accounting (docs/integrity.md): how many fragments were
-# dropped for a bad CRC, how many NACKs went out, how many bytes were
-# retransmitted, how many digests mismatched.  Same shape as the phase
-# buckets — in-process sums the harness reads at the end of a run — but
-# counting EVENTS, not seconds.  Writers: transport/tcp.py,
-# transport/inmem.py, runtime/receiver.py, runtime/send.py.  Stored in
-# the run-scoped telemetry registry (see the phase-marker note above).
+# In-process sums of EVENTS (docs/integrity.md and every plane since):
+# CRC drops, NACKs, retransmitted bytes, device-path fallbacks, XLA
+# compilations.  Stored in the run-scoped telemetry registry.
 
 
 def count(name: str, n: int = 1) -> None:
@@ -125,6 +168,73 @@ def reset_counters() -> None:
 
 
 def reset_run() -> None:
-    """Clear ALL run-scoped accounting (phases, counters, gauges,
+    """Clear ALL run-scoped accounting (spans, counters, gauges,
     histograms, per-link flight recorder) — the between-runs reset."""
     _telemetry.reset_run()
+
+
+# ------------------------------------------------------------- compilations
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "xla.cache_hits",
+                 "/jax/compilation_cache/cache_misses": "xla.cache_misses"}
+_watching = False
+
+
+def _on_duration(event: str, seconds: float, **_kw) -> None:
+    if event == _COMPILE_EVENT:
+        count("xla.compiles")
+        count("xla.compile_ms", round(seconds * 1000))
+
+
+def _on_event(event: str, **_kw) -> None:
+    name = _CACHE_EVENTS.get(event)
+    if name is not None:
+        count(name)
+
+
+def watch_compiles() -> None:
+    """Count what XLA compiles in this process from here on, through
+    ``jax.monitoring`` (once per process; imports jax).  ``xla.compiles``
+    / ``xla.compile_ms``: programs that were in no in-memory cache and
+    went to the backend (compiled, or read from the persistent cache),
+    and the whole milliseconds that took;
+    ``xla.cache_hits`` / ``xla.cache_misses``: which of the two."""
+    global _watching
+    if _watching:
+        return
+    _watching = True
+    from jax import monitoring
+
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_listener(_on_event)
+
+
+# -------------------------------------------------------------------- dump
+
+
+def dump_spans(log, since: float = None) -> int:
+    """Write the ring out as log records: ``"spans"`` records of at most
+    ``DUMP_CHUNK`` interval spans each, then one ``"span counters"``
+    record (event counters, how many spans the ring dropped — a dump
+    with ``dropped`` above 0 is a window, not the run — and one reading
+    of both clocks).  ``since``: only spans that ended at or
+    after that ``time.monotonic()`` (a process that never resets its
+    registry between runs).  Returns how many spans were written."""
+    out = []
+    for rec in _telemetry.interval_spans():
+        if since is not None and rec["t1"] < since:
+            continue
+        rec = {k: v for k, v in rec.items() if v not in (None, {})}
+        rec["t0"], rec["t1"] = round(rec["t0"], 6), round(rec["t1"], 6)
+        out.append(rec)
+    parts = max(1, -(-len(out) // DUMP_CHUNK))
+    for i in range(parts):
+        log.info("spans", part=i + 1, of=parts,
+                 spans=out[i * DUMP_CHUNK:(i + 1) * DUMP_CHUNK])
+    counters = counter_totals()
+    log.info("span counters", counters=counters, spans=len(out),
+             dropped=counters.get("telemetry.intervals_dropped", 0),
+             mono=round(time.monotonic(), 6),
+             wall_ms=round(time.time() * 1000.0, 3))
+    return len(out)

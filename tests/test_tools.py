@@ -148,30 +148,36 @@ def test_trace_cli_writes_valid_json(tmp_path, capsys):
 
 
 def test_span_logs_duration():
+    """The span primitive files an interval in the run's ring (it used
+    to log a ``duration_ms`` record that nothing read), error and all,
+    and ``cli.trace`` renders the dumped ring as slices."""
     import io
 
     import pytest as _pytest
 
-    from distributed_llm_dissemination_tpu.utils.logging import log
-    from distributed_llm_dissemination_tpu.utils.trace import span
+    from distributed_llm_dissemination_tpu.cli.trace import to_trace_events
+    from distributed_llm_dissemination_tpu.utils import trace
+    from distributed_llm_dissemination_tpu.utils.logging import JsonLogger
+
+    with trace.span("ingest.write", id="1.7", node=1, layerID=7):
+        pass
+    rec = trace.spans()[-1]
+    assert rec["name"] == "ingest.write" and rec["fields"] == {"layerID": 7}
+    assert rec["t1"] - rec["t0"] >= 0
+
+    with _pytest.raises(ValueError):
+        with trace.span("ingest.ack"):
+            raise ValueError("boom")
+    rec = trace.spans()[-1]
+    assert rec["name"] == "ingest.ack" and "boom" in rec["fields"]["error"]
 
     buf = io.StringIO()
-    old_stream = log.stream
-    log.stream = buf
-    try:
-        with span("unit work", layerID=7):
-            pass
-        rec = json.loads(buf.getvalue().strip().splitlines()[-1])
-        assert rec["message"] == "unit work" and rec["layerID"] == 7
-        assert rec["duration_ms"] >= 0
-
-        with _pytest.raises(ValueError):
-            with span("failing work"):
-                raise ValueError("boom")
-        rec = json.loads(buf.getvalue().strip().splitlines()[-1])
-        assert rec["level"] == "error" and "boom" in rec["error"]
-    finally:
-        log.stream = old_stream
+    assert trace.dump_spans(JsonLogger(node="1", stream=buf)) == 2
+    events = to_trace_events(json.loads(line)
+                             for line in buf.getvalue().splitlines())
+    slices = [e for e in events if e["ph"] == "X"]
+    assert [e["name"] for e in slices] == ["ingest.write", "ingest.ack"]
+    assert slices[0]["args"]["id"] == "1.7" and slices[0]["dur"] >= 0
 
 
 # ----------------------------------------------------------- shipped configs
