@@ -19,7 +19,9 @@ Mapping:
 
 - the entry points' ``"spans"`` dumps (``utils/trace.dump_spans``)
   become one slice per interval span on a per-thread track, placed on
-  the wall clock by the ``"span counters"`` record's clock pair.
+  the wall clock by the ``"span counters"`` record's clock pair; the
+  ``decode.stage`` spans' ``fast_bytes`` / ``slow_bytes`` are added up
+  and printed on stderr (give one round's logs for the round's sum).
 
 Usage:
     python -m distributed_llm_dissemination_tpu.cli.trace logs/ -o run.trace.json
@@ -224,6 +226,22 @@ def interval_span_events(records, offsets: dict) -> List[dict]:
                          if k not in ("name", "t0", "t1", "thread")},
             })
     return events
+
+
+def decode_widen_totals(events: List[dict]) -> dict:
+    """How the logs' device decodes widened their bytes: the
+    ``decode.stage`` slices' ``fast_bytes`` (the kernel) and
+    ``slow_bytes`` (the strided slices) added up, over one round's logs
+    the round's sum (``models/serde.py`` ``widen_split``).  Empty when
+    no such span is there."""
+    fields = [ev["args"].get("fields") or {} for ev in events
+              if ev.get("ph") == "X" and ev.get("name") == "decode.stage"]
+    fields = [f for f in fields if "fast_bytes" in f]
+    if not fields:
+        return {}
+    return {"spans": len(fields),
+            "fast_bytes": sum(f["fast_bytes"] for f in fields),
+            "slow_bytes": sum(f["slow_bytes"] for f in fields)}
 
 
 def to_trace_events(records: Iterable[dict],
@@ -451,6 +469,11 @@ def main(argv: list[str] | None = None) -> int:
 
     events = to_trace_events(iter_records(args.paths),
                              align_clocks=not args.raw_clocks)
+    widened = decode_widen_totals(events)
+    if widened:
+        print("decode.stage widened {fast_bytes} B with the kernel, "
+              "{slow_bytes} B with the strided slices ({spans} spans)"
+              .format(**widened), file=sys.stderr)
     doc = {"traceEvents": events, "displayTimeUnit": "ms"}
     if args.output == "-":
         json.dump(doc, sys.stdout)

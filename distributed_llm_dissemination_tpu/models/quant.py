@@ -497,6 +497,34 @@ def device_decode_jit(codec: str, donate: bool = False):
     return _decode_qblobs_donated if donate else _decode_qblobs
 
 
+def widen_bytes(codec: str, specs: Sequence[Spec],
+                dtype_name: str) -> Tuple[int, int]:
+    """``(fast, slow)`` wire bytes of ONE blob that
+    ``device_decode_jit(codec)`` widens with the kernel and with the
+    strided slices: ``serde.widen_split`` — the rule
+    ``serde._bytes_to_wide`` dispatches on — summed over the byte runs
+    the program hands it.  raw: every leaf, at the model's item size;
+    int8: the scale vectors (its payload is one byte wide, a same-width
+    bitcast); int4: its raw leaves and its scale vectors."""
+    itemsize = np.dtype(dtype_name).itemsize
+    scale = _SCALE_DT().itemsize
+    runs = []
+    for _, shape in specs:
+        rows, cols = _rows_cols(shape)
+        if codec == "raw":
+            runs.append((rows * cols * itemsize, itemsize))
+        elif codec == "int8":
+            runs.append((rows * scale, scale))
+        elif codec == "int4":
+            layout = _q4_layout(shape, itemsize)
+            runs.append((layout[1], itemsize) if layout[0] == "raw"
+                        else (rows * layout[3] * scale, scale))
+        else:
+            raise ValueError(f"codec {codec!r} has no device decode program")
+    fast, slow = zip(*(serde.widen_split(n, k) for n, k in runs))
+    return sum(fast), sum(slow)
+
+
 def host_unwrap(codec: str, data) -> Tuple[str, Any]:
     """Peel an entropy wire form back to its quantized BASE on the host
     (the byte-domain coder has no device program).  Returns

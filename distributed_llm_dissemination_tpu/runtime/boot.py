@@ -194,7 +194,7 @@ def verify_blob_digest(blob_id: int, src, digest_lookup,
 
 
 def stage_blob_leaves(cfg, blob_id: int, src, codec: str = "raw",
-                      sharding=None) -> dict:
+                      sharding=None, span=None) -> dict:
     """ONE blob's share of the boot: its decoded leaves, each with a
     leading length-1 axis so assembly is a uniform per-leaf concatenate.
     THE shared per-blob staging: the streaming stager runs it mid-wire
@@ -206,8 +206,10 @@ def stage_blob_leaves(cfg, blob_id: int, src, codec: str = "raw",
     (non-donated) 1-blob codec jit — callers release consumable blobs by
     dropping references (``blob_donate_ok``), never by ``donate_argnums``
     (a concurrent flow-retransmit reader holding the array would crash
-    on an XLA-deleted buffer).  Host path: numpy decode + async
-    ``device_put`` per leaf (under ``sharding`` when given)."""
+    on an XLA-deleted buffer); ``span`` (the caller's ``decode.stage``)
+    then learns how the program widens the blob (``fast_bytes``,
+    ``slow_bytes``: ``quant.widen_bytes``).  Host path: numpy decode +
+    async ``device_put`` per leaf (under ``sharding`` when given)."""
     import jax
     import numpy as np
 
@@ -229,8 +231,12 @@ def stage_blob_leaves(cfg, blob_id: int, src, codec: str = "raw",
             src.device_array = None
         arr = None
     if arr is not None:
+        dt_name = np.dtype(cfg.dtype).name
+        if span is not None:
+            fast, slow = quant.widen_bytes(codec, specs, dt_name)
+            span.set(fast_bytes=fast, slow_bytes=slow)
         decode = quant.device_decode_jit(codec, donate=False)
-        leaves = decode((arr,), specs, np.dtype(cfg.dtype).name)
+        leaves = decode((arr,), specs, dt_name)
         if blob_donate_ok(src):
             src.device_array = None
         return leaves

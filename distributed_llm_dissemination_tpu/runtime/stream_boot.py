@@ -181,7 +181,7 @@ class StreamingBootStager:
             with trace.span("decode.stage", id=self._pair(blob_id),
                             node=self.node_id) as sp:
                 try:
-                    leaves = self._stage_one(blob_id, src)
+                    leaves = self._stage_one(blob_id, src, sp)
                 except Exception as e:  # noqa: BLE001 — boot falls back to bulk
                     log.warn("streamed boot staging failed for blob; bulk "
                              "assembly will cover it", blobID=blob_id,
@@ -362,7 +362,7 @@ class StreamingBootStager:
         if out is None:
             trace.count("shard.gather_failed")
 
-    def _stage_one(self, blob_id: int, src) -> dict:
+    def _stage_one(self, blob_id: int, src, sp) -> dict:
         """One blob's staging — ``boot.stage_blob_leaves`` verbatim, so
         the mid-wire path and the boot's infill path share programs and
         bits.  Consumable device blobs (``blob_donate_ok``: host
@@ -381,4 +381,4 @@ class StreamingBootStager:
                            self.digest_verified)
         codec = getattr(src.meta, "codec", "") or self.codec
         return stage_blob_leaves(self.cfg, blob_id, src, codec=codec,
-                                 sharding=self._sharding())
+                                 sharding=self._sharding(), span=sp)

@@ -1,5 +1,7 @@
 """Model + 5-axis sharded train-step tests on the 8-device CPU mesh."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -252,37 +254,135 @@ def test_param_specs_cover_all_leaves(cpu_devices):
             assert spec[0] == "pp"
 
 
-def test_bytes_to_wide_bit_exact_all_widths():
-    # The decode primitive behind every device blob assembly
-    # (serde._bytes_to_wide): strided byte combine + same-width bitcast
-    # must reproduce a little-endian memory view BIT-exactly.  Compared
-    # through integer dtypes — the TPU float path canonicalizes NaN bit
-    # patterns, and this pin must hold on every backend.
-    import numpy as np
+# Lengths in words around the two grains a widening can have: a 128-word
+# row and the kernel's 4 KiB tile (2048 2-byte words), and one that
+# holds every 16-bit pattern.
+WIDEN_WORDS = (2, 127, 128, 129, 2047, 2048, 2049, 4096, 4096 + 3,
+               65536, 65536 + 3)
 
+
+@pytest.mark.parametrize("words", WIDEN_WORDS)
+@pytest.mark.parametrize("itemsize", (1, 2, 4))
+def test_bytes_to_wide_bit_exact_all_widths(itemsize, words):
+    # The decode primitive behind every device blob assembly
+    # (serde._bytes_to_wide): whole tiles through the widening kernel,
+    # the rest through the strided byte combine, then a same-width
+    # bitcast — must reproduce a little-endian memory view BIT-exactly
+    # at every length.  Compared through integer dtypes — the TPU float
+    # path canonicalizes NaN bit patterns, and this pin must hold on
+    # every backend.
     from distributed_llm_dissemination_tpu.models import serde
 
-    rng = np.random.default_rng(7)
-    buf = rng.integers(0, 256, 4096, dtype=np.uint8)
-    for dt in (jnp.int8, jnp.uint16, jnp.uint32):
+    rng = np.random.default_rng(7 + words)
+    if itemsize == 2:
+        # all 65,536 patterns, in a shuffled order, repeated or cut
+        buf = np.resize(rng.permutation(65536).astype(np.uint16),
+                        words).view(np.uint8)
+    else:
+        buf = rng.integers(0, 256, words * itemsize, dtype=np.uint8)
+    uint = {1: np.uint8, 2: np.uint16, 4: np.uint32}[itemsize]
+    fast, slow = serde.widen_split(len(buf), itemsize)
+    assert fast + slow == (len(buf) if itemsize > 1 else 0)
+    assert fast % 4096 == 0 and (slow < 4096 or itemsize == 4)
+    # the integer views, and the float widths real checkpoints use
+    for dt in {1: (jnp.int8,), 2: (jnp.uint16, jnp.bfloat16),
+               4: (jnp.uint32, jnp.float32)}[itemsize]:
         got = np.asarray(serde._bytes_to_wide(jnp.asarray(buf), dt))
-        want = buf.view(np.dtype(dt))
-        np.testing.assert_array_equal(got, want, err_msg=str(dt))
-    # 8-byte widths are rejected loudly (uint64 silently truncates
-    # without jax_enable_x64; no config uses them).
-    import pytest as _pytest
+        np.testing.assert_array_equal(got.view(uint), buf.view(uint),
+                                      err_msg=str(dt))
 
-    with _pytest.raises(ValueError, match="itemsize 8"):
-        serde._bytes_to_wide(jnp.asarray(buf), jnp.float64)
-    # And the float widths used by real checkpoints, viewed as ints.
-    got16 = np.asarray(
-        serde._bytes_to_wide(jnp.asarray(buf), jnp.bfloat16)
-    ).view(np.uint16)
-    np.testing.assert_array_equal(got16, buf.view(np.uint16))
-    got32 = np.asarray(
-        serde._bytes_to_wide(jnp.asarray(buf), jnp.float32)
-    ).view(np.uint32)
-    np.testing.assert_array_equal(got32, buf.view(np.uint32))
+
+def test_bytes_to_wide_rejects_8_byte_items():
+    # uint64 silently truncates without jax_enable_x64; no config uses
+    # 8-byte widths, so they are refused loudly.
+    from distributed_llm_dissemination_tpu.models import serde
+
+    with pytest.raises(ValueError, match="itemsize 8"):
+        serde._bytes_to_wide(jnp.zeros(4096, jnp.uint8), jnp.float64)
+
+
+def _as_u16(tree):
+    return {k: np.asarray(jax.device_get(v)).view(np.uint16)
+            for k, v in tree.items()}
+
+
+def _assert_same_bf16_bits(got, want, name):
+    """Equal bit for bit, but for a NaN's payload: a copy of a bfloat16
+    array may quieten a signalling NaN (the TPU's does, the CPU's
+    sometimes), in the slices' path as in the kernel's — the widening
+    itself is pinned through integers above."""
+    nan = ((want & 0x7F80) == 0x7F80) & ((want & 0x7F) != 0)
+    np.testing.assert_array_equal(got[~nan], want[~nan], err_msg=name)
+    assert np.all((got[nan] & 0x7F80) == 0x7F80), name
+    assert np.all((got[nan] & 0x7F) != 0), name
+    assert np.all((got[nan] & 0x8000) == (want[nan] & 0x8000)), name
+
+
+def test_decode_blobs_equals_the_host_views_bit_for_bit():
+    # serde._decode_blobs (the device path) against params_from_blobs'
+    # numpy views (the host path) on RANDOM bytes, NaN patterns too:
+    # two stacked layer blobs whose leaves are longer than a tile
+    # (32 KiB) and shorter (the 256-byte norms), and a head blob whose
+    # embedding is fifteen tiles and a remainder.
+    from distributed_llm_dissemination_tpu.models import serde
+
+    cfg = dataclasses.replace(CONFIGS["tiny"], n_layers=2, vocab=250)
+    rng = np.random.default_rng(11)
+    blobs = {b: rng.integers(0, 256, serde.blob_nbytes(cfg, b),
+                             dtype=np.uint8).tobytes()
+             for b in range(cfg.n_layers + 1)}
+    want = serde.params_from_blobs(cfg, blobs)
+    dev = {b: jnp.asarray(np.frombuffer(blobs[b], np.uint8)) for b in blobs}
+    specs = tuple(serde.layer_param_specs(cfg))
+    sizes = [serde.widen_split(int(np.prod(s)) * 2, 2) for _, s in specs]
+    assert any(f and not sl for f, sl in sizes)
+    assert any(sl and not f for f, sl in sizes)
+    got = serde._decode_blobs((dev[0], dev[1]), specs, "bfloat16")
+    for name, arr in _as_u16(got).items():
+        _assert_same_bf16_bits(arr, want["layers"][name].view(np.uint16),
+                               name)
+    head_specs = tuple(serde.head_param_specs(cfg))
+    assert all(serde.widen_split(250 * cfg.d_model * 2, 2))  # both paths
+    head = serde._decode_blobs((dev[2],), head_specs, "bfloat16")
+    for name, arr in _as_u16(head).items():
+        _assert_same_bf16_bits(arr[0], want[name].view(np.uint16), name)
+
+
+def test_decode_programs_keep_their_names_and_stride_no_bytes():
+    # Shapes only, lowered for this backend: one Mistral-7B leaf
+    # (4096 x 14336 bfloat16) through _decode_blobs.  A stride on a
+    # uint8 operand is the lane-by-lane gather that cost 0.12 s a leaf
+    # on the v5e (PR 24's ledger lines); whole tiles must never take it.
+    # The traced names are what the benchmark's trace reader and the
+    # compile-log oracles find the programs by.
+    import re
+
+    from distributed_llm_dissemination_tpu.models import quant, serde
+
+    shape = (4096, 14336)
+    blob = jax.ShapeDtypeStruct((shape[0] * shape[1] * 2,), jnp.uint8)
+    text = serde._decode_blobs.lower(
+        (blob,), (("w1", shape),), "bfloat16").as_text()
+    assert "@jit__decode_blobs" in text
+    strided = [
+        m.group(0) for m in re.finditer(
+            r"stablehlo\.slice[^\n]*?\[([^\]]*)\]\s*:\s*\(tensor<[0-9x]*ui8>",
+            text)
+        if any(len(dim.split(":")) == 3 and dim.split(":")[2].strip() != "1"
+               for dim in m.group(1).split(","))]
+    assert not strided, strided[:2]
+    # ... and the pattern does see the strided form where it is kept
+    tail = serde._decode_blobs.lower(
+        (jax.ShapeDtypeStruct((128,), jnp.uint8),), (("ln", (64,)),),
+        "bfloat16").as_text()
+    assert re.search(r"stablehlo\.slice[^\n]*:2\]\s*:\s*\(tensor<128xui8>",
+                     tail)
+    qblob = jax.ShapeDtypeStruct((64 * 4 + 64 * 128,), jnp.uint8)
+    qtext = quant._decode_qblobs.lower(
+        (qblob,), (("wq", (64, 128)),), "bfloat16").as_text()
+    assert "@jit__decode_qblobs" in qtext
+    assert quant.device_decode_jit("raw") is serde._decode_blobs
+    assert quant.device_decode_jit("int8") is quant._decode_qblobs
 
 
 def test_train_state_checkpoint_roundtrip_resumes_exactly(

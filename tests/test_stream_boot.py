@@ -127,6 +127,61 @@ def test_streamed_device_path_matches_bulk(cpu_devices):
         np.asarray(jax.device_get(bulk.logits), np.float32))
 
 
+def test_decode_stage_span_says_how_the_blob_widened(cpu_devices):
+    """A staged device-resident raw blob's ``decode.stage`` span carries
+    ``fast_bytes`` (whole 4 KiB tiles, the widening kernel) and
+    ``slow_bytes`` (the rest, the strided slices), together the blob;
+    ``cli.trace`` adds them up; and at the benchmark's widths — a
+    count from the specs, nothing staged — nothing is left to the
+    slices."""
+    from distributed_llm_dissemination_tpu.cli import trace as cli_trace
+    from distributed_llm_dissemination_tpu.utils import trace
+
+    trace.reset_run()
+    layers = seeded_layers(CFG, device=True)
+    stager = stage_all(CFG, layers, [0])
+    try:
+        assert set(stager.collect([0], timeout=TIMEOUT)) == {0}
+    finally:
+        stager.close()
+    span, = [s for s in trace.spans() if s["name"] == "decode.stage"]
+    fields = span["fields"]
+    assert fields["fast_bytes"] + fields["slow_bytes"] == layers[0].data_size
+    assert fields["slow_bytes"] == 2 * 2 * CFG.d_model  # the two norms
+    assert fields["fast_bytes"] % 4096 == 0
+    events = [{"ph": "X", "name": s["name"], "args": {"fields": s["fields"]}}
+              for s in trace.spans()]
+    assert cli_trace.decode_widen_totals(events) == {
+        "spans": 1, "fast_bytes": fields["fast_bytes"],
+        "slow_bytes": fields["slow_bytes"]}
+    # the host path widens nothing on the device, and says nothing
+    trace.reset_run()
+    stager = stage_all(CFG, seeded_layers(CFG), [1])
+    try:
+        stager.collect([1], timeout=TIMEOUT)
+    finally:
+        stager.close()
+    span, = [s for s in trace.spans() if s["name"] == "decode.stage"]
+    assert "fast_bytes" not in span["fields"]
+    assert cli_trace.decode_widen_totals([]) == {}
+    # Mistral-7B and Codestral-22B widths (the benchmark's two models)
+    for d, h, kv, f, vocab in ((4096, 32, 8, 14336, 32768),
+                               (6144, 48, 8, 16384, 32768)):
+        cfg = dataclasses.replace(CFG, d_model=d, n_heads=h, n_kv_heads=kv,
+                                  d_ff=f, vocab=vocab)
+        for bid in (0, serde.head_blob_id(cfg)):
+            specs = (serde.head_param_specs(cfg) if bid else
+                     serde.layer_param_specs(cfg))
+            assert quant.widen_bytes("raw", specs, "bfloat16") == (
+                serde.blob_nbytes(cfg, bid), 0)
+        # int8 sends the same layer with 4-byte scale vectors: those
+        # are all it widens, and they keep the slices
+        rows = sum(int(np.prod(s[:-1])) if len(s) > 1 else 1
+                   for _, s in serde.layer_param_specs(cfg))
+        assert quant.widen_bytes(
+            "int8", serde.layer_param_specs(cfg), "bfloat16") == (0, 4 * rows)
+
+
 def test_streamed_stage_boot_contiguous_slice():
     blobs = {bid: blob_layer(serde.seeded_blob(CFG, bid, SEED))
              for bid in (1, 2)}
