@@ -12,7 +12,7 @@ program and point at the current round's ``<seat>.out`` / ``<seat>.jsonl``.
 
 What the child does to the program, and nothing more:
 
-- registers the configuration (``CONFIGS[name] = ModelConfig(...)``);
+- registers the configuration (its architecture module's ``register``);
 - answers ``core.config.create_layers`` with the blobs the harness made
   from ``--seed`` (made once, in set-up, and page-touched);
 - remembers the node objects ``cli.main`` builds, so that a round can be
@@ -39,7 +39,7 @@ REPO = os.path.dirname(HERE)
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-from benchmark import fabricate  # noqa: E402  (numpy only)
+from benchmark import archs, fabricate  # noqa: E402  (numpy only)
 
 PKG = "distributed_llm_dissemination_tpu"
 # What the device may still hold when a round starts.  Every array is
@@ -73,12 +73,14 @@ class StampedStream:
 
 def pin(cores) -> dict:
     """Fence this process (every thread of it) onto ``cores``.  A refusal
-    is reported, never fatal."""
+    is reported, never fatal; a thread that ended since it was listed is
+    no refusal (it left a leader wholly unfenced once, PR 26)."""
     if not cores:
         return {"cores": sorted(os.sched_getaffinity(0)), "fenced": False}
     try:
         for tid in os.listdir("/proc/self/task"):
-            os.sched_setaffinity(int(tid), cores)
+            with contextlib.suppress(ProcessLookupError):
+                os.sched_setaffinity(int(tid), cores)
         return {"cores": sorted(os.sched_getaffinity(0)), "fenced": True}
     except (OSError, ValueError) as e:
         return {"cores": sorted(os.sched_getaffinity(0)), "fenced": False,
@@ -89,6 +91,7 @@ class Child:
     def __init__(self, spec: dict):
         self.spec = spec
         self.config = spec["config"]
+        self.arch = archs.of(self.config)
         self.traffic = spec["traffic"]
         self.seed = int(spec["seed"])
         self.codec = self.traffic.get("codec", "raw")
@@ -123,20 +126,9 @@ class Child:
         sys.stderr = StampedStream(sys.stderr)
 
         from distributed_llm_dissemination_tpu.core import config as pcfg
-        from distributed_llm_dissemination_tpu.models.llama import (
-            CONFIGS,
-            ModelConfig,
-        )
 
-        m = fabricate.model_dims(self.config)
-        if m["hd"] * m["h"] != m["d"]:
-            raise SystemExit("models/llama.py derives head_dim from "
-                             "hidden_size / heads; this config differs")
         self.model_name = self.spec["model_name"]
-        CONFIGS[self.model_name] = self.mcfg = ModelConfig(
-            name=self.model_name, vocab=m["vocab"], d_model=m["d"],
-            n_layers=m["layers"], n_heads=m["h"], n_kv_heads=m["kv"],
-            d_ff=m["f"], rope_theta=m["theta"], norm_eps=m["eps"])
+        self.forward = self.arch.register(self.config, self.model_name)
 
         def create_layers(my_conf, save_disk, storage_path=".", model="",
                           model_seed=0, model_codec="raw"):
@@ -405,8 +397,9 @@ class DeviceHolder(Child):
 
     def read_blobs(self, held: dict) -> dict:
         """Whole blobs read back from the device and digested by the
-        harness's own hashlib.  ``held[b]`` is ``("leaves", leaf)`` with
-        ``leaf(name)`` the decoded leaf in the resident params, or
+        harness's own hashlib.  ``held[b]`` is ``("leaves", boot)`` with
+        ``boot`` the boot result whose resident params hold its decoded
+        leaves (the architecture module finds each), or
         ``("wire", array)`` where the model keeps the wire blob itself."""
         import numpy as np
 
@@ -415,7 +408,7 @@ class DeviceHolder(Child):
             if kind == "wire":
                 return {"wire": fabricate.digest([np.asarray(what)])}
             return {"leaves": fabricate.digest(
-                np.asarray(what(name))
+                np.asarray(self.arch.leaf(what, b, name))
                 for name, _ in fabricate.blob_specs(self.config, b))}
 
         ids = sorted(held)
@@ -474,11 +467,8 @@ class Dest(DeviceHolder):
         node = self.kept
         if node is None or node.boot_result is None:
             raise RuntimeError("no booted node to read back")
-        params = node.boot_result.params
-        n_layers = fabricate.model_dims(self.config)["layers"]
-        held = {b: ("leaves", lambda name, b=b: params["layers"][name][b])
-                for b in range(n_layers)}
-        held[n_layers] = ("leaves", lambda name: params[name])
+        n_blobs = fabricate.model_dims(self.config)["layers"] + 1
+        held = {b: ("leaves", node.boot_result) for b in range(n_blobs)}
         return {"got": self.read_blobs(held),
                 "seconds": time.monotonic() - t0,
                 "placement": node.layer_placement()}
@@ -491,14 +481,12 @@ class Dest(DeviceHolder):
         import numpy as np
 
         from benchmark import reference
-        from distributed_llm_dissemination_tpu.models.llama import forward_jit
 
         jax = self.jax
         tokens = np.asarray(cmd["tokens"], np.int32)  # prompt + served
         inputs = tokens[:, :-1]
-        got = np.asarray(jax.device_get(forward_jit(
-            self.kept.boot_result.params, jax.numpy.asarray(inputs),
-            self.mcfg)), np.float32)
+        got = np.asarray(jax.device_get(self.forward(
+            self.kept.boot_result, jax.numpy.asarray(inputs))), np.float32)
         self.make_cold()
         t1 = time.monotonic()
         n_blobs = fabricate.model_dims(self.config)["layers"] + 1
@@ -547,7 +535,6 @@ class Pod(DeviceHolder):
         return {"device": self.device, "fabricate_s": fab_s,
                 "fence": pin(None), "baseline_bytes": self.baseline,
                 "cache_dir": self.cache_dir}
-
 
     def do_round(self, cmd) -> dict:
         before = self.make_cold()  # run_pod closed its own nodes
@@ -609,8 +596,7 @@ class Pod(DeviceHolder):
             staged = list(res.layer_ids) if res is not None else []
             for b, wire in wires.items():
                 if b in staged:
-                    held[b] = ("leaves", lambda name, res=res,
-                               i=staged.index(b): res.params[name][i])
+                    held[b] = ("leaves", res)
                 elif wire is not None:
                     held[b] = ("wire", wire)
         return {"got": self.read_blobs(held),

@@ -84,17 +84,12 @@ def run(run) -> dict:
                                      t["prompt_len"])
     state = {"tokens": None}
 
-    def one_round(k: int, traced: bool) -> dict:
-        rdir = os.path.join(run.out, f"round_{k:02d}")
-        os.makedirs(rdir)
-        addrs = dict(zip((s["id"] for s in t["seats"]),
-                         launch.free_addrs(len(t["seats"]))))
+    def start_seats(traced: bool, rdir: str, addrs: dict,
+                    cancel: str) -> None:
         conf_path = os.path.join(rdir, "topology.json")
-        cancel = os.path.join(rdir, "cancelled")
         with open(conf_path, "w") as f:
             json.dump(topology(run, addrs), f, indent=1)
         main = ["-f", conf_path, "-m", str(t.get("mode", 3)), "-bw", "300"]
-        cache_before = R.cache_entries(cache_dir)
         for name, seat in seats.items():
             prefix = os.path.join(rdir, name)
             if seat["role"] == "leader":
@@ -119,12 +114,22 @@ def run(run) -> dict:
                     node=dest["id"], id=seat["id"],
                     tokens=t["gen_tokens"], prompts=prompts,
                     timeout=R_TIMEOUT, cancel=cancel)
+
+    def one_round(k: int, traced: bool) -> dict:
+        rdir = os.path.join(run.out, f"round_{k:02d}")
+        os.makedirs(rdir)
+        cancel = os.path.join(rdir, "cancelled")
+        cache_before = R.cache_entries(cache_dir)
         rec = {"round": k, "ok": False, "traced": traced}
         logs = {name: [] for name in seats}
         try:
-            replies = run.kids.gather(
-                list(seats), R_TIMEOUT + 60,
-                on_failure=lambda: open(cancel, "w").close())
+            # the seats' ports stay held until every seat has answered
+            with launch.held_addrs(len(t["seats"])) as free:
+                start_seats(traced, rdir, dict(zip(
+                    (s["id"] for s in t["seats"]), free)), cancel)
+                replies = run.kids.gather(
+                    list(seats), R_TIMEOUT + 60,
+                    on_failure=lambda: open(cancel, "w").close())
             logs = _logs(rdir, seats)
             _assemble(run, rec, rdir, logs, replies, dest, leader,
                       requester, state)

@@ -21,46 +21,38 @@ from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
+from benchmark import archs
+
 CODECS = ("raw", "int8")
 # bfloat16 patterns: sign and 7 mantissa bits random, exponent 120 or
 # 121, so |w| is in [2^-7, 2^-5) with an RMS near 0.018 — the d^-0.5
 # scale of a trained layer at these widths, and never NaN or infinite.
 _KEEP = np.uint16(0x80FF)
 _EXP = np.uint16(0x3C00)
-_ONE_BF16 = np.uint16(0x3F80)
 _INT8_RMS = 73.9  # RMS of a uniform int8
 _TARGET_RMS = 0.0156
 
 
 def model_dims(config: dict) -> dict:
-    """The sizes the blob layout needs, from a configuration file in the
-    source's own (Hugging Face) keys."""
-    d = int(config["hidden_size"])
-    h = int(config["num_attention_heads"])
-    return {
-        "d": d, "h": h, "kv": int(config["num_key_value_heads"]),
-        "hd": int(config.get("head_dim") or d // h),
-        "f": int(config["intermediate_size"]),
-        "vocab": int(config["vocab_size"]),
-        "layers": int(config["num_hidden_layers"]),
-        "theta": float(config["rope_theta"]),
-        "eps": float(config["rms_norm_eps"]),
-    }
+    """The configuration's sizes as its architecture module gives them:
+    ``layers`` (the layer blobs; the head blob is one past them),
+    ``vocab``, and what the module's own functions need."""
+    return archs.of(config).dims(config)
+
+
+def _layout(config: dict, blob_id: int) -> list:
+    """``[(name, shape, fill)]``: the architecture module's word on one
+    blob."""
+    arch = archs.of(config)
+    if not 0 <= blob_id <= arch.dims(config)["layers"]:
+        raise ValueError(f"blob {blob_id} out of range")
+    return arch.layout(config, blob_id)
 
 
 def blob_specs(config: dict, blob_id: int) -> list:
     """``[(name, shape)]`` of a blob's leaves in wire order.  Blob
-    ``layers`` is the head blob (embed, final norm, lm_head)."""
-    m = model_dims(config)
-    d, f, h, kv, hd = m["d"], m["f"], m["h"], m["kv"], m["hd"]
-    if blob_id == m["layers"]:
-        return [("embed", (m["vocab"], d)), ("ln_f", (d,)),
-                ("lm_head", (d, m["vocab"]))]
-    if not 0 <= blob_id < m["layers"]:
-        raise ValueError(f"blob {blob_id} out of range")
-    return [("wq", (d, h * hd)), ("wk", (d, kv * hd)), ("wv", (d, kv * hd)),
-            ("wo", (h * hd, d)), ("ln1", (d,)), ("ln2", (d,)),
-            ("w1", (d, f)), ("w3", (d, f)), ("w2", (f, d))]
+    ``layers`` is the head blob."""
+    return [(name, shape) for name, shape, _ in _layout(config, blob_id)]
 
 
 def _rows_cols(shape: tuple) -> tuple:
@@ -93,6 +85,14 @@ def _random_bytes(rng: np.random.Generator, n: int) -> np.ndarray:
     return words.view(np.uint8)[:n]
 
 
+def _bf16_bits(fill: float) -> np.uint16:
+    """The bfloat16 pattern of a constant fill, which must be one."""
+    bits = int(np.float32(fill).view(np.uint32))
+    if bits & 0xFFFF:
+        raise ValueError(f"a constant fill must be a bfloat16: {fill!r}")
+    return np.uint16(bits >> 16)
+
+
 def make_blob(config: dict, blob_id: int, seed: int,
               codec: str = "raw") -> np.ndarray:
     """One blob's bytes (a writable 1-D uint8 array), the same for the
@@ -102,14 +102,14 @@ def make_blob(config: dict, blob_id: int, seed: int,
     rng = _rng(seed, blob_id, CODECS.index(codec))
     out = _random_bytes(rng, blob_nbytes(config, blob_id, codec))
     off = 0
-    for name, shape in blob_specs(config, blob_id):
+    for _, shape, fill in _layout(config, blob_id):
         rows, cols = _rows_cols(shape)
-        gain = name.startswith("ln")  # norm gains are exactly 1
+        held = None if fill is None else _bf16_bits(fill)
         if codec == "raw":
             n = rows * cols * 2
             leaf = out[off:off + n].view(np.uint16)
-            if gain:
-                leaf[:] = _ONE_BF16
+            if held is not None:
+                leaf[:] = held
             else:
                 np.bitwise_and(leaf, _KEEP, out=leaf)
                 np.bitwise_or(leaf, _EXP, out=leaf)
@@ -117,9 +117,9 @@ def make_blob(config: dict, blob_id: int, seed: int,
             continue
         scale = out[off:off + rows * 4].view(np.float32)
         off += rows * 4
-        if gain:
-            scale[:] = 1.0 / 127.0
-            out[off:off + cols] = 127
+        if held is not None:  # 127 times a 127th of it, in every element
+            scale[:] = fill / 127.0
+            out[off:off + rows * cols] = 127
         else:
             scale[:] = (_TARGET_RMS / _INT8_RMS) * rng.uniform(
                 0.75, 1.25, rows).astype(np.float32)

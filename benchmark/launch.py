@@ -8,6 +8,7 @@ the smoke cannot change the benchmark.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import select
@@ -37,12 +38,21 @@ def child_env(jax_platforms: str) -> dict:
     return env
 
 
-def free_addrs(n: int) -> list:
+@contextlib.contextmanager
+def held_addrs(n: int):
+    """``n`` free loopback addresses, HELD while the block runs: each port
+    stays bound here (never listening) under ``SO_REUSEADDR``, so a seat's
+    listener (``socket.create_server`` sets the same option) can bind
+    and bind again, and nothing else gets the port, neither another
+    ``bind`` nor a ``connect`` in want of a source port.  Handed out
+    and closed, a port of the ephemeral range was twice in 29 runs some
+    seat's source port by the time the requester bound (PERF.md, PR 24)."""
     socks = [socket.socket() for _ in range(n)]
     try:
         for s in socks:
+            s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
             s.bind(("127.0.0.1", 0))
-        return [f"127.0.0.1:{s.getsockname()[1]}" for s in socks]
+        yield [f"127.0.0.1:{s.getsockname()[1]}" for s in socks]
     finally:
         for s in socks:
             s.close()
@@ -107,12 +117,15 @@ class Children:
 
     def gather(self, names, timeout: float, on_failure=None,
                grace: float = 20.0) -> dict:
-        """One answer from each of ``names``.  A round's seats wait on one
-        another, so when one of them fails (``rc`` not 0) the rest get
-        ``grace`` seconds and ``on_failure()`` is called once — a stalled
-        round costs seconds, not its whole timeout."""
+        """One answer from each of ``names``: every answer is read, so
+        that no seat's pipe is left a reply ahead, and only then does a
+        seat that died or reported an error fail the call, with all of
+        them.  A round's seats wait on one another, so when one of them
+        fails (an error, or ``rc`` not 0) the rest get ``grace`` seconds
+        and ``on_failure()`` is called once — a stalled round costs
+        seconds, not its whole timeout."""
         pending = {self.procs[n].stdout: n for n in names}
-        out, failed = {}, False
+        out, errors, failed = {}, {}, False
         deadline = time.monotonic() + timeout
         while pending:
             left = deadline - time.monotonic()
@@ -120,19 +133,26 @@ class Children:
                                         max(0.0, min(left, 1.0)))
             for pipe in ready:
                 name = pending.pop(pipe)
-                out[name] = self.recv(name, 1.0)
-                if out[name].get("rc") != 0 and not failed:
+                try:
+                    out[name] = self.recv(name, 1.0)
+                    bad = out[name].get("rc") != 0
+                except BenchFailure as e:
+                    errors[name], bad = str(e), True
+                if bad and not failed:
                     failed = True
                     deadline = min(deadline, time.monotonic() + grace)
                     if on_failure is not None:
                         on_failure()
             if pending and time.monotonic() > deadline:
-                said = {n: r.get("error") for n, r in out.items()
-                        if r.get("rc") != 0}
+                said = dict(errors, **{n: r.get("error")
+                                       for n, r in out.items()
+                                       if r.get("rc") != 0})
                 raise BenchFailure(
                     f"no answer from {sorted(pending.values())}"
                     + (f" after {said}" if said else
                        f" in {timeout:.0f}s"))
+        if errors:
+            raise BenchFailure("; ".join(errors.values()))
         return out
 
     def call(self, name: str, timeout: float, **cmd) -> dict:

@@ -5,7 +5,8 @@ the file the manifest gives; its traffic mix is
 ``benchmark/traffic/<traffic>.json``; a per-layer metric ``m`` is
 described by ``benchmark/metrics/<m>.json`` (layer, unit, moves, the
 reader's name and its arguments) and read by
-``benchmark/readers/<reader>.py``.  Every lookup tries the manifest's
+``benchmark/readers/<reader>.py``; a configuration's architecture is
+``benchmark/archs/<arch>.py``.  Every lookup tries the manifest's
 own directory first and this checkout second, so a later PR — or a test
 in a temporary directory — adds cells, configurations, mixes, metrics
 and readers as new files and edits none.
@@ -23,6 +24,24 @@ REPO = os.path.dirname(HERE)
 
 class ManifestError(ValueError):
     pass
+
+
+def load_file(path: str, name: str):
+    """A module loaded from its file, under ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def arch_names(root: str) -> list:
+    """The architecture modules under one root's ``benchmark/archs``."""
+    try:
+        names = os.listdir(os.path.join(root, "benchmark", "archs"))
+    except OSError:
+        return []
+    return sorted(n[:-3] for n in names
+                  if n.endswith(".py") and not n.startswith("_"))
 
 
 class Manifest:
@@ -57,11 +76,28 @@ class Manifest:
             f"{[w['name'] for w in self.data['workloads']]}")
 
     def config(self, name: str) -> tuple:
-        """``(manifest entry, the configuration file's contents)``."""
+        """``(manifest entry, the configuration file's contents)``, with
+        the path of its architecture module (``benchmark/archs/<arch>.py``,
+        ``arch`` a key of the file, ``llama`` where it has none) added
+        under ``arch_file``: the configuration is all that the seats, the
+        readers and ``kernels.py`` are handed."""
         for c in self.data["configs"]:
             if c["name"] == name:
-                return c, self.load(c["file"])
+                cfg = self.load(c["file"])
+                arch = cfg.get("arch", "llama")
+                try:
+                    cfg["arch_file"] = self.find("benchmark", "archs",
+                                                 arch + ".py")
+                except ManifestError:
+                    raise ManifestError(
+                        f"config {name!r} names the architecture {arch!r}; "
+                        f"known: {self.archs()}") from None
+                return c, cfg
         raise ManifestError(f"unknown config {name!r}")
+
+    def archs(self) -> list:
+        """The architecture modules a configuration here may name."""
+        return sorted({a for root in self.roots for a in arch_names(root)})
 
     def traffic(self, name: str) -> dict:
         return self.load("benchmark", "traffic", name + ".json")
@@ -87,9 +123,5 @@ class Manifest:
     def module(self, kind: str, name: str):
         """``benchmark/<kind>/<name>.py`` (a reader, a driver), found like
         every other piece and loaded from its file."""
-        path = self.find("benchmark", kind, name + ".py")
-        spec = importlib.util.spec_from_file_location(
-            f"benchmark_{kind}_{name}", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
+        return load_file(self.find("benchmark", kind, name + ".py"),
+                         f"benchmark_{kind}_{name}")

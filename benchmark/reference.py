@@ -1,64 +1,20 @@
-"""The plain reference: a Llama-architecture forward in float32.
+"""The plain reference's shared half: the blockwise loop, the widening
+of a wire leaf, and the verdict.
 
-Straightforward ``jax.numpy``: no KV cache, no kernels, no scan, no
-batching tricks, ``highest`` matmul precision (on a TPU a float32 matmul
-otherwise runs in bfloat16 passes).  It shares no code with
-``models/llama.py`` and follows the published description of the
-Mistral/Llama block:
-
-    h   = embed[tokens]
-    h  += Wo . attention(rope(Wq . n1), rope(Wk . n1), Wv . n1)
-    h  += W2 . (silu(W1 . n2) * (W3 . n2))      n = RMSNorm(h) * gain
-    out = lm_head . RMSNorm(h)
-
-with grouped-query attention (each KV head serves ``heads / kv_heads``
-query heads), a causal mask, and rotary embeddings in the half-split
-("rotate_half") convention with ``theta ** (-i / (hd/2))``.  Weights are
-stored ``[in, out]`` (``x @ W``), as the blob layout has them.  Layers
-are taken one at a time from ``blob(b) -> {leaf: fabricate.Leaf}`` (views
-of the wire blob) and widened to float32 on the device by the codec's
-published formula, so the reference never holds more than one layer and
-the head, and moves each wire byte to the device once.
+The forward itself (tokens to the hidden state, one block, the hidden
+state to logits) is the configuration's architecture module's
+(``benchmark/archs/<arch>.py``): float32 ``jax.numpy`` at ``highest``
+matmul precision (on a TPU a float32 matmul otherwise runs in bfloat16
+passes), sharing no code with the program.  Layers are taken one at a
+time from ``blob(b) -> {leaf: fabricate.Leaf}`` (views of the wire blob)
+and widened to float32 on the device by the codec's published formula,
+so the reference never holds more than one layer and the head, and moves
+each wire byte to the device once.
 """
 
 from __future__ import annotations
 
 import numpy as np
-
-
-def _rms_norm(jnp, x, gain, eps):
-    return x * (1.0 / jnp.sqrt(jnp.mean(x * x, axis=-1, keepdims=True)
-                               + eps)) * gain
-
-
-def _rope(jnp, x, theta):
-    # x: [batch, seq, heads, hd]
-    hd = x.shape[-1]
-    half = hd // 2
-    freqs = theta ** (-np.arange(half, dtype=np.float32) / half)
-    angles = np.arange(x.shape[1], dtype=np.float32)[:, None] * freqs
-    cos = jnp.asarray(np.cos(angles))[None, :, None, :]
-    sin = jnp.asarray(np.sin(angles))[None, :, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-
-
-def _layer(jnp, jax, dims, p, h):
-    b, s, _ = h.shape
-    nh, kv, hd = dims["h"], dims["kv"], dims["hd"]
-    n1 = _rms_norm(jnp, h, p["ln1"], dims["eps"])
-    q = _rope(jnp, (n1 @ p["wq"]).reshape(b, s, nh, hd), dims["theta"])
-    k = _rope(jnp, (n1 @ p["wk"]).reshape(b, s, kv, hd), dims["theta"])
-    v = (n1 @ p["wv"]).reshape(b, s, kv, hd)
-    k = jnp.repeat(k, nh // kv, axis=2)  # KV head j serves heads j*g..
-    v = jnp.repeat(v, nh // kv, axis=2)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(hd)
-    causal = np.tril(np.ones((s, s), bool))
-    scores = jnp.where(causal, scores, -jnp.inf)
-    attn = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1), v)
-    h = h + attn.reshape(b, s, nh * hd) @ p["wo"]
-    n2 = _rms_norm(jnp, h, p["ln2"], dims["eps"])
-    return h + (jax.nn.silu(n2 @ p["w1"]) * (n2 @ p["w3"])) @ p["w2"]
 
 
 def _widen(jnp, leaf):
@@ -80,18 +36,20 @@ def logits(config: dict, tokens, blob) -> np.ndarray:
     import jax
     import jax.numpy as jnp
 
-    from benchmark.fabricate import model_dims
+    from benchmark import archs
 
-    dims = model_dims(config)
-    layer = jax.jit(lambda p, h: _layer(jnp, jax, dims, p, h))
+    arch = archs.of(config)
+    dims = arch.dims(config)
+    layer = jax.jit(lambda p, h: arch.ref_layer(jnp, jax, dims, p, h))
     with jax.default_matmul_precision("highest"):
         head = {k: _widen(jnp, v) for k, v in blob(dims["layers"]).items()}
-        h = head["embed"][jnp.asarray(np.asarray(tokens, np.int32))]
+        h = arch.ref_in(jnp, dims, head,
+                        jnp.asarray(np.asarray(tokens, np.int32)))
         for b in range(dims["layers"]):
             p = {k: _widen(jnp, v) for k, v in blob(b).items()}
             h = jax.block_until_ready(layer(p, h))
             del p
-        out = _rms_norm(jnp, h, head["ln_f"], dims["eps"]) @ head["lm_head"]
+        out = arch.ref_out(jnp, dims, head, h)
         return np.asarray(jax.device_get(out), np.float32)
 
 

@@ -37,7 +37,7 @@ REPO = os.path.dirname(HERE)
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-from benchmark import launch, rounds as R  # noqa: E402
+from benchmark import archs, launch, rounds as R  # noqa: E402
 from benchmark.launch import BenchFailure  # noqa: E402
 from benchmark.manifest import Manifest  # noqa: E402
 
@@ -70,6 +70,8 @@ class Run:
         self.manifest = manifest
         self.cell = manifest.workload(args.workload)
         self.config_entry, self.config = manifest.config(self.cell["config"])
+        # found and held to its hooks here, before a seat is started
+        self.arch = archs.of(self.config)
         self.traffic = manifest.traffic(self.cell["traffic"])
         self.seed = int(args.seed)
         self.seconds = float(args.seconds)

@@ -4,6 +4,7 @@ and every rehearsal runs on the CPU in seconds."""
 
 import json
 import os
+import shutil
 import subprocess
 import sys
 
@@ -12,10 +13,26 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-TINY = {"hidden_size": 128, "num_attention_heads": 4,
-        "num_key_value_heads": 2, "intermediate_size": 256,
-        "vocab_size": 256, "num_hidden_layers": 4,
-        "rope_theta": 500000.0, "rms_norm_eps": 1e-05}
+TINY_FILE = {"hidden_size": 128, "num_attention_heads": 4,
+             "num_key_value_heads": 2, "intermediate_size": 256,
+             "vocab_size": 256, "num_hidden_layers": 4,
+             "rope_theta": 500000.0, "rms_norm_eps": 1e-05}
+
+
+def with_arch(config: dict, root: str = REPO) -> dict:
+    """A configuration file's contents as ``Manifest.config`` hands them
+    out: with the path of its architecture module under ``root``."""
+    return dict(config, arch_file=os.path.join(
+        root, "benchmark", "archs", config.get("arch", "llama") + ".py"))
+
+
+TINY = with_arch(TINY_FILE)
+
+# The second architecture: the program's routed variant, as files that
+# only ever sit beside a temporary manifest (``add_second_arch``).
+ARCH_MOE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "arch_moe")
+MOE_MIXES = ("cold-raw", "cold-int8")  # a pod of it is not asked for
 
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 
@@ -45,10 +62,11 @@ def traffic_mixes() -> list:
 
 
 def load_config(name: str) -> dict:
-    """A committed configuration file, whether or not a cell uses it."""
+    """A committed configuration file, whether or not a cell uses it, as
+    ``Manifest.config`` would hand it out."""
     with open(os.path.join(REPO, "benchmark", "configs",
                            name + ".json")) as f:
-        return json.load(f)
+        return with_arch(json.load(f))
 
 
 def write_tiny_root(root, tag: str) -> str:
@@ -58,7 +76,7 @@ def write_tiny_root(root, tag: str) -> str:
     os.makedirs(os.path.join(root, "benchmark", "configs"), exist_ok=True)
     with open(os.path.join(root, "benchmark", "configs", "tiny.json"),
               "w") as f:
-        json.dump(TINY, f)
+        json.dump(TINY_FILE, f)
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         d = json.load(f)
     d["configs"] = [{"name": "tinycfg", "source": "tests",
@@ -92,6 +110,41 @@ def write_tiny_root(root, tag: str) -> str:
     with open(path, "w") as f:
         json.dump(d, f)
     return path
+
+
+def moe_config() -> dict:
+    """The second architecture's tiny configuration as ``Manifest.config``
+    would hand it out, its module found where it lies."""
+    with open(os.path.join(ARCH_MOE, "tiny-moe.json")) as f:
+        return dict(json.load(f), arch_file=os.path.join(ARCH_MOE, "moe.py"))
+
+
+def add_second_arch(manifest: str, tag: str) -> None:
+    """The second architecture as NEW FILES beside ``manifest`` (its
+    module, its configuration) and new entries in it: a cell
+    ``<tag>.moe.<mix>`` for each of ``MOE_MIXES``, reporting what the
+    Llama cell of that mix reports."""
+    root = os.path.dirname(manifest)
+    os.makedirs(os.path.join(root, "benchmark", "archs"), exist_ok=True)
+    shutil.copy(os.path.join(ARCH_MOE, "moe.py"),
+                os.path.join(root, "benchmark", "archs"))
+    shutil.copy(os.path.join(ARCH_MOE, "tiny-moe.json"),
+                os.path.join(root, "benchmark", "configs"))
+    with open(manifest) as f:
+        d = json.load(f)
+    d["configs"].append({"name": "tinymoe", "source": "tests", "reduced": [],
+                         "file": "benchmark/configs/tiny-moe.json",
+                         "why": "a second architecture at tiny width"})
+    for mix in MOE_MIXES:
+        d["workloads"].append({
+            "name": f"{tag}.moe.{mix}", "config": "tinymoe", "traffic": mix,
+            "chips": 1, "why": "a committed mix under a second architecture"})
+    for m in d["end_to_end"] + d["per_layer"]:
+        if "workloads" in m:
+            m["workloads"] += [f"{tag}.moe.{mix}" for mix in MOE_MIXES
+                               if f"{tag}.{mix}" in m["workloads"]]
+    with open(manifest, "w") as f:
+        json.dump(d, f)
 
 
 def rehearse(manifest: str, workload: str, *, stub: bool, trace: int = 0,

@@ -88,3 +88,57 @@ def test_a_failed_seat_cuts_the_others_wait_to_the_grace(kids):
         kids.gather(["dest", "leader"], 600.0, grace=1.0,
                     on_failure=lambda: cancelled.append(1))
     assert time.monotonic() - t0 < 10 and cancelled == [1]
+
+
+def test_gather_reads_every_answer_before_it_fails_with_all_of_them(kids):
+    """A seat that reports an error (the requester whose bind failed)
+    must not leave the others' answers unread: the next command to a seat
+    would be answered with this round's reply, and the run would die at
+    its read-back with no result line (PERF.md, PR 24)."""
+    kids.start_fake("requester", reply={
+        "ok": False, "error": "OSError(98, 'Address already in use')"})
+    kids.start_fake("dest", reply={"ok": False, "error": "device lost"})
+    kids.start_fake("leader", sleep=0.5)
+    for name in ("requester", "dest", "leader"):
+        kids.send(name, cmd="round", k=1)
+    cancelled = []
+    with pytest.raises(launch.BenchFailure) as e:
+        kids.gather(["requester", "dest", "leader"], 60.0, grace=10.0,
+                    on_failure=lambda: cancelled.append(1))
+    assert "Address already in use" in str(e.value)
+    assert "device lost" in str(e.value) and cancelled == [1]
+    # the leader's pipe is in step: its next answer is to the next command
+    assert kids.call("leader", 10.0, cmd="expected")["echo"] == {
+        "cmd": "expected"}
+
+
+def test_a_seat_that_died_still_ends_the_run_after_the_others_are_read(kids):
+    kids.start_fake("dest", die=True)
+    kids.start_fake("leader")
+    for name in ("dest", "leader"):
+        kids.send(name, cmd="round")
+    with pytest.raises(launch.BenchFailure, match="dest exited rc=3"):
+        kids.gather(["dest", "leader"], 60.0, grace=5.0)
+    assert kids.call("leader", 10.0, cmd="x")["echo"] == {"cmd": "x"}
+
+
+def test_handed_out_ports_stay_held_until_the_round_is_over():
+    """While held, a seat's listener (``socket.create_server``, as
+    ``transport/tcp.py`` opens it) binds, serves, closes and binds again,
+    and nobody else can take the port."""
+    import socket
+
+    with launch.held_addrs(3) as addrs:
+        assert len(set(addrs)) == 3
+        host, port = addrs[0].split(":")
+        for _ in range(2):  # the requester binds once for every request
+            with socket.create_server((host, int(port)),
+                                      reuse_port=False) as srv:
+                with socket.create_connection((host, int(port)),
+                                              timeout=5) as c:
+                    peer, _ = srv.accept()
+                    c.sendall(b"x")
+                    assert peer.recv(1) == b"x"
+                    peer.close()
+        with socket.socket() as other, pytest.raises(OSError):
+            other.bind((host, int(port)))

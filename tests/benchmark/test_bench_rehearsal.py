@@ -11,7 +11,8 @@ import os
 
 import pytest
 
-from bench_helpers import REPO, RESULT_KEYS, rehearse, traffic_mixes
+from bench_helpers import (MOE_MIXES, REPO, RESULT_KEYS, add_second_arch,
+                           rehearse, traffic_mixes)
 from benchmark import rounds
 from benchmark.manifest import Manifest
 from contract import problems
@@ -34,15 +35,31 @@ def test_a_run_off_the_tpu_fails_and_prints_no_result(tiny_manifest, mix):
     assert not _last_line(proc).startswith("{")  # no result line
 
 
-@pytest.mark.parametrize("mix,trace", [(m, t) for m in MIXES
-                                       for t in (0, 1)])
-def test_a_rehearsed_run_prints_the_contracts_last_line(tiny_manifest, mix,
-                                                        trace):
+# The second architecture (tests/benchmark/arch_moe/, files beside the
+# temporary manifest and nowhere else) goes through the same whole run.
+CASES = ([("llama", m, t) for m in MIXES for t in (0, 1)]
+         + [("moe", m, 0) for m in MOE_MIXES] + [("moe", MOE_MIXES[0], 1)])
+
+
+@pytest.mark.parametrize("arch,mix,trace", CASES)
+def test_a_rehearsed_run_prints_the_contracts_last_line(tiny_manifest, arch,
+                                                        mix, trace):
     manifest, tag = tiny_manifest
     cell = f"{tag}.{mix}"
+    if arch == "moe":
+        add_second_arch(manifest, tag)
+        cell = f"{tag}.moe.{mix}"
+        assert problems(Manifest(manifest)) == []
     proc = rehearse(manifest, cell, stub=True, trace=trace)
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-2000:]
     line = json.loads(_last_line(proc))
+    # every blob is read back whole, leaf by leaf of the architecture's
+    # own layout (the second one's has leaves of rank 3), and the logits
+    # of either architecture are held to the one tolerance
+    assert "read-back: 5 whole blobs" in proc.stdout
+    assert ", 0 mismatches" in proc.stdout
+    ref = json.loads(proc.stdout.split("reference: ", 1)[1].splitlines()[0])
+    assert ref["passed"] and ref["tolerance"] == 0.03
     assert set(line) == RESULT_KEYS | ({"breakdown"} if trace else set())
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] >= 1

@@ -323,3 +323,35 @@ def test_roofline_reader_divides_counted_bytes_by_traced_time():
         "collective": {"ms": 1930.0, "n": 5}}}}}
     spec = man.metric_spec("fabric.collective_busy_s")
     assert man.reader(spec["reader"])(pod, **spec["args"]) == 1.93
+
+
+def test_a_thread_that_ended_since_it_was_listed_does_not_unfence_the_rest(
+        monkeypatch):
+    """``pin`` lists the process's threads and fences each; one that has
+    ended in between is gone, not a refusal (on the v5e it once left a
+    whole leader on every core, PR 26)."""
+    import os
+
+    from benchmark import child
+
+    mine = sorted(os.sched_getaffinity(0))
+    real, seen = os.sched_setaffinity, []
+
+    def setaffinity(tid, cores):
+        if tid == 2 ** 22 + 1:  # above any pid: a thread that has ended
+            raise ProcessLookupError(3, "No such process")
+        seen.append(tid)
+        real(tid, cores)
+
+    listed = os.listdir("/proc/self/task")
+    monkeypatch.setattr(os, "sched_setaffinity", setaffinity)
+    monkeypatch.setattr(os, "listdir",
+                        lambda path: [str(2 ** 22 + 1)] + listed)
+    got = child.pin(mine[:1])
+    monkeypatch.undo()
+    try:
+        assert got == {"cores": mine[:1], "fenced": True}
+        assert sorted(seen) == sorted(int(t) for t in listed)
+    finally:
+        for tid in listed:
+            os.sched_setaffinity(int(tid), mine)
