@@ -72,7 +72,8 @@ def build_parser() -> argparse.ArgumentParser:
                    help="receiver: stage each delivered layer into TPU HBM "
                         "(jax.Array) before acking")
     p.add_argument("-boot", type=str, default="",
-                   help="model config name (models.llama.CONFIGS), "
+                   help="model config name (of any family in "
+                        "models/family.py: family.known()), "
                         "hf:<checkpoint-dir>, or 'none': receivers boot the "
                         "model from the delivered layer blobs on startup; "
                         "the leader waits for every assignee's boot and "
@@ -225,19 +226,18 @@ def validate_boot_choice(args, conf) -> None:
 
 
 def _resolve_model_config(name: str):
-    """THE model-name resolution (CONFIGS entry or ``hf:<dir>``) —
-    shared by the boot path and the wire-codec plane so a new naming
-    scheme can't silently reach one and miss the other.  Raises
-    KeyError/OSError/ValueError for unresolvable names; callers own the
-    error policy (boot fails fast, the codec plane degrades to None)."""
-    from ..models import hf
+    """THE model-name resolution (a named configuration of any family in
+    ``models/family.py``, or ``hf:<dir>``) — shared by the boot path and
+    the wire-codec plane so a new naming scheme can't silently reach one
+    and miss the other.  Raises KeyError/OSError/ValueError for
+    unresolvable names; callers own the error policy (boot fails fast,
+    the codec plane degrades to None)."""
+    from ..models import family, hf
 
     if hf.is_hf(name):
         # A Hugging Face Llama checkpoint directory (models/hf.py).
         return hf.config_from_name(name)
-    from ..models.llama import CONFIGS
-
-    return CONFIGS[name]
+    return family.config(name)
 
 
 def boot_config(name: str):
@@ -248,10 +248,10 @@ def boot_config(name: str):
     try:
         return _resolve_model_config(name)
     except KeyError:
-        from ..models.llama import CONFIGS
+        from ..models import family
 
         raise SystemExit(
-            f"unknown -boot model {name!r}; known: {sorted(CONFIGS)}, "
+            f"unknown -boot model {name!r}; known: {family.known()}, "
             "none, hf:<checkpoint-dir>"
         )
     except (OSError, ValueError) as e:

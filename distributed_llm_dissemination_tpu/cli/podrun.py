@@ -138,6 +138,16 @@ def run_pod(conf: cfg.Config, mode: int = 3, boot: str = "",
     from .main import boot_config  # same validation as the per-node CLI
 
     boot_cfg = boot_config(boot or conf.model)
+    if boot_cfg is not None:
+        from ..models import family
+
+        try:
+            family.only(boot_cfg, ("llama",), "cli.podrun.run_pod",
+                        "a pod's stage boots, pipelined forward and pod "
+                        "decode (runtime/pp_serve.py, models/sharded.py) "
+                        "know Llama's block and K/V cache only")
+        except family.FamilyNotSupported as e:
+            raise SystemExit(str(e))
 
     leader = None
     receivers = []

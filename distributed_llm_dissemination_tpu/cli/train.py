@@ -34,21 +34,36 @@ from ..utils import logging as ulog
 from ..utils.logging import log
 
 
+def _trainable_config(name: str):
+    """The topology's model (a named configuration or ``hf:<dir>``),
+    refused at entry if its family has no sharded train step
+    (``models/sharded.py`` knows Llama's only)."""
+    from ..models import family, hf
+
+    if hf.is_hf(name):
+        return hf.config_from_name(name)
+    try:
+        mcfg = family.config(name)
+        family.only(mcfg, ("llama",), "cli.train",
+                    "the sharded AdamW train step and its checkpoint "
+                    "(models/sharded.py, models/train_ckpt.py) know "
+                    "Llama's leaves only")
+    except KeyError:
+        raise SystemExit(f"unknown Model {name!r}; known: {family.known()}")
+    except family.FamilyNotSupported as e:
+        raise SystemExit(str(e))
+    return mcfg
+
+
 def _params_from_dissemination(conf, timeout: float):
     """Run one mode-3 pod dissemination and return (params, cfg,
     timings) assembled from the DELIVERED blobs on the dest."""
     from ..models import serde
-    from ..models.llama import CONFIGS
     from ..models.serde import params_from_blobs
 
     from .podrun import run_pod  # noqa: PLC0415 — heavy import path
 
-    if conf.model.startswith("hf:"):
-        from ..models.hf import config_from_dir
-
-        mcfg = config_from_dir(conf.model[3:])
-    else:
-        mcfg = CONFIGS[conf.model]
+    mcfg = _trainable_config(conf.model)
     head_id = serde.head_blob_id(mcfg)
     want = set(range(head_id + 1))
     blobs: dict = {}
@@ -123,7 +138,6 @@ def main(argv=None) -> int:
 
     import jax
 
-    from ..models.llama import CONFIGS
     from ..models.sharded import (
         build_adamw_train_step,
         example_batch,
@@ -136,12 +150,7 @@ def main(argv=None) -> int:
 
     summary: dict = {}
     if args.resume:
-        if conf.model.startswith("hf:"):
-            from ..models.hf import config_from_dir
-
-            mcfg = config_from_dir(conf.model[3:])
-        else:
-            mcfg = CONFIGS[conf.model]
+        mcfg = _trainable_config(conf.model)
         mesh = make_train_mesh(len(jax.devices()), mcfg)
         params, opt = restore_train_state(args.ckpt, mcfg, mesh)
         summary["resumed_step"] = int(opt["step"])

@@ -199,7 +199,8 @@ class Config:
     layer_size: int = 0
     mesh: Optional[MeshConf] = None
     distributed: Optional[DistributedConf] = None
-    # TPU extension: when set (a models.llama.CONFIGS name), seeders
+    # TPU extension: when set (a named configuration of any family,
+    # models/family.py), seeders
     # fabricate REAL model weight blobs (deterministic from ModelSeed)
     # instead of dummy zero bytes, so the disseminated layers can boot an
     # inference engine after delivery (-boot).
@@ -387,7 +388,8 @@ def create_layers(
     storage location: layers are fabricated in RAM unless ``save_disk``
     (the reference's ``-s`` flag) forces disk-backed files.
 
-    ``model``: a ``models.llama.CONFIGS`` name — layers are then REAL
+    ``model``: a named configuration of any family
+    (``models/family.py``) — layers are then REAL
     weight blobs (``serde.seeded_blob``, deterministic from ``model_seed``)
     the delivered model boots from, instead of the reference's dummy zero
     bytes; the blob's true size overrides the configured LayerSize."""
@@ -402,10 +404,10 @@ def create_layers(
             mcfg = hf.config_from_name(model)
             raw_fn = lambda lid: hf.blob_from_name(model, lid)  # noqa: E731
         else:
-            from ..models.llama import CONFIGS
+            from ..models import family
             from ..models.serde import seeded_blob
 
-            mcfg = CONFIGS[model]
+            mcfg = family.config(model)
             raw_fn = lambda lid: seeded_blob(mcfg, lid, model_seed)  # noqa: E731
 
         def blob_fn(lid):

@@ -1,4 +1,4 @@
-"""Autoregressive decoding with a KV cache: the booted engine serves.
+"""Autoregressive decoding through a cache: the booted engine serves.
 
 The reference's startup hook gestures at "launching an inference engine"
 (``/root/reference/distributor/message.go:216-241``); ``runtime/boot.py``
@@ -6,8 +6,8 @@ makes the hook assemble the model and produce logits.  This module is
 the serving half: a jitted, TPU-shaped decode loop —
 
 - **prefill**: one full-attention pass over the prompt that also writes
-  every layer's K/V into a preallocated cache (``lax.dynamic_update_
-  slice`` at static offsets);
+  every layer's serving state into a preallocated cache (``lax.dynamic_
+  update_slice`` at static offsets);
 - **decode**: ``lax.scan`` over steps, each step attending the single
   new query against the cache under a position mask (static shapes —
   the cache is sized to ``prompt + max_new`` up front, so XLA compiles
@@ -17,10 +17,14 @@ Greedy decoding is exact: ``tests/test_hf.py`` pins the generated token
 ids to the ``transformers`` implementation's ``generate`` on the same
 checkpoint.  Sampling takes a temperature + PRNG key.
 
-MoE configs serve too: the cache layer dispatches to the same
-``moe_ffn`` as the full forward (each token routes through its top-k
-experts), so the dense and MoE paths share one attention/cache
-implementation.
+What the cache holds and how a block runs through it is the family's
+(``models/family.py``: ``init_cache`` / ``layer_with_cache`` — Llama's K
+and V per KV head in ``models/llama.py``, with the same ``moe_ffn`` as
+the full forward for its routed variant; another family's latent
+vectors in its own module); the loops here are the same for every
+family, and what a family's blocks count on the way (routing
+slots, say) comes back beside the tokens in the same transfer
+(``generate_counted``).
 """
 
 from __future__ import annotations
@@ -31,16 +35,11 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .llama import (
-    ModelConfig,
-    dense_ffn,
-    gqa_attention,
-    moe_ffn,
-    qkv_proj,
-    rms_norm,
-)
+from . import family
+from .llama import ModelConfig
 
-KVCache = Dict[str, jax.Array]  # {"k","v"}: [n_layers, b, max_len, kvh, hd]
+Cache = Dict[str, jax.Array]  # the family's; every leaf [n_layers, ...]
+Counters = Dict[str, jax.Array]  # the family's; int32 scalars
 
 
 class MixedVersionError(ValueError):
@@ -70,60 +69,27 @@ def ensure_uniform_version(versions: Dict[int, str],
     return got
 
 
-def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> KVCache:
-    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
-    return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+def init_cache(cfg, batch: int, max_len: int) -> Cache:
+    return family.of(cfg).init_cache(cfg, batch, max_len)
 
 
-def _layer_with_cache(
-    p: Dict[str, jax.Array], x, positions, k_cache, v_cache, cfg: ModelConfig,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One layer over ``x`` [b, s, d]: writes this block's K/V into the
-    cache at ``positions`` and attends against the WHOLE (masked) cache
-    — the same ``gqa_attention``/``dense_ffn`` kernels as the cache-less
-    forward, with the causal mask generalized to cache-row validity.
-    Returns (x_out, k_cache, v_cache)."""
-    b, s, d = x.shape
-    h, hd = cfg.n_heads, cfg.head_dim
-    xn = rms_norm(x, p["ln1"], cfg.norm_eps)
-    q, k, v = qkv_proj(p, xn, positions, cfg)
-    # Contiguous block write at the first position (prefill writes the
-    # prompt at 0; a decode step writes one row at pos).
-    start = positions[0]
-    k_cache = jax.lax.dynamic_update_slice(k_cache, k, (0, start, 0, 0))
-    v_cache = jax.lax.dynamic_update_slice(v_cache, v, (0, start, 0, 0))
-
-    max_len = k_cache.shape[1]
-    # Valid: the cache row holds a key at position <= this query's.
-    k_valid = jnp.arange(max_len)[None, :] <= positions[:, None]  # [s, max]
-    mask = jnp.where(k_valid, 0.0, -jnp.inf).astype(jnp.float32)
-    out = gqa_attention(q, k_cache, v_cache, mask)
-    x = x + jnp.einsum("bsq,qd->bsd", out.reshape(b, s, h * hd), p["wo"])
-    ffn = moe_ffn if cfg.n_experts else dense_ffn
-    return ffn(p, x, cfg), k_cache, v_cache
-
-
-def _forward_with_cache(params, tokens, positions, cache, cfg: ModelConfig):
-    """Stacked-layer forward that threads the KV cache; returns
-    (logits for the LAST position, updated cache)."""
-    x = params["embed"][tokens]
+def _forward_with_cache(params, tokens, positions, cache, cfg):
+    """Stacked-layer forward that threads the cache; returns (logits for
+    the LAST position, updated cache, the blocks' counters added up over
+    the layers)."""
+    fam = family.of(cfg)
+    x = fam.embed(params, tokens, cfg)
 
     def body(x, scanned):
-        layer_p, k_cache, v_cache = scanned
-        x, k_cache, v_cache = _layer_with_cache(
-            layer_p, x, positions, k_cache, v_cache, cfg
+        layer_p, layer_cache = scanned
+        x, layer_cache, counted = fam.layer_with_cache(
+            layer_p, x, positions, layer_cache, cfg
         )
-        return x, (k_cache, v_cache)
+        return x, (layer_cache, counted)
 
-    x, (k_new, v_new) = jax.lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"])
-    )
-    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-    logits = jnp.einsum(
-        "bd,dv->bv", x[:, -1, :], params["lm_head"],
-        preferred_element_type=jnp.float32,
-    )
-    return logits, {"k": k_new, "v": v_new}
+    x, (cache, counted) = jax.lax.scan(body, x, (params["layers"], cache))
+    logits = fam.logits(params, x[:, -1:, :], cfg)[:, 0, :]
+    return logits, cache, jax.tree.map(lambda c: c.sum(0), counted)
 
 
 def _pick(logits, step_key, temperature: float):
@@ -148,24 +114,25 @@ def _prefill_fn(cfg: ModelConfig, p: int):
 @functools.lru_cache(maxsize=32)
 def _decode_fn(cfg: ModelConfig, p: int, max_new: int, temperature: float):
     @jax.jit
-    def decode(params, cache, first, keys):
+    def decode(params, cache, first, keys, counted):
         def step(carry, scanned):
-            cache, token, pos = carry
+            cache, token, pos, counted = carry
             step_key, = scanned
             with jax.named_scope("generate.step"):
-                logits, cache = _forward_with_cache(
+                logits, cache, more = _forward_with_cache(
                     params, token[:, None], pos[None], cache, cfg
                 )
                 nxt = _pick(logits, step_key, temperature)
-            return (cache, nxt, pos + 1), token
+            counted = jax.tree.map(jnp.add, counted, more)
+            return (cache, nxt, pos + 1, counted), token
 
-        (_, last, _), toks = jax.lax.scan(
-            step, (cache, first, jnp.asarray(p, jnp.int32)),
+        (_, last, _, counted), toks = jax.lax.scan(
+            step, (cache, first, jnp.asarray(p, jnp.int32), counted),
             (keys,), length=max_new - 1,
         )
         # toks holds tokens emitted BEFORE each step: [first, ...]; the
         # final pick is `last`.
-        return jnp.concatenate([toks.T, last[:, None]], axis=1)
+        return jnp.concatenate([toks.T, last[:, None]], axis=1), counted
 
     return decode
 
@@ -181,7 +148,7 @@ def _decode_step_fn(cfg: ModelConfig, temperature: float):
     @jax.jit
     def step(params, cache, token, pos, step_key):
         with jax.named_scope("generate.step"):
-            logits, cache = _forward_with_cache(
+            logits, cache, _ = _forward_with_cache(
                 params, token[:, None], pos[None], cache, cfg
             )
             return _pick(logits, step_key, temperature), cache
@@ -221,7 +188,7 @@ def generate_stepwise(
     b, p = prompt.shape
     cache = init_cache(cfg, b, p + max_new)
     params, _ = params_fn()
-    logits, cache = _prefill_fn(cfg, p)(params, prompt, cache)
+    logits, cache, _ = _prefill_fn(cfg, p)(params, prompt, cache)
     keys = (jax.random.split(key, max_new) if key is not None
             else jnp.zeros((max_new, 2), jnp.uint32))
     token = _pick(logits, keys[0], temperature)
@@ -235,18 +202,22 @@ def generate_stepwise(
     return jnp.stack(out, axis=1)
 
 
-def generate(
+def generate_counted(
     params: Dict[str, Any],
     prompt: jax.Array,
-    cfg: ModelConfig,
+    cfg,
     max_new: int,
     temperature: float = 0.0,
     key: Optional[jax.Array] = None,
-) -> jax.Array:
+) -> Tuple[jax.Array, Counters]:
     """Decode ``max_new`` tokens after ``prompt`` [b, p] (int32).
 
     temperature 0 = greedy (exact — parity-tested against transformers);
-    otherwise softmax sampling with ``key``.  Returns [b, max_new].
+    otherwise softmax sampling with ``key``.  Returns ([b, max_new], what
+    the family's blocks counted over every position of the request — a
+    dict of int32 scalars, empty for a family that counts nothing); both
+    are outputs of the same jitted programs, so one ``device_get`` brings
+    them.
 
     The prefill and decode programs are built per (cfg, shapes,
     temperature) and cached — repeated serving calls on a booted model
@@ -258,12 +229,18 @@ def generate(
     b, p = prompt.shape
     cache = init_cache(cfg, b, p + max_new)
 
-    logits, cache = _prefill_fn(cfg, p)(params, prompt, cache)
+    logits, cache, counted = _prefill_fn(cfg, p)(params, prompt, cache)
     keys = (jax.random.split(key, max_new) if key is not None
             else jnp.zeros((max_new, 2), jnp.uint32))
     first = _pick(logits, keys[0], temperature)
     if max_new == 1:
-        return first[:, None]
+        return first[:, None], counted
     return _decode_fn(cfg, p, max_new, temperature)(
-        params, cache, first, keys[1:]
+        params, cache, first, keys[1:], counted
     )
+
+
+def generate(params, prompt, cfg, max_new, temperature=0.0, key=None):
+    """``generate_counted``'s tokens alone."""
+    return generate_counted(params, prompt, cfg, max_new, temperature,
+                            key)[0]

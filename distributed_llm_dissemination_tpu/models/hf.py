@@ -34,6 +34,7 @@ from typing import Any, Dict
 
 import numpy as np
 
+from . import family, llama
 from .llama import ModelConfig
 
 PREFIX = "hf:"
@@ -76,8 +77,14 @@ def config_from_dir(path: str) -> ModelConfig:
     with open(os.path.join(path, "config.json")) as f:
         hf = json.load(f)
     arch = (hf.get("architectures") or ["?"])[0]
-    if "Llama" not in arch:
-        raise ValueError(f"unsupported HF architecture {arch!r} (Llama only)")
+    if llama.HF_ARCHITECTURE not in arch:
+        theirs = [name for name in family.FAMILIES
+                  if family.module(name).HF_ARCHITECTURE in arch]
+        raise family.FamilyNotSupported(
+            f"unsupported HF architecture {arch!r} (Llama only)"
+            + (f": models/hf.py cannot load the {theirs[0]} family — it "
+               "maps no checkpoint tensor names to that family's blob "
+               "leaves" if theirs else ""))
     if hf.get("rope_scaling"):
         raise ValueError(
             f"checkpoint uses rope_scaling={hf['rope_scaling']!r} "

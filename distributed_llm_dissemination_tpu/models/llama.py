@@ -17,15 +17,24 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import family
+
+Spec = Tuple[str, Tuple[int, ...]]
+HF_ARCHITECTURE = "Llama"  # what models/hf.py accepts
+
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
+    # Which module of ``models/`` answers for this configuration
+    # (``models/family.py``): a class attribute, not a field.
+    family = "llama"
+
     name: str = "tiny"
     vocab: int = 256
     d_model: int = 128
@@ -47,16 +56,7 @@ class ModelConfig:
     def layer_nbytes(self) -> int:
         """Bytes of one transformer layer's params in this dtype — the
         'LayerSize' the dissemination configs should use."""
-        itemsize = np.dtype(self.dtype).itemsize
-        d, f, h, kv = self.d_model, self.d_ff, self.n_heads, self.n_kv_heads
-        hd = self.head_dim
-        attn = d * h * hd + 2 * d * kv * hd + h * hd * d
-        if self.n_experts:
-            ffn = self.n_experts * 3 * d * f + d * self.n_experts
-        else:
-            ffn = 3 * d * f
-        norms = 2 * d
-        return (attn + ffn + norms) * itemsize
+        return family.spec_nbytes(layer_param_specs(self), self.dtype)
 
 
 # Real Llama-3 family shapes (public architecture constants) + test sizes.
@@ -95,6 +95,42 @@ CONFIGS: Dict[str, ModelConfig] = {
         n_heads=128, n_kv_heads=8, d_ff=53248,
     ),
 }
+
+
+# --------------------------------------------------------------- blob leaves
+
+def layer_param_specs(cfg: ModelConfig) -> List[Spec]:
+    """(name, shape) of one layer's leaves, in canonical blob order."""
+    d, f = cfg.d_model, cfg.d_ff
+    h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    specs: List[Spec] = [
+        ("wq", (d, h * hd)),
+        ("wk", (d, kv * hd)),
+        ("wv", (d, kv * hd)),
+        ("wo", (h * hd, d)),
+        ("ln1", (d,)),
+        ("ln2", (d,)),
+    ]
+    if cfg.n_experts:
+        e = cfg.n_experts
+        specs += [
+            ("router", (d, e)),
+            ("w1", (e, d, f)),
+            ("w3", (e, d, f)),
+            ("w2", (e, f, d)),
+        ]
+    else:
+        specs += [("w1", (d, f)), ("w3", (d, f)), ("w2", (f, d))]
+    return specs
+
+
+def head_param_specs(cfg: ModelConfig) -> List[Spec]:
+    """(name, shape) of the non-layer leaves, in canonical blob order."""
+    return [
+        ("embed", (cfg.vocab, cfg.d_model)),
+        ("ln_f", (cfg.d_model,)),
+        ("lm_head", (cfg.d_model, cfg.vocab)),
+    ]
 
 
 # ---------------------------------------------------------------------- init
@@ -140,31 +176,27 @@ def init_head_params(
     }
 
 
-def model_keys(cfg: ModelConfig, key: jax.Array):
+def model_keys(cfg, key: jax.Array):
     """Deterministic per-component key split — exposed so one layer's
     weights can be regenerated in isolation (seeded dissemination blobs)
-    bit-identically to ``init_params``."""
+    bit-identically to ``init_params``.  The same for every family."""
     k_emb, k_layers, k_out = jax.random.split(key, 3)
     return k_emb, jax.random.split(k_layers, cfg.n_layers), k_out
 
 
-def init_params(cfg: ModelConfig, key: jax.Array) -> Dict[str, Any]:
-    """Full model params.  Layer weights are STACKED along a leading
-    n_layers axis — one pytree leaf per weight kind — so a layer is a
-    slice (disseminable blob) and scan/pipeline stages index it."""
+def init_params(cfg, key: jax.Array) -> Dict[str, Any]:
+    """Full model params of any family.  Layer weights are STACKED along
+    a leading n_layers axis — one pytree leaf per weight kind — so a
+    layer is a slice (disseminable blob) and scan/pipeline stages index
+    it; the head blob's leaves lie beside ``"layers"``."""
+    fam = family.of(cfg)
     k_emb, layer_keys, k_out = model_keys(cfg, key)
-    per_layer = [init_layer_params(cfg, lk) for lk in layer_keys]
+    per_layer = [fam.init_layer_params(cfg, lk) for lk in layer_keys]
     stacked = {
         name: jnp.stack([lp[name] for lp in per_layer])
         for name in per_layer[0]
     }
-    head = init_head_params(cfg, k_emb, k_out)
-    return {
-        "embed": head["embed"],
-        "layers": stacked,
-        "ln_f": head["ln_f"],
-        "lm_head": head["lm_head"],
-    }
+    return {**fam.init_head_params(cfg, k_emb, k_out), "layers": stacked}
 
 
 # ------------------------------------------------------------------- blocks
@@ -277,26 +309,81 @@ def layer_apply(
     return dense_ffn(p, x, cfg)
 
 
-# ------------------------------------------------------------------ forward
+# ------------------------------------------------------- embedding and head
 
-def forward(params: Dict[str, Any], tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
-    """Logits for [batch, seq] int tokens.  Layers run under lax.scan over
-    the stacked layer axis — one traced layer body regardless of depth."""
-    b, s = tokens.shape
-    positions = jnp.arange(s)
-    x = params["embed"][tokens]
+def embed(params: Dict[str, Any], tokens: jax.Array,
+          cfg: ModelConfig) -> jax.Array:
+    return params["embed"][tokens]
 
-    def body(x, layer_p):
-        return layer_apply(layer_p, x, positions, cfg), None
 
-    x, _ = jax.lax.scan(body, x, params["layers"])
+def logits(params: Dict[str, Any], x: jax.Array,
+           cfg: ModelConfig) -> jax.Array:
+    """Final norm and head over ``x`` [b, s, d]: float32 logits."""
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-    # f32 accumulation, matching the KV-cached decode head
-    # (generate.py:_step_fn) — on bf16 checkpoints a lower-precision
-    # accumulation here could make greedy argmax diverge between the
-    # full forward and the decode loop.
+    # f32 accumulation, the same for the full forward and the KV-cached
+    # decode (models/generate.py) — on bf16 checkpoints a lower-precision
+    # accumulation in one of them could make greedy argmax diverge
+    # between the two.
     return jnp.einsum("bsd,dv->bsv", x, params["lm_head"],
                       preferred_element_type=jnp.float32)
+
+
+# ------------------------------------------------------------ serving cache
+
+KVCache = Dict[str, jax.Array]  # {"k","v"}: [n_layers, b, max_len, kvh, hd]
+
+
+def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> KVCache:
+    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
+    return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+
+
+def layer_with_cache(
+    p: Dict[str, jax.Array], x, positions, cache: KVCache, cfg: ModelConfig,
+) -> Tuple[jax.Array, KVCache, Dict[str, jax.Array]]:
+    """One layer over ``x`` [b, s, d]: writes this block's K/V into the
+    layer's cache at ``positions`` and attends against the WHOLE (masked)
+    cache — the same ``gqa_attention``/``dense_ffn`` kernels as the
+    cache-less forward, with the causal mask generalized to cache-row
+    validity.  Returns (x_out, cache, counters); this family counts
+    nothing."""
+    b, s, d = x.shape
+    h, hd = cfg.n_heads, cfg.head_dim
+    xn = rms_norm(x, p["ln1"], cfg.norm_eps)
+    q, k, v = qkv_proj(p, xn, positions, cfg)
+    # Contiguous block write at the first position (prefill writes the
+    # prompt at 0; a decode step writes one row at pos).
+    start = positions[0]
+    k_cache = jax.lax.dynamic_update_slice(cache["k"], k, (0, start, 0, 0))
+    v_cache = jax.lax.dynamic_update_slice(cache["v"], v, (0, start, 0, 0))
+
+    max_len = k_cache.shape[1]
+    # Valid: the cache row holds a key at position <= this query's.
+    k_valid = jnp.arange(max_len)[None, :] <= positions[:, None]  # [s, max]
+    mask = jnp.where(k_valid, 0.0, -jnp.inf).astype(jnp.float32)
+    out = gqa_attention(q, k_cache, v_cache, mask)
+    x = x + jnp.einsum("bsq,qd->bsd", out.reshape(b, s, h * hd), p["wo"])
+    ffn = moe_ffn if cfg.n_experts else dense_ffn
+    return ffn(p, x, cfg), {"k": k_cache, "v": v_cache}, {}
+
+
+# ------------------------------------------------------------------ forward
+
+def forward(params: Dict[str, Any], tokens: jax.Array, cfg) -> jax.Array:
+    """Logits for [batch, seq] int tokens, for a configuration of any
+    family (``models/family.py``: embedding, block and head are the
+    family's).  Layers run under lax.scan over the stacked layer axis —
+    one traced layer body regardless of depth."""
+    fam = family.of(cfg)
+    b, s = tokens.shape
+    positions = jnp.arange(s)
+    x = fam.embed(params, tokens, cfg)
+
+    def body(x, layer_p):
+        return fam.layer_apply(layer_p, x, positions, cfg), None
+
+    x, _ = jax.lax.scan(body, x, params["layers"])
+    return fam.logits(params, x, cfg)
 
 
 def loss_fn(params: Dict[str, Any], tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
@@ -309,6 +396,6 @@ def loss_fn(params: Dict[str, Any], tokens: jax.Array, cfg: ModelConfig) -> jax.
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
-def forward_jit(params, tokens, cfg: ModelConfig):
+def forward_jit(params, tokens, cfg):
     with jax.named_scope("model.forward"):
         return forward(params, tokens, cfg)

@@ -4,7 +4,7 @@ The reference disseminates opaque byte blobs and its ``startupMsg`` is "the
 hook that would launch an inference engine"
 (``/root/reference/distributor/message.go:216-241``).  This module defines
 the byte format that closes that loop for real: each transformer layer of a
-``models.llama`` model serializes to one blob (the dissemination unit), and
+model of any family (``models/family.py``) serializes to one blob (the dissemination unit), and
 a receiver reassembles delivered blobs back into the stacked-layer params
 pytree the jitted forward consumes.
 
@@ -12,8 +12,9 @@ Format (deterministic, self-describing via the ModelConfig):
 - Blob ``i`` for ``0 <= i < n_layers`` is layer ``i``'s weights — each leaf
   in the fixed ``layer_param_specs`` order, as raw C-order bytes of
   ``cfg.dtype``.
-- Blob ``head_blob_id(cfg) == n_layers`` holds the non-layer params:
-  ``embed``, ``ln_f``, ``lm_head`` (same encoding).
+- Blob ``head_blob_id(cfg) == n_layers`` holds the non-layer params in
+  ``head_param_specs`` order (Llama: ``embed``, ``ln_f``, ``lm_head``),
+  same encoding.
 
 Two decode paths, bit-identical by construction (and by test):
 - **host**: numpy views over the blob bytes (zero-copy) — used when layers
@@ -35,56 +36,31 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .llama import ModelConfig
-
-Spec = Tuple[str, Tuple[int, ...]]
-
-
-def layer_param_specs(cfg: ModelConfig) -> List[Spec]:
-    """(name, shape) of one layer's leaves, in canonical blob order."""
-    d, f = cfg.d_model, cfg.d_ff
-    h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    specs: List[Spec] = [
-        ("wq", (d, h * hd)),
-        ("wk", (d, kv * hd)),
-        ("wv", (d, kv * hd)),
-        ("wo", (h * hd, d)),
-        ("ln1", (d,)),
-        ("ln2", (d,)),
-    ]
-    if cfg.n_experts:
-        e = cfg.n_experts
-        specs += [
-            ("router", (d, e)),
-            ("w1", (e, d, f)),
-            ("w3", (e, d, f)),
-            ("w2", (e, f, d)),
-        ]
-    else:
-        specs += [("w1", (d, f)), ("w3", (d, f)), ("w2", (f, d))]
-    return specs
+from . import family
+from .llama import ModelConfig, Spec
 
 
-def head_param_specs(cfg: ModelConfig) -> List[Spec]:
+def layer_param_specs(cfg) -> List[Spec]:
+    """(name, shape) of one layer's leaves, in canonical blob order: the
+    configuration's family says (``models/family.py``)."""
+    return family.of(cfg).layer_param_specs(cfg)
+
+
+def head_param_specs(cfg) -> List[Spec]:
     """(name, shape) of the non-layer leaves, in canonical blob order."""
-    return [
-        ("embed", (cfg.vocab, cfg.d_model)),
-        ("ln_f", (cfg.d_model,)),
-        ("lm_head", (cfg.d_model, cfg.vocab)),
-    ]
+    return family.of(cfg).head_param_specs(cfg)
 
 
 def head_blob_id(cfg: ModelConfig) -> int:
-    """The blob id carrying embed/ln_f/lm_head: one past the layers."""
+    """The blob id carrying the non-layer leaves: one past the layers."""
     return cfg.n_layers
 
 
 def blob_nbytes(cfg: ModelConfig, blob_id: int) -> int:
     """Exact byte size of a blob (== cfg.layer_nbytes() for layer blobs)."""
-    itemsize = np.dtype(cfg.dtype).itemsize
     specs = (head_param_specs(cfg) if blob_id == head_blob_id(cfg)
              else layer_param_specs(cfg))
-    return sum(int(np.prod(s)) for _, s in specs) * itemsize
+    return family.spec_nbytes(specs, cfg.dtype)
 
 
 def _encode(leaves: Sequence[np.ndarray]) -> bytes:
@@ -140,16 +116,12 @@ def params_from_blobs(
         name: np.stack([lp[name] for lp in per_layer]) for name, _ in specs
     }
     head = _split_blob(cfg, blobs[head_blob_id(cfg)], head_param_specs(cfg))
-    return {
-        "embed": head["embed"],
-        "layers": stacked,
-        "ln_f": head["ln_f"],
-        "lm_head": head["lm_head"],
-    }
+    return {**head, "layers": stacked}
 
 
 def head_from_blob(cfg: ModelConfig, data) -> Dict[str, np.ndarray]:
-    """Host path: embed/ln_f/lm_head views over the head blob's bytes."""
+    """Host path: the non-layer leaves as views over the head blob's
+    bytes."""
     return _split_blob(cfg, data, head_param_specs(cfg))
 
 
@@ -171,17 +143,18 @@ def seeded_blob(cfg: ModelConfig, blob_id: int, seed: int = 0) -> bytes:
     model can be checked against an independently initialized source."""
     import jax
 
-    from .llama import init_head_params, init_layer_params, model_keys
+    from .llama import model_keys
 
+    fam = family.of(cfg)
     k_emb, layer_keys, k_out = model_keys(cfg, jax.random.key(seed))
     if blob_id == head_blob_id(cfg):
-        head = init_head_params(cfg, k_emb, k_out)
+        head = fam.init_head_params(cfg, k_emb, k_out)
         leaves = [np.asarray(jax.device_get(head[name]))
                   for name, _ in head_param_specs(cfg)]
         return _encode(leaves)
     if not 0 <= blob_id < cfg.n_layers:
         raise ValueError(f"blob {blob_id} out of range for {cfg.name}")
-    p = init_layer_params(cfg, layer_keys[blob_id])
+    p = fam.init_layer_params(cfg, layer_keys[blob_id])
     return _encode([np.asarray(jax.device_get(p[name]))
                     for name, _ in layer_param_specs(cfg)])
 

@@ -36,9 +36,21 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..parallel.mesh import make_mesh
 from ..parallel.ring_attention import ring_attention
+from . import family
 from .llama import ModelConfig, rms_norm, rope, route_topk
 
 AXES = ("dp", "sp", "pp", "ep", "tp")
+
+
+def _llama_only(cfg) -> None:
+    """Every entry of this module refuses another family by name: its
+    partition specs, local layer and pipeline stages are written out for
+    Llama's nine leaves, its one FFN and its K/V cache."""
+    family.only(
+        cfg, ("llama",), "models/sharded.py",
+        "its partition specs, sharded layer and pipeline stages (train "
+        "step, build_pp_forward, build_pp_decode) are written for Llama's "
+        "leaves and K/V cache; this family has no ep/tp/pp form yet")
 
 
 def factor_mesh_axes(n_devices: int, cfg: ModelConfig) -> Dict[str, int]:
@@ -47,6 +59,7 @@ def factor_mesh_axes(n_devices: int, cfg: ModelConfig) -> Dict[str, int]:
 
     tp must divide n_kv_heads, pp must divide n_layers, ep must divide
     n_experts (dense models keep ep=1); sp and dp are unconstrained."""
+    _llama_only(cfg)
     sizes = {a: 1 for a in AXES}
 
     def accepts(axis: str, f: int) -> bool:
@@ -79,6 +92,7 @@ def make_train_mesh(n_devices: int, cfg: ModelConfig) -> Mesh:
 def param_specs(cfg: ModelConfig) -> Dict[str, Any]:
     """PartitionSpec per parameter leaf (layer leaves lead with the
     pp-sharded stacked-layer axis)."""
+    _llama_only(cfg)
     layers = {
         "wq": P("pp", None, "tp"),
         "wk": P("pp", None, "tp"),
@@ -391,6 +405,7 @@ def build_pp_forward(cfg: ModelConfig, mesh: Mesh, pp_axis: str):
 
     Any extra mesh axes (e.g. tp) replicate the computation — this is the
     serving form of the staged placement, not the full 5-axis program."""
+    _llama_only(cfg)
     from .llama import layer_apply
 
     pp = mesh.shape[pp_axis]
@@ -451,7 +466,8 @@ def build_pp_decode(cfg: ModelConfig, mesh: Mesh, pp_axis: str,
     as [b, d_model], and argmax picks the next token identically on
     every device, so the replicated decode loop can never diverge.
     Uneven padded slices work exactly as in ``build_pp_forward``."""
-    from .generate import _layer_with_cache
+    _llama_only(cfg)
+    from .llama import layer_with_cache
 
     pp = mesh.shape[pp_axis]
     fwd = [(i, (i + 1) % pp) for i in range(pp)]
@@ -473,8 +489,9 @@ def build_pp_decode(cfg: ModelConfig, mesh: Mesh, pp_axis: str,
 
                 def body(h, scanned):
                     layer_p, k_l, v_l, li = scanned
-                    h_new, k_new, v_new = _layer_with_cache(
-                        layer_p, h, positions, k_l, v_l, cfg)
+                    h_new, kv_new, _ = layer_with_cache(
+                        layer_p, h, positions, {"k": k_l, "v": v_l}, cfg)
+                    k_new, v_new = kv_new["k"], kv_new["v"]
                     valid = real & (li < count)
                     return (
                         jnp.where(valid, h_new, h),

@@ -57,9 +57,13 @@ def restore_train_state(path: str, cfg: ModelConfig, mesh: Mesh):
     import numpy as np
     import orbax.checkpoint as ocp
 
+    from . import family
     from .llama import init_params
     from .sharded import init_adamw_state
 
+    family.only(cfg, ("llama",), "models/train_ckpt.py",
+                "a training state is the sharded train step's "
+                "(models/sharded.py), which knows Llama's leaves only")
     shardings = _state_shardings(cfg, mesh)
     # Abstract targets: shape/dtype from a throwaway host init (cheap at
     # config scale), sharding from the train-step specs.

@@ -4,8 +4,8 @@ The reference broadcasts a ``startupMsg`` whose handler is a stub — "the
 hook that would launch an inference engine"
 (``/root/reference/distributor/message.go:216-241``,
 ``distributor/node.go:1387-1389``).  Here the hook boots one: a receiver
-assembles its delivered layer blobs into ``models.llama`` params and runs a
-jitted forward pass, so dissemination ends at a *serving model*, not a pile
+assembles its delivered layer blobs into its model family's params
+(``models/family.py``) and runs a jitted forward pass, so dissemination ends at a *serving model*, not a pile
 of bytes — and the leader can report time-to-first-token next to TTD.
 
 Two boot shapes, chosen by what the node holds:
@@ -87,11 +87,12 @@ def _stage_forward_jitted():
             import jax
             import jax.numpy as jnp
 
-            from ..models.llama import layer_apply
+            from ..models import family
 
             @functools.partial(jax.jit, static_argnums=(2,),
                                donate_argnums=(1,))
             def stage_forward(stacked, x, cfg):
+                layer_apply = family.of(cfg).layer_apply
                 positions = jnp.arange(x.shape[1])
 
                 def body(x, layer_p):
@@ -253,7 +254,7 @@ def stage_blob_leaves(cfg, blob_id: int, src, codec: str = "raw",
 
 
 def decode_head(cfg, src, codec: str = "raw", donate: bool = False):
-    """embed/ln_f/lm_head leaves from a head-blob ``LayerSrc`` — the
+    """The non-layer leaves from a head-blob ``LayerSrc`` — the
     device path when the blob is HBM-resident (jax arrays), the host
     path otherwise (numpy).  Shared by the full boot and pod serving
     (``runtime/pp_serve.py``) so the decode dispatch lives once.
@@ -403,14 +404,22 @@ def boot_from_layers(
     # footprint); otherwise host blobs go up in one device_put per
     # leaf-stack.
     held = layer_ids + ([head_id] if head_id in layers else [])
-    dev_blobs = {lid: _device_blob(layers[lid]) for lid in held}
     streamed: Dict[int, dict] = {}
     stream_wait_s = 0.0
     if stager is not None:
         t_w = time.monotonic()
         with trace.span("boot.wait_stream", node=node_id, blobs=len(held)):
-            streamed = stager.collect(held)
+            # The boot owns the staged leaves from here (its own dicts,
+            # the stager's references dropped): assembly below pops each
+            # leaf as it stacks it, so the device holds the model and ONE
+            # stacked leaf kind in flight, not the model twice.
+            streamed = {lid: dict(leaves)
+                        for lid, leaves in stager.collect(held).items()}
+            stager.release(streamed)
         stream_wait_s = time.monotonic() - t_w
+    # Taken AFTER the wait: a wire blob the stager released while staging
+    # must not be kept alive through assembly by a reference held here.
+    dev_blobs = {lid: _device_blob(layers[lid]) for lid in held}
     stacked = None
     via = ""
     with trace.span("boot.assemble", node=node_id, blobs=len(held)) as asm:
@@ -430,7 +439,7 @@ def boot_from_layers(
                 with jax.named_scope("boot.assemble"):
                     stacked = {
                         name: jnp.concatenate(
-                            [streamed[lid][name] for lid in layer_ids])
+                            [streamed[lid].pop(name) for lid in layer_ids])
                         for name, _ in specs
                     }
                 # The decoded params exist: blobs whose device copy the boot
@@ -480,7 +489,8 @@ def boot_from_layers(
         if full:
             head_on_device = dev_blobs[head_id] is not None
             if head_id in streamed:
-                head = {name: a[0] for name, a in streamed[head_id].items()}
+                head = {name: a[0]
+                        for name, a in streamed.pop(head_id).items()}
                 head_on_device = True  # streamed leaves are already placed
             else:
                 head = decode_head(cfg, layers[head_id], codec,
@@ -492,12 +502,7 @@ def boot_from_layers(
                     else jnp.asarray(a)
                     for name, a in head.items()
                 }
-            params = {
-                "embed": head["embed"],
-                "layers": stacked,
-                "ln_f": head["ln_f"],
-                "lm_head": head["lm_head"],
-            }
+            params = {**head, "layers": stacked}
         asm.set(via=via)
     if full:
         if tokens is None:
@@ -676,12 +681,7 @@ def precompile_boot(
     if full:
         head_abs = {name: sds(shape, dt, leaf_sharding)
                     for name, shape in serde.head_param_specs(cfg)}
-        params_abs = {
-            "embed": head_abs["embed"],
-            "layers": stacked_abs,
-            "ln_f": head_abs["ln_f"],
-            "lm_head": head_abs["lm_head"],
-        }
+        params_abs = {**head_abs, "layers": stacked_abs}
         tok_abs = jax.ShapeDtypeStruct((1, 16), jnp.int32)
         forward_jit.lower(params_abs, tok_abs, cfg).compile()
         compiled.append("forward")

@@ -2685,16 +2685,17 @@ class ReceiverNode:
 
             from ..models.generate import (
                 ensure_uniform_version,
-                generate,
+                generate_counted,
                 generate_stepwise,
             )
 
             temp = float(msg.temperature)
             prompt_arr = jnp.asarray([list(msg.prompt)], jnp.int32)
             prng = jax.random.key(int(msg.seed)) if temp > 0 else None
+            counted = {}
             with trace.span("serve.generate", id=req, node=me,
                             prompt_tokens=len(msg.prompt),
-                            new_tokens=int(msg.max_new)):
+                            new_tokens=int(msg.max_new)) as gen_span:
                 if os.environ.get("DLD_TOKEN_FLIP", "0") == "1":
                     # Per-TOKEN flip granularity (docs/rollout.md): re-read
                     # the serving tree before every decode step, so an
@@ -2716,10 +2717,15 @@ class ReceiverNode:
                         params_fn, prompt_arr, cfg, int(msg.max_new),
                         temperature=temp, key=prng)
                 else:
-                    toks = generate(
+                    toks, counted = generate_counted(
                         res.params, prompt_arr, cfg, int(msg.max_new),
                         temperature=temp, key=prng)
-                out = [int(t) for t in jax.device_get(toks)[0]]
+                # What the model's blocks counted over the request (a
+                # routed family's slots; nothing for Llama) comes with
+                # the tokens, in the one transfer.
+                toks, counted = jax.device_get((toks, counted))
+                out = [int(t) for t in toks[0]]
+                gen_span.set(**{k: int(v) for k, v in counted.items()})
         except Exception as e:  # noqa: BLE001 — must answer, not vanish
             log.error("generation request failed", requester=msg.src_id,
                       req=msg.req_id, err=repr(e))

@@ -80,6 +80,7 @@ class StreamingBootStager:
         self._lock = threading.Lock()
         self._done = threading.Condition(self._lock)
         self._staged: Dict[int, dict] = {}
+        self._released = 0  # staged blobs a boot took over (release)
         # Shard-gather state (docs/sharding.md): blob -> accumulated
         # shard parts, and blob -> the materialized full layer's bytes.
         self._shards: Dict[int, dict] = {}
@@ -138,7 +139,7 @@ class StreamingBootStager:
     @property
     def staged_count(self) -> int:
         with self._lock:
-            return len(self._staged)
+            return len(self._staged) + self._released
 
     # ------------------------------------------------------------ consume
 
@@ -155,6 +156,16 @@ class StreamingBootStager:
                 return {}
             return {b: self._staged[b] for b in blob_ids
                     if b in self._staged}
+
+    def release(self, blob_ids) -> None:
+        """The boot took these blobs' staged leaves over (``collect``):
+        drop this stager's references, so that each leaf frees the moment
+        the boot has stacked it.  The submission markers stay — a
+        released blob is not staged again."""
+        with self._lock:
+            for b in blob_ids:
+                if self._staged.pop(b, None) is not None:
+                    self._released += 1
 
     def close(self) -> None:
         with self._lock:
@@ -209,6 +220,9 @@ class StreamingBootStager:
             if leaves is not None:
                 log.info("layer boot-staged (streamed)", blobID=blob_id,
                          stage_ms=round(dt * 1000, 1), in_wire=in_wire)
+            # This frame waits in ``get`` next: it must not keep the last
+            # blob's leaves (or its wire blob) alive meanwhile.
+            item = src = leaves = None
 
     def _pair(self, blob_id) -> Optional[str]:
         """The blob's pair id (every span of one blob shares it)."""

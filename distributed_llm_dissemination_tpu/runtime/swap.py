@@ -374,10 +374,8 @@ class SwapController:
         layers = {name: jnp.stack([jnp.asarray(per_slot[i][name])
                                    for i in range(n)])
                   for name in names}
-        return {"embed": jnp.asarray(head_leaves["embed"]),
-                "layers": layers,
-                "ln_f": jnp.asarray(head_leaves["ln_f"]),
-                "lm_head": jnp.asarray(head_leaves["lm_head"])}
+        return {**{name: jnp.asarray(a) for name, a in head_leaves.items()},
+                "layers": layers}
 
     def _flip_when_ready(self, version: str) -> None:
         """The commit fence's flip half: wait (bounded) for the prepare,

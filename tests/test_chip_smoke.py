@@ -23,24 +23,35 @@ import chip_smoke
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _run_smoke_in_child(model: str, platform: str, timeout: float) -> dict:
+def _run_smoke_in_child(model: str, platform: str, timeout: float,
+                        cache_dir: str = None) -> dict:
     """run_smoke in a FRESH interpreter (this pytest process imported
     jax long ago, and the parent's jax-freedom is part of the contract);
-    the child also reports whether the orchestration imported jax."""
+    the child also reports whether the orchestration imported jax.
+    ``cache_dir``: a compile cache of the smoke's own
+    (``JAX_COMPILATION_CACHE_DIR``, which the program honours) — the
+    smoke counts the entries its second process writes, and in the
+    checkout's shared directory another test worker's compile lands in
+    that count."""
+    env = dict(os.environ)
+    if cache_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     code = (
         "import json, sys, chip_smoke\n"
         f"r = chip_smoke.run_smoke({model!r}, {platform!r}, serve_s=12)\n"
         "r['parent_imported_jax'] = 'jax' in sys.modules\n"
         "print(json.dumps(r))\n")
-    out = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+    out = subprocess.run([sys.executable, "-c", code], cwd=REPO, env=env,
                          capture_output=True, text=True, timeout=timeout)
     assert out.returncode == 0, out.stderr[-3000:]
     return json.loads(out.stdout.strip().splitlines()[-1])
 
 
 @pytest.fixture(scope="module")
-def tiny_cpu_smoke():
-    return _run_smoke_in_child("tiny", "cpu", timeout=240)
+def tiny_cpu_smoke(tmp_path_factory):
+    return _run_smoke_in_child(
+        "tiny", "cpu", timeout=240,
+        cache_dir=str(tmp_path_factory.mktemp("smoke_compile_cache")))
 
 
 @pytest.mark.timeout(300)

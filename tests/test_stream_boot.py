@@ -609,3 +609,42 @@ def test_stream_boot_env_gate(monkeypatch):
     finally:
         r.close()
         t.close()
+
+
+@pytest.mark.parametrize("family_name", ["tiny", "tiny-longcat"])
+def test_the_boot_takes_the_staged_leaves_over_and_frees_them(family_name):
+    """Assembly pops every staged leaf as it stacks it and the stager
+    drops its own references (PR 27: a 6.4 GiB replica held twice through
+    assembly did not fit a 15.75 GiB chip), so after a boot no per-layer
+    leaf is alive anywhere but inside the stacked parameters; the count of
+    what was staged stays, and a second boot of the same store — nothing
+    streamed is left for it — still assembles the same model."""
+    import gc
+    import weakref
+
+    from distributed_llm_dissemination_tpu.models import family
+
+    cfg = family.config(family_name)
+    ids = list(range(cfg.n_layers)) + [serde.head_blob_id(cfg)]
+    layers = seeded_layers(cfg)
+    stager = stage_all(cfg, layers, ids)
+    try:
+        staged = stager.collect(ids, timeout=TIMEOUT)
+        assert set(staged) == set(ids)
+        alive = [weakref.ref(a) for leaves in staged.values()
+                 for a in leaves.values()]
+        del staged
+        first = boot_from_layers(cfg, layers, stager=stager)
+        assert first.via == "streamed per-layer"
+        gc.collect()
+        assert all(ref() is None for ref in alive)
+        assert stager.collect(ids, timeout=TIMEOUT) == {}
+        assert stager.staged_count == len(ids)
+        again = boot_from_layers(cfg, layers, stager=stager)
+    finally:
+        stager.close()
+    assert again.via != "streamed per-layer"
+    assert np.array_equal(np.asarray(first.logits), np.asarray(again.logits))
+    want = forward_jit(init_params(cfg, jax.random.key(SEED)),
+                       jnp.zeros((1, 16), jnp.int32), cfg)
+    assert np.array_equal(np.asarray(first.logits), np.asarray(want))
