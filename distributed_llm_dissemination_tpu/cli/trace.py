@@ -20,8 +20,10 @@ Mapping:
 - the entry points' ``"spans"`` dumps (``utils/trace.dump_spans``)
   become one slice per interval span on a per-thread track, placed on
   the wall clock by the ``"span counters"`` record's clock pair; the
-  ``decode.stage`` spans' ``fast_bytes`` / ``slow_bytes`` are added up
-  and printed on stderr (give one round's logs for the round's sum).
+  ``decode.stage`` spans' ``fast_bytes`` / ``slow_bytes`` and the
+  ``fabric.publish`` spans' ``bytes`` / ``host_copy_bytes`` / ``pieces``
+  are added up and printed on stderr (give one round's logs for the
+  round's sum).
 
 Usage:
     python -m distributed_llm_dissemination_tpu.cli.trace logs/ -o run.trace.json
@@ -228,20 +230,36 @@ def interval_span_events(records, offsets: dict) -> List[dict]:
     return events
 
 
+def _field_totals(events: List[dict], name: str, fields) -> dict:
+    """The spans ``name`` that carry ``fields[0]``, counted, and each of
+    ``fields`` added up over them.  Empty when no such span is there."""
+    found = [ev["args"].get("fields") or {} for ev in events
+             if ev.get("ph") == "X" and ev.get("name") == name]
+    found = [f for f in found if fields[0] in f]
+    if not found:
+        return {}
+    return {"spans": len(found),
+            **{k: sum(f.get(k, 0) for f in found) for k in fields}}
+
+
 def decode_widen_totals(events: List[dict]) -> dict:
     """How the logs' device decodes widened their bytes: the
     ``decode.stage`` slices' ``fast_bytes`` (the kernel) and
     ``slow_bytes`` (the strided slices) added up, over one round's logs
     the round's sum (``models/serde.py`` ``widen_split``).  Empty when
     no such span is there."""
-    fields = [ev["args"].get("fields") or {} for ev in events
-              if ev.get("ph") == "X" and ev.get("name") == "decode.stage"]
-    fields = [f for f in fields if "fast_bytes" in f]
-    if not fields:
-        return {}
-    return {"spans": len(fields),
-            "fast_bytes": sum(f["fast_bytes"] for f in fields),
-            "slow_bytes": sum(f["slow_bytes"] for f in fields)}
+    return _field_totals(events, "decode.stage",
+                         ("fast_bytes", "slow_bytes"))
+
+
+def fabric_publish_totals(events: List[dict]) -> dict:
+    """What the logs' seeding seats published onto the device fabric: the
+    ``fabric.publish`` slices' ``bytes``, ``host_copy_bytes`` (copied on
+    the host before their upload: 0 where the host holds the layer) and
+    ``pieces`` added up, over one round's logs the round's sum
+    (``runtime/send.py`` ``contribute_device_plan``)."""
+    return _field_totals(events, "fabric.publish",
+                         ("bytes", "host_copy_bytes", "pieces"))
 
 
 def to_trace_events(records: Iterable[dict],
@@ -474,6 +492,11 @@ def main(argv: list[str] | None = None) -> int:
         print("decode.stage widened {fast_bytes} B with the kernel, "
               "{slow_bytes} B with the strided slices ({spans} spans)"
               .format(**widened), file=sys.stderr)
+    published = fabric_publish_totals(events)
+    if published:
+        print("fabric.publish put {bytes} B on the fabric in {pieces} "
+              "pieces, {host_copy_bytes} B of them copied on the host "
+              "first ({spans} spans)".format(**published), file=sys.stderr)
     doc = {"traceEvents": events, "displayTimeUnit": "ms"}
     if args.output == "-":
         json.dump(doc, sys.stdout)

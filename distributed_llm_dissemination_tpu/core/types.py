@@ -336,6 +336,27 @@ class LayerSrc:
             f"layer has no host-readable bytes (location={self.meta.location!r})"
         )
 
+    def view_span(self, off: int, size: int):
+        """``read_span``'s range as a 1-D ``uint8`` numpy array, for a
+        caller that only hands the bytes on (a host→device upload).
+
+        Where the host holds the layer this is a VIEW of ``inmem_data``:
+        nothing is copied, and nothing holds the GIL for the length of
+        a layer (``read_span``'s ``bytes()`` is one C call that never
+        releases it).  The view exports ``inmem_data``'s buffer, so the
+        bytes stay alive, and a ``bytearray`` cannot be resized, for as
+        long as the view — or an upload made from it — lives; a held
+        layer's bytes are never written in place (a reassembly buffer
+        is write-once before it becomes a record, a new version is a
+        new record).  Every other store gives what ``read_span`` reads:
+        a ``DISK`` store still touches only the span."""
+        import numpy as np
+
+        if self._host_resident():
+            return np.frombuffer(self.inmem_data, np.uint8, count=size,
+                                 offset=self.offset + off)
+        return np.frombuffer(self.read_span(off, size), np.uint8)
+
     def ensure_host_bytes(self) -> bool:
         """Materialize a host copy of an HBM-only layer (e.g. delivered
         over the pod fabric, where no host copy ever existed) from its

@@ -40,7 +40,7 @@ import threading
 import time
 from typing import Callable, Dict, Iterator, List, Tuple
 
-from ..utils import trace
+from ..utils import intervals, trace
 from ..utils.logging import log
 
 
@@ -49,8 +49,9 @@ class FabricPlane:
 
     Contributions are ``(byte_offset, uint8 device array)`` pairs keyed by
     plan id.  ``collect`` yields them *as they arrive*, so a destination
-    overlaps its ICI ingest with later senders' host→HBM uploads instead
-    of waiting for the full set."""
+    overlaps its ICI ingest with the senders' later host→HBM uploads — a
+    sender publishes a range piece by piece — instead of waiting for the
+    full set."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -72,36 +73,48 @@ class FabricPlane:
 
     def publish(self, plan_id: str, offset: int, arr) -> None:
         """Sender side: register one device-resident byte-range fragment."""
+        self.publish_all(plan_id, [(offset, arr)])
+
+    def publish_all(self, plan_id: str, pieces) -> None:
+        """Sender side: register ``(offset, device array)`` fragments —
+        a range uploaded in pieces — with one wake-up of the waiting
+        destinations, not one per piece."""
         with self._cond:
-            self._contribs.setdefault(plan_id, []).append((offset, arr))
+            self._contribs.setdefault(plan_id, []).extend(pieces)
             self._touched[plan_id] = time.monotonic()
             self._cond.notify_all()
 
     def collect(
-        self, plan_id: str, count: int, timeout: float = 120.0
+        self, plan_id: str, nbytes: int, timeout: float = 120.0
     ) -> Iterator[Tuple[int, object]]:
-        """Destination side: yield ``count`` contributions as they arrive.
+        """Destination side: yield contributions as they arrive, until
+        they cover ``nbytes`` bytes of the layer (the plan's layout says
+        how many: a sender may publish a range as any number of pieces,
+        so their count says nothing; a piece published twice covers its
+        bytes once).
 
-        Raises ``TimeoutError`` if the remaining contributions don't show
-        up in time (a crashed seeder — the leader's failure detector will
+        Raises ``TimeoutError`` if the remaining bytes don't show up in
+        time (a crashed seeder — the leader's failure detector will
         re-plan; the superseding plan has a fresh id).  The plan's entries
         are discarded once fully consumed; abandon via ``discard``."""
         got = 0
+        have: list = []  # disjoint byte intervals yielded so far
         deadline = time.monotonic() + timeout
-        while got < count:
+        while intervals.covered(have) < nbytes:
             with self._cond:
                 while len(self._contribs.get(plan_id, ())) <= got:
                     left = deadline - time.monotonic()
                     if left <= 0:
                         raise TimeoutError(
-                            f"plan {plan_id}: {got}/{count} contributions "
-                            f"after {timeout}s"
+                            f"plan {plan_id}: {intervals.covered(have)}/"
+                            f"{nbytes} bytes contributed after {timeout}s"
                         )
                     self._cond.wait(left)
                 fresh = list(self._contribs[plan_id][got:])
-            for item in fresh:
-                yield item
+            for off, arr in fresh:
+                yield off, arr
                 got += 1
+                have = intervals.insert(have, off, off + int(arr.shape[0]))
         self.discard(plan_id)
 
     def discard(self, plan_id: str) -> None:

@@ -289,7 +289,10 @@ class ShardedLayerIngest:
                         hostmem.copy_into(self._host[r], a - s_off, piece)
                     else:
                         if is_device:
-                            src = data[a - offset : b - offset]  # on-src slice
+                            # a fragment whole inside this span goes as it
+                            # is; a true sub-range is sliced on its source
+                            src = (data if (a, b) == (offset, end)
+                                   else data[a - offset : b - offset])
                         else:
                             src = np.frombuffer(
                                 data[a - offset : b - offset], np.uint8)

@@ -2321,7 +2321,7 @@ class ReceiverNode:
             # ack).  The drain is bounded and off the handler pool.
             try:
                 for _ in self.fabric.collect(
-                    msg.plan_id, len(msg.layout),
+                    msg.plan_id, msg.layout_bytes,
                     timeout=min(30.0, self.FABRIC_COLLECT_TIMEOUT),
                 ):
                     pass
@@ -2365,7 +2365,7 @@ class ReceiverNode:
                                 node=self.node.my_id,
                                 fragments=len(msg.layout)):
                     for off, arr in self.fabric.collect(
-                        msg.plan_id, len(msg.layout),
+                        msg.plan_id, msg.layout_bytes,
                         timeout=self.FABRIC_COLLECT_TIMEOUT,
                     ):
                         if ingest_alive:

@@ -378,17 +378,52 @@ class NackRetransmitter:
         return True
 
 
+# One host→HBM transfer of a seeder's upload.  Measured on the v5e host
+# (PR 28, CHANGES.md): ten 780 MB layers put whole and at once take
+# 6.2-9.8 s — past some 4 GB in flight the runtime's transfers stall for
+# seconds — where the same bytes in pieces of 4-12 MiB take 0.56 s
+# (14 GB/s), 16 MiB 0.6-0.9 s, 32 MiB 1.4 s, and 2 MiB 0.95 s (the
+# dispatch loop's own time); a piece's device→device hop to its
+# destination (17 ms a layer) runs under the next pieces' uploads.
+UPLOAD_CHUNK_BYTES = 8 << 20
+
+
+def _upload_chunks(view, base: int, device) -> list:
+    """``view`` (a 1-D uint8 host array, the layer's bytes from ``base``
+    on) onto ``device`` as consecutive ``(offset, device array)`` pieces
+    of ``UPLOAD_CHUNK_BYTES``.  The puts are only dispatched here; each
+    holds its slice of ``view`` until its transfer is done."""
+    import jax
+
+    return [(base + o, jax.device_put(view[o : o + UPLOAD_CHUNK_BYTES],
+                                      device))
+            for o in range(0, view.shape[0], UPLOAD_CHUNK_BYTES)]
+
+
+def _cut(pieces, off: int, size: int):
+    """The parts of offset-ordered ``(offset, device array)`` pieces that
+    lie inside ``[off, off + size)``: a piece whole inside goes as it is,
+    a true sub-range is sliced on its device."""
+    for p_off, arr in pieces:
+        a, b = max(off, p_off), min(off + size, p_off + arr.shape[0])
+        if a < b:
+            whole = (a, b) == (p_off, p_off + arr.shape[0])
+            yield a, (arr if whole else arr[a - p_off : b - p_off])
+
+
 class _FabricUploadCache:
     """Budgeted LRU over seeder-side full-layer device copies.
 
     A seeder serving many layers to many destinations must not pin one
     whole-layer HBM copy per layer forever — at 70B scale that exceeds a
-    chip.  Entries count against ``budget_bytes`` (default 4 GiB,
-    ``FABRIC_UPLOAD_CACHE_BYTES`` env overrides); eviction clears the
-    record's ``device_array`` (safe: only records this cache populated —
-    never receiver-staged HBM layers, whose location is HBM).  A failed
-    upload is memoized so k plans don't re-read a multi-GiB layer into
-    host RAM k times just to fail the same device_put again."""
+    chip.  An entry is the layer's upload as it was made: its
+    ``(offset, device array)`` chunks, kept here and not on the record
+    (a record's ``device_array`` is a staged layer's).  Entries count
+    against ``budget_bytes`` (default 4 GiB, ``FABRIC_UPLOAD_CACHE_BYTES``
+    env overrides); eviction drops the chunks, which then live as long as
+    a published plan still holds them.  A failed upload is memoized so k
+    plans don't re-read a multi-GiB layer into host RAM k times just to
+    fail the same device_put again."""
 
     def __init__(self):
         import os
@@ -396,7 +431,9 @@ class _FabricUploadCache:
         self.budget = int(os.environ.get("FABRIC_UPLOAD_CACHE_BYTES",
                                          4 << 30))
         self._lock = threading.Lock()
-        self._order: Dict[int, object] = {}  # id(record) -> record (LRU)
+        # id(record) -> (record, chunks), oldest first (LRU).  The entry
+        # holds the record, so its id cannot be reused while it is here.
+        self._order: Dict[int, tuple] = {}
         self._bytes = 0
         # Latched by clear() at startup: while closed, new uploads serve
         # their plan transiently and are never retained — the decision is
@@ -406,25 +443,23 @@ class _FabricUploadCache:
         self._closed = False
 
     def get_or_put(self, layer, layer_id, device):
-        import jax
-        import numpy as np
-
+        """The layer's whole device copy as offset-ordered chunks
+        (``None``: contribute ranges) and the bytes this call copied on
+        the host to make it — 0 on a hit, and 0 for a layer the host
+        holds, which uploads from a view of its bytes."""
         key = id(layer)
         with layer._host_lock:  # once-guard, shared with ensure_host_bytes
-            dev = getattr(layer, "device_array", None)
-            if dev is not None:
-                with self._lock:  # LRU touch: reuse = recency
-                    if key in self._order:
-                        self._order[key] = self._order.pop(key)
-                return dev if (getattr(dev, "ndim", 0) == 1
-                               and dev.dtype == np.uint8) else None
+            with self._lock:
+                hit = self._order.get(key)
+                if hit is not None:  # LRU touch: reuse = recency
+                    self._order[key] = self._order.pop(key)
+                    return hit[1], 0
             if layer.upload_failed or layer.data_size > self.budget:
-                return None
+                return None, 0
+            copied = 0 if layer._host_resident() else layer.data_size
             try:
-                whole = np.frombuffer(
-                    layer.read_span(0, layer.data_size), np.uint8
-                )
-                dev = jax.device_put(whole, device)
+                chunks = _upload_chunks(
+                    layer.view_span(0, layer.data_size), 0, device)
             except Exception as e:  # noqa: BLE001 — fall back to ranges
                 log.warn("full-layer upload cache failed; using range "
                          "uploads for this layer from now on",
@@ -432,40 +467,20 @@ class _FabricUploadCache:
                 # Memoized on the RECORD (an id()-keyed set would outlive
                 # the object and poison whatever reuses its address).
                 layer.upload_failed = True
-                return None
-            layer.device_array = dev
-        # Victims are collected under the cache lock but cleared outside
-        # it: clearing takes the victim's _host_lock, and another thread
-        # in get_or_put holds its own _host_lock while briefly taking the
-        # cache lock — nesting them here in the opposite order could
-        # deadlock.
-        victims = []
-        retained = True
-        with self._lock:
-            if self._closed:
-                # Released (startup fired, the model owns the HBM): serve
-                # THIS plan from the transient handle, retain nothing.
-                retained = False
-            else:
-                self._order[key] = layer
-                self._bytes += layer.data_size
-                while self._bytes > self.budget and len(self._order) > 1:
-                    old_key, old = next(iter(self._order.items()))
-                    if old_key == key:
-                        break  # never evict the entry just inserted
-                    del self._order[old_key]
-                    self._bytes -= old.data_size
-                    victims.append(old)
-        if not retained:
-            with layer._host_lock:
-                if (layer.device_array is dev
-                        and layer.meta.location != LayerLocation.HBM):
-                    layer.device_array = None
-        for old in victims:
-            with old._host_lock:
-                if old.meta.location != LayerLocation.HBM:
-                    old.device_array = None  # frees the HBM copy
-        return dev
+                return None, 0
+            with self._lock:
+                # Closed (startup fired, the model owns the HBM): serve
+                # THIS plan from the transient chunks, retain nothing.
+                if not self._closed:
+                    self._order[key] = (layer, chunks)
+                    self._bytes += layer.data_size
+                    while self._bytes > self.budget and len(self._order) > 1:
+                        old_key, (old, _) = next(iter(self._order.items()))
+                        if old_key == key:
+                            break  # never evict the entry just inserted
+                        del self._order[old_key]  # frees the HBM copy
+                        self._bytes -= old.data_size
+        return chunks, copied
 
     def reopen(self) -> None:
         """Re-arm retention for a new distribution cycle (a node
@@ -478,15 +493,11 @@ class _FabricUploadCache:
         """Release every cached upload (dissemination is over — the HBM
         belongs to the booting model now).  Returns entries freed."""
         with self._lock:
-            victims = list(self._order.values())
+            freed = len(self._order)
             self._order.clear()
             self._bytes = 0
             self._closed = True
-        for old in victims:
-            with old._host_lock:
-                if old.meta.location != LayerLocation.HBM:
-                    old.device_array = None
-        return len(victims)
+        return freed
 
 
 _upload_cache = _FabricUploadCache()
@@ -515,10 +526,16 @@ def contribute_device_plan(
 
     The host→HBM upload happens here, locally — the same hop a TCP send
     would have paid to read the layer — and the destination's ingest then
-    moves the fragment device-to-device (ICI).  A seeder whose copy is
-    already HBM-staged contributes an on-device slice: no host traffic at
-    all.  Multiple ranges from one node fan out round-robin across its
-    stage devices so their uploads overlap."""
+    moves each piece device-to-device (ICI).  What is uploaded from where
+    follows the layer's backing kind: bytes the host holds go up from a
+    view of them (``LayerSrc.view_span``: no host copy), a ``DISK`` store
+    reads only the contributed span, and a seeder whose copy is already
+    HBM-staged contributes an on-device slice: no host traffic at all.
+    An upload goes in pieces of ``UPLOAD_CHUNK_BYTES`` and every piece is
+    a contribution of its own, so the destination's ingest starts a piece,
+    not a layer, after the plan (``FabricPlane.collect`` ends on the
+    plan's bytes covered).  Multiple ranges from one node fan out
+    round-robin across its stage devices so their uploads overlap."""
     mine = [(off, size) for s, off, size in msg.layout if s == node.my_id]
     if not mine:
         return
@@ -534,36 +551,43 @@ def contribute_device_plan(
     # The sender seat's half of a fabric plan: its upload (or on-device
     # slice) of every range it contributes, until each is published.
     with trace.span("fabric.publish", id=f"plan.{msg.plan_id}",
-                    node=node.my_id, ranges=len(mine)):
+                    node=node.my_id, ranges=len(mine)) as span:
         devices = placement.devices_for_node(node.my_id)
-        dev_src = getattr(layer, "device_array", None)
-        if dev_src is not None and not (
-            getattr(dev_src, "ndim", 0) == 1 and dev_src.dtype == np.uint8
-        ):
-            dev_src = None  # only raw uint8 blobs slice meaningfully by byte
+        staged = getattr(layer, "device_array", None)
+        # only raw uint8 blobs slice meaningfully by byte
+        on_device = ([(0, staged)] if staged is not None
+                     and getattr(staged, "ndim", 0) == 1
+                     and staged.dtype == np.uint8 else None)
 
-        if dev_src is None and sum(size for _, size in mine) * 2 >= layer.data_size:
-            # Contributing most of the layer: upload it whole ONCE and cache
-            # the device copy on the record — a mode-0/1 seeder serving k
-            # destinations (k plans, each a full-layer layout) then pays one
-            # host→HBM upload instead of k, and every later plan or re-plan
-            # slices device-side.  Small byte-range jobs (mode-3 splits) keep
-            # the range-only upload below.
-            dev_src = _upload_cache.get_or_put(layer, msg.layer_id, devices[0])
+        nbytes = sum(size for _, size in mine)
+        host_copy = 0  # bytes copied on the host before their upload
+        if on_device is None and nbytes * 2 >= layer.data_size:
+            # Contributing most of the layer: upload it whole ONCE and keep
+            # the device copy — a mode-0/1 seeder serving k destinations (k
+            # plans, each a full-layer layout) then pays one host→HBM upload
+            # instead of k, and every later plan or re-plan cuts its ranges
+            # device-side.  Small byte-range jobs (mode-3 splits) keep the
+            # range-only upload below.
+            on_device, host_copy = _upload_cache.get_or_put(
+                layer, msg.layer_id, devices[0])
 
+        pieces = 0
         for k, (off, size) in enumerate(mine):
             dev = devices[k % len(devices)]
-            if dev_src is not None:
-                piece = jax.device_put(dev_src[off : off + size], dev)
+            if on_device is not None:
+                parts = [(p_off, jax.device_put(part, dev))
+                         for p_off, part in _cut(on_device, off, size)]
             else:
-                # read_span: only the contributed range touches host RAM (a
-                # disk seeder of a multi-GiB layer serves small ranges).
-                piece = jax.device_put(
-                    np.frombuffer(layer.read_span(off, size), np.uint8), dev
-                )
-            fabric.publish(msg.plan_id, off, piece)
+                # Only the contributed range touches host RAM: a view of
+                # it where the host holds the layer, else the span read.
+                if not layer._host_resident():
+                    host_copy += size
+                parts = _upload_chunks(layer.view_span(off, size), off, dev)
+            fabric.publish_all(msg.plan_id, parts)
+            pieces += len(parts)
             log.debug("published fabric contribution", layerID=msg.layer_id,
                       plan=msg.plan_id, offset=off, size=size)
+        span.set(bytes=nbytes, host_copy_bytes=host_copy, pieces=pieces)
 
 
 def handle_flow_retransmit(

@@ -908,6 +908,12 @@ class DevicePlanMsg:
 
     msg_type = MsgType.DEVICE_PLAN
 
+    @property
+    def layout_bytes(self) -> int:
+        """The bytes the plan's senders contribute between them: what
+        the destination collects from the fabric."""
+        return sum(int(size) for _, _, size in self.layout)
+
     def to_payload(self) -> dict:
         payload = {
             "SrcID": self.src_id,

@@ -47,6 +47,7 @@ from distributed_llm_dissemination_tpu.transport import (
     reset_registry,
 )
 from distributed_llm_dissemination_tpu.transport.inmem import InmemTransport
+from distributed_llm_dissemination_tpu.utils import trace
 from distributed_llm_dissemination_tpu.transport.messages import (
     DevicePlanMsg,
     MsgType,
@@ -162,7 +163,7 @@ def test_fabric_plane_collect_yields_as_published(cpu_devices):
     got = []
 
     def consume():
-        for off, arr in plane.collect("p", 2, timeout=5.0):
+        for off, arr in plane.collect("p", 8, timeout=5.0):
             got.append((off, bytes(np.asarray(arr))))
 
     t = threading.Thread(target=consume)
@@ -578,33 +579,51 @@ def test_multi_dest_contribution_caches_one_device_upload(cpu_devices):
         close_all(leader, [seeder] + dests, ts)
 
 
-def test_fabric_upload_cache_unit(cpu_devices):
-    """One upload serves many plans; eviction and clear release the HBM
-    copies; a failed upload is memoized on the record."""
-    import jax
+def chunks_to_bytes(chunks) -> bytes:
+    """An upload's offset-ordered chunks back as the layer's bytes."""
+    assert [off for off, _ in chunks] == sorted(off for off, _ in chunks)
+    return b"".join(array_to_bytes(arr) for _, arr in chunks)
 
-    from distributed_llm_dissemination_tpu.runtime.send import (
-        _FabricUploadCache,
-    )
 
-    cache = _FabricUploadCache()
+def test_fabric_upload_cache_unit(cpu_devices, monkeypatch):
+    """One upload serves many plans, and is the layer in chunks put from
+    a view of the held bytes; eviction and clear release the HBM copies;
+    a failed upload is memoized on the record."""
+    import unittest.mock as mock
+
+    from distributed_llm_dissemination_tpu.runtime import send
+
+    monkeypatch.setattr(send, "UPLOAD_CHUNK_BYTES", LAYER_SIZE // 4)
+    cache = send._FabricUploadCache()
     cache.budget = 3 * LAYER_SIZE  # room for 3 entries
 
     puts = []
     real_put = jax.device_put
 
     def counting_put(x, d=None, **kw):
-        puts.append(1)
+        puts.append(x)
         return real_put(x, d, **kw)
 
+    def uploads_of(layer, layer_id):
+        """How many host→device puts one more plan of ``layer`` costs."""
+        before = len(puts)
+        with mock.patch.object(jax, "device_put", counting_put):
+            cache.get_or_put(layer, layer_id, cpu_devices[0])
+        return len(puts) - before
+
     layers = [mem_layer(i) for i in range(4)]
-    import unittest.mock as mock
 
     with mock.patch.object(jax, "device_put", counting_put):
-        a = cache.get_or_put(layers[0], 0, cpu_devices[0])
-        b = cache.get_or_put(layers[0], 0, cpu_devices[0])
-    assert a is b and len(puts) == 1  # second plan reused the upload
-    assert array_to_bytes(a) == layer_bytes(0)
+        a, copied = cache.get_or_put(layers[0], 0, cpu_devices[0])
+        b, again = cache.get_or_put(layers[0], 0, cpu_devices[0])
+    assert a is b and len(puts) == 4  # second plan reused the upload
+    assert [off for off, _ in a] == [i * LAYER_SIZE // 4 for i in range(4)]
+    assert chunks_to_bytes(a) == layer_bytes(0)
+    # The host holds the layer: every chunk went up from a view of the
+    # record's own bytes, nothing was copied on the host first.
+    assert copied == 0 and again == 0
+    held = np.frombuffer(layers[0].inmem_data, np.uint8)
+    assert all(np.shares_memory(x, held) for x in puts)
 
     # LRU: touch layer 0, insert 1..3 — budget 3 evicts the stale entry
     # (layer 1), never the re-touched layer 0.
@@ -612,23 +631,26 @@ def test_fabric_upload_cache_unit(cpu_devices):
     cache.get_or_put(layers[0], 0, cpu_devices[0])  # touch
     cache.get_or_put(layers[2], 2, cpu_devices[0])
     cache.get_or_put(layers[3], 3, cpu_devices[0])
-    assert layers[1].device_array is None, "LRU should evict the coldest"
-    assert layers[0].device_array is not None
+    assert uploads_of(layers[0], 0) == 0, "the re-touched entry stays"
+    assert uploads_of(layers[3], 3) == 0
+    assert uploads_of(layers[1], 1) == 4, "LRU should evict the coldest"
+    # the cache keeps its copies to itself: a record's device_array is a
+    # STAGED layer's, and these are host-held
+    assert all(rec.device_array is None for rec in layers)
 
     assert cache.clear() > 0
-    for rec in layers:
-        assert rec.device_array is None
+    assert cache._bytes == 0 and not cache._order
 
     # clear() latches the cache closed: a late plan's upload serves its
     # caller but is NOT retained (the booted model owns the HBM) until
     # reopen() re-arms a new cycle.
     stale = mem_layer(9)
-    dev = cache.get_or_put(stale, 9, cpu_devices[0])
-    assert dev is not None  # the plan is still served
-    assert stale.device_array is None  # ...but nothing was retained
+    dev, _ = cache.get_or_put(stale, 9, cpu_devices[0])
+    assert chunks_to_bytes(dev) == layer_bytes(9)  # the plan is still served
+    assert uploads_of(stale, 9) == 4  # ...but nothing was retained
     cache.reopen()
-    dev = cache.get_or_put(stale, 9, cpu_devices[0])
-    assert stale.device_array is not None
+    assert uploads_of(stale, 9) == 4
+    assert uploads_of(stale, 9) == 0  # retained again
 
     # Failure memoized on the record, not by object address.
     broken = mem_layer(0)
@@ -637,9 +659,279 @@ def test_fabric_upload_cache_unit(cpu_devices):
         raise RuntimeError("no HBM")
 
     with mock.patch.object(jax, "device_put", failing_put):
-        assert cache.get_or_put(broken, 0, cpu_devices[0]) is None
+        assert cache.get_or_put(broken, 0, cpu_devices[0]) == (None, 0)
     assert broken.upload_failed
-    assert cache.get_or_put(broken, 0, cpu_devices[0]) is None  # no re-read
+    assert cache.get_or_put(broken, 0, cpu_devices[0]) == (None, 0)  # no re-read
+
+
+def publish_spans():
+    return [s for s in trace.spans() if s["name"] == "fabric.publish"]
+
+
+@pytest.mark.parametrize("chunk,pieces_a_layer", [(2 * LAYER_SIZE, 1),
+                                                  (24 * 1024, 3)])
+def test_host_resident_seeder_publishes_its_bytes_in_place(
+        cpu_devices, monkeypatch, chunk, pieces_a_layer):
+    """A seeder whose host holds the layer uploads it from a view of those
+    bytes, whole or in chunks: its ``fabric.publish`` spans count no byte
+    copied on the host, and what lands has the source's sha256."""
+    import hashlib
+
+    from distributed_llm_dissemination_tpu.cli import trace as cli_trace
+    from distributed_llm_dissemination_tpu.runtime import send
+
+    monkeypatch.setattr(send, "UPLOAD_CHUNK_BYTES", chunk)
+    trace.reset_run()
+    ids = range(4)
+    ts = inmem_transports(ids)
+    assignment = {3: {0: LayerMeta(), 1: LayerMeta()}}
+    leader, receivers, placement = _fabric_cluster(
+        1, ids, assignment, seeders={1}, transports=ts)
+    try:
+        run_distribution(leader, receivers, assignment)
+        dest = receivers[-1]
+        check_fabric_landing(dest, placement, [0, 1])
+        for lid in (0, 1):
+            assert (hashlib.sha256(array_to_bytes(
+                dest.layers[lid].device_array)).hexdigest()
+                == hashlib.sha256(layer_bytes(lid)).hexdigest())
+        spans = publish_spans()
+        assert len(spans) == 2
+        for sp in spans:
+            assert sp["fields"]["host_copy_bytes"] == 0
+            assert sp["fields"]["bytes"] == LAYER_SIZE
+            assert sp["fields"]["pieces"] == pieces_a_layer
+        events = [{"ph": "X", "name": s["name"],
+                   "args": {"fields": s["fields"]}} for s in trace.spans()]
+        assert cli_trace.fabric_publish_totals(events) == {
+            "spans": 2, "bytes": 2 * LAYER_SIZE, "host_copy_bytes": 0,
+            "pieces": 2 * pieces_a_layer}
+        assert cli_trace.fabric_publish_totals([]) == {}
+    finally:
+        close_all(leader, receivers, ts)
+
+
+def _one_seeder(cpu_devices, layer):
+    """A seeder seat (node 1) holding ``layer`` as layer 0, a fabric and a
+    placement: what ``contribute_device_plan`` needs, without a cluster."""
+    ids = range(3)
+    ts = inmem_transports(ids)
+    mesh = make_mesh((3, 2), ("pp", "tp"), devices=list(cpu_devices)[:6])
+    placement = fabric_placement(list(ids), {2: {0: LayerMeta()}}, mesh, "pp")
+    return Node(1, 0, ts[1]), {0: layer}, FabricPlane(), placement, ts
+
+
+def _plan(plan_id, layout, total=LAYER_SIZE):
+    return DevicePlanMsg(0, plan_id, 0, 2, total, layout)
+
+
+def collected(fabric, msg, timeout=5.0):
+    """A plan's contributions as one ``{offset: bytes}`` map."""
+    return {off: array_to_bytes(arr) for off, arr in
+            fabric.collect(msg.plan_id, msg.layout_bytes, timeout=timeout)}
+
+
+@pytest.mark.parametrize("off,size", [(4096, 8192), (0, LAYER_SIZE)])
+def test_disk_seeder_copies_exactly_the_contributed_span(
+        cpu_devices, tmp_path, off, size):
+    """A ``DISK`` store has no bytes to view: its publish reads the span
+    it contributes and reports exactly that — a small range only the
+    range, the whole layer once however many plans ask for it."""
+    from distributed_llm_dissemination_tpu.runtime.send import (
+        contribute_device_plan,
+        release_upload_cache,
+        reopen_upload_cache,
+    )
+
+    path = tmp_path / "layer0.bin"
+    path.write_bytes(layer_bytes(0))
+    layer = LayerSrc(fp=str(path), data_size=LAYER_SIZE,
+                     meta=LayerMeta(location=LayerLocation.DISK,
+                                    source_type=SourceType.DISK))
+    node, layers, fabric, placement, ts = _one_seeder(cpu_devices, layer)
+    reopen_upload_cache()
+    trace.reset_run()
+    try:
+        for plan_id in ("a", "b"):
+            msg = _plan(plan_id, [(1, off, size)])
+            contribute_device_plan(node, layers, threading.Lock(), fabric,
+                                   placement, msg)
+            got = collected(fabric, msg)
+            assert b"".join(got[o] for o in sorted(got)) == (
+                layer_bytes(0)[off:off + size])
+        first, second = publish_spans()
+        assert first["fields"]["host_copy_bytes"] == size
+        assert first["fields"]["bytes"] == second["fields"]["bytes"] == size
+        # the whole layer stays uploaded for the second plan; a small
+        # range is read again (the cache keeps whole layers only)
+        assert second["fields"]["host_copy_bytes"] == (
+            0 if size == LAYER_SIZE else size)
+        assert layer.inmem_data is None  # never materialized whole on host
+    finally:
+        release_upload_cache()
+        for t in ts.values():
+            t.close()
+
+
+@pytest.mark.parametrize("held", ["bytearray", "memoryview", "bytes",
+                                  "hbm-with-host-buffer", "fragment-offset"])
+def test_view_span_views_what_the_host_holds(cpu_devices, held):
+    """``view_span`` is ``read_span``'s bytes without the copy, for every
+    kind of buffer a record's ``inmem_data`` is in practice."""
+    data = layer_bytes(3)
+    base = 0
+    meta = LayerMeta(location=LayerLocation.INMEM)
+    if held == "bytearray":
+        buf = bytearray(data)
+    elif held == "memoryview":  # the benchmark's blobs: a numpy array's
+        buf = memoryview(np.frombuffer(data, np.uint8).copy())
+    elif held == "bytes":
+        buf = data
+    elif held == "hbm-with-host-buffer":
+        buf = bytearray(data)
+        meta = LayerMeta(location=LayerLocation.HBM)
+    else:  # a record that starts inside its buffer
+        buf, base = bytearray(data), 512
+    src = LayerSrc(inmem_data=buf, data_size=LAYER_SIZE - base, offset=base,
+                   meta=meta)
+    view = src.view_span(100, 1000)
+    assert view.dtype == np.uint8 and view.shape == (1000,)
+    assert view.tobytes() == src.read_span(100, 1000)
+    assert view.tobytes() == data[base + 100:base + 1100]
+    assert np.shares_memory(view, np.frombuffer(buf, np.uint8))
+    assert src.view_span(0, src.data_size).tobytes() == src.read_range()
+    if isinstance(buf, bytearray):
+        # the view exports the buffer: the bytes cannot move under an
+        # upload made from it
+        with pytest.raises(BufferError):
+            buf.extend(b"x")
+    with pytest.raises(ValueError):
+        src.view_span(LAYER_SIZE, 16)  # past the end: an error, no short read
+
+
+@pytest.mark.parametrize("stream", [False, True])
+def test_plan_in_chunks_out_of_order_completes_on_bytes_covered(
+        cpu_devices, stream):
+    """A range published as pieces, in any order and one of them twice,
+    is one plan: the collect ends when the plan's bytes are covered, not
+    at a count of contributions, and the ingest lands the layer exact."""
+    plane = FabricPlane()
+    data = layer_bytes(5)
+    step = LAYER_SIZE // 8
+    order = [5, 0, 7, 2, 2, 1, 6, 4, 3]  # piece 2 twice
+    pieces = [(i * step, jax.device_put(
+        np.frombuffer(data[i * step:(i + 1) * step], np.uint8),
+        cpu_devices[i % 2])) for i in order]
+    ingest = ShardedLayerIngest(LAYER_SIZE, list(cpu_devices)[2:4],
+                                stream=stream)
+
+    def seed():
+        for k in range(0, len(pieces), 3):
+            time.sleep(0.02)
+            plane.publish_all("p", pieces[k:k + 3])
+
+    t = threading.Thread(target=seed)
+    t.start()
+    seen = 0
+    for off, arr in plane.collect("p", LAYER_SIZE, timeout=5.0):
+        ingest.write(off, arr)
+        seen += 1
+    t.join(timeout=5.0)
+    assert seen == len(pieces)  # the last piece completed it, none sooner
+    assert array_to_bytes(ingest.finalize(timeout=5.0)) == data
+    assert plane.pending() == 0
+
+
+def test_plan_whose_seeder_stops_half_way_times_out(cpu_devices):
+    """Half a plan's bytes, however many pieces they came in, is not the
+    plan: the collect waits for the rest and times out with the count."""
+    plane = FabricPlane()
+    half = LAYER_SIZE // 2
+    plane.publish_all("p", [
+        (off, jax.device_put(np.zeros(half // 4, np.uint8), cpu_devices[0]))
+        for off in range(0, half, half // 4)])
+    seen = []
+    with pytest.raises(TimeoutError, match=f"{half}/{LAYER_SIZE} bytes"):
+        for item in plane.collect("p", LAYER_SIZE, timeout=0.3):
+            seen.append(item)
+    assert len(seen) == 4  # what was there was handed over first
+
+
+def test_publish_of_a_held_layer_never_holds_the_gil_for_its_length(
+        cpu_devices):
+    """The property the pod's cold start rests on: while a seat publishes
+    a 64 MiB layer its host holds, the process's other threads keep
+    running.  A thread that sleeps 1 ms a turn got at most one turn
+    inside the ``bytes()`` copy the publish used to make (one C call that
+    never releases the GIL); now its longest stall is a fraction of that
+    copy's time."""
+    from distributed_llm_dissemination_tpu.runtime.send import (
+        contribute_device_plan,
+        release_upload_cache,
+        reopen_upload_cache,
+    )
+
+    size = 64 << 20
+    data = bytearray(size)
+    data[::4096] = bytes(range(256)) * (size // 4096 // 256)
+
+    class Ticker:
+        def __enter__(self):
+            self.stalls, self._stop = [], False
+            self._turning = threading.Event()
+            self._t = threading.Thread(target=self._run, daemon=True)
+            self._t.start()
+            assert self._turning.wait(5.0)  # measure from a running thread
+            return self
+
+        def _run(self):
+            last = time.perf_counter()
+            self._turning.set()
+            while not self._stop:
+                time.sleep(0.001)
+                now = time.perf_counter()
+                self.stalls.append(now - last)
+                last = now
+
+        def __exit__(self, *a):
+            self._stop = True
+            self._t.join(timeout=5.0)
+
+    def once():
+        with Ticker() as under_copy:
+            t0 = time.perf_counter()
+            copy = bytes(memoryview(data)[0:size])
+            copy_s = time.perf_counter() - t0
+        del copy
+        layer = LayerSrc(inmem_data=data, data_size=size,
+                         meta=LayerMeta(location=LayerLocation.INMEM))
+        node, layers, fabric, placement, ts = _one_seeder(cpu_devices, layer)
+        reopen_upload_cache()
+        trace.reset_run()
+        try:
+            with Ticker() as under_publish:
+                for k in range(4):
+                    contribute_device_plan(
+                        node, layers, threading.Lock(), fabric, placement,
+                        _plan(f"p{k}", [(1, 0, size)], total=size))
+            span = publish_spans()[0]["fields"]
+            assert span["host_copy_bytes"] == 0 and span["bytes"] == size
+            assert span["pieces"] == 8  # 64 MiB in UPLOAD_CHUNK_BYTES
+            first = next(fabric.collect("p0", 1, timeout=5.0))
+            assert array_to_bytes(first[1])[:4096] == bytes(data[:4096])
+        finally:
+            release_upload_cache()
+            for t in ts.values():
+                t.close()
+        return (max(under_publish.stalls, default=0.0), copy_s,
+                max(under_copy.stalls, default=0.0))
+
+    # A loaded machine stalls any thread now and then: the property has
+    # to show in one of three tries; the copy's own stall is there every
+    # time (it is the whole copy).
+    tries = [once() for _ in range(3)]
+    assert all(copy_stall >= 0.5 * copy_s for _, copy_s, copy_stall in tries)
+    assert any(stall < 0.25 * copy_s for stall, copy_s, _ in tries), tries
 
 
 def test_fabric_collect_timeout_triggers_replan_recovery(cpu_devices,
