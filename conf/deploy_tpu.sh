@@ -15,7 +15,7 @@ PROJECT=${3:-$(gcloud config get-value project)}
 REPO_DIR=$(cd "$(dirname "$0")/.." && pwd)
 
 tar -C "$REPO_DIR" -czf /tmp/dissem_tpu.tgz \
-    distributed_llm_dissemination_tpu conf bench.py
+    distributed_llm_dissemination_tpu conf benchmark BENCHMARK.json
 
 gcloud compute tpus tpu-vm scp /tmp/dissem_tpu.tgz "$TPU":/tmp/ \
     --zone "$ZONE" --project "$PROJECT" --worker=all

@@ -2,8 +2,7 @@
 
 ``BASELINE.json`` (driver-provided) names five scenarios; the first is the
 reference's own shape (shipped as ``conf/local_4node.json``), the rest are
-materialized here so they can be run anywhere — full size on real clusters,
-or scaled down by the TTD matrix for loopback recording:
+materialized here so they can be run anywhere:
 
 1. 4 nodes, 3 dummy layers @1 MiB, mode 0            → conf/local_4node.json
 2. 8-node mode-0 broadcast, 32 layers @400 MiB       → bench_8node_llama8b.json
@@ -23,6 +22,10 @@ Scenario 5 is scenario 4 at Llama-3-405B scale with layers seeded on DISK
 seven replica seeders — the disk-spill path.
 
     python -m distributed_llm_dissemination_tpu.cli.genconf -o conf/
+
+``spmd_two_proc_config`` / ``spmd_pod_config`` build the loopback
+topologies of the multi-controller SPMD fabric (one OS process per node);
+they are not written to ``conf/`` because their ports are picked per run.
 """
 
 from __future__ import annotations
@@ -132,6 +135,56 @@ def scenario_64node_llama405b() -> dict:
         "LayerSize": size,
         "Mesh": {"AxisNames": ["nodes"], "AxisSizes": [64],
                  "PipelineAxis": "nodes"},
+    }
+
+
+def _spmd_nodes(n: int, scale: int, layers: int, port) -> list:
+    """Leader 0 seeds ``layers`` layers in RAM; nodes 1..n-1 start cold."""
+    nodes = [{"Id": i, "Addr": f"127.0.0.1:{port()}",
+              "NetworkBW": 12500000000, "Sources": {"2": 0},
+              "InitialLayers": {}} for i in range(n)]
+    nodes[0]["IsLeader"] = True
+    nodes[0]["InitialLayers"] = {
+        "2": {str(i): {"LayerSize": scale} for i in range(layers)}}
+    return nodes
+
+
+def _spmd_mesh(n: int, port) -> dict:
+    return {
+        "Mesh": {"AxisNames": ["nodes"], "AxisSizes": [n],
+                 "PipelineAxis": "nodes", "Fabric": True},
+        "Distributed": {"Coordinator": f"127.0.0.1:{port()}",
+                        "CpuCollectives": "gloo"},
+    }
+
+
+def spmd_two_proc_config(scale: int, layers: int, port) -> dict:
+    """A 2-process multi-controller SPMD fabric topology on loopback
+    (leader seeds, node 1 assigned): one OS process per node, one
+    jax.distributed runtime, layer bytes as lockstep collectives
+    (``parallel/spmd_fabric.py``).  ``port()`` returns a free port each
+    time it is called (three are taken)."""
+    return {
+        "Nodes": _spmd_nodes(2, scale, layers, port),
+        "Assignment": {"1": {str(i): {} for i in range(layers)}},
+        "LayerSize": scale,
+        **_spmd_mesh(2, port),
+    }
+
+
+def spmd_pod_config(scale: int, layers: int, port) -> dict:
+    """A 3-process SPMD pod-delivery topology (docs/fabric.md): leader
+    0 seeds; nodes 1 and 2 form ONE pod and both want every layer —
+    the NIC ships each member its 1/2 shard (host TCP), and the leader
+    dispatches the pod gather as a lockstep collective that leaves the
+    full tree on BOTH members.  ``port()`` as above (four are taken)."""
+    return {
+        "Nodes": _spmd_nodes(3, scale, layers, port),
+        "Assignment": {"1": {str(i): {} for i in range(layers)},
+                       "2": {str(i): {} for i in range(layers)}},
+        "LayerSize": scale,
+        "Pods": [[1, 2]],
+        **_spmd_mesh(3, port),
     }
 
 

@@ -710,9 +710,8 @@ def run_leader(args, conf: cfg.Config, node: Node, layers) -> int:
     print(f"Time to deliver: {ttd:.6f}s", flush=True)
     pred_ms = getattr(leader, "predicted_ttd_ms", 0)
     if pred_ms:
-        # Mode 3 plan fidelity: the solver's min-time next to achieved
-        # TTD (VERDICT item 2's measurement half).  Machine-parsed by
-        # cli.ttd_matrix into predicted_s/solve_ms columns.
+        # Mode 3 plan fidelity: the solver's min-time next to the
+        # achieved TTD (cli/report.py reads the log record).
         solve_ms = getattr(leader, "solve_ms", 0.0)
         ulog.log.info("Predicted time to deliver",
                       seconds=round(pred_ms / 1000.0, 6),

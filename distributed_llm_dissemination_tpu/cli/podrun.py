@@ -88,9 +88,9 @@ def fabric_bandwidths(conf: cfg.Config) -> Dict[int, int]:
     return {nc.id: (ici if ici > 0 else nc.network_bw) for nc in conf.nodes}
 
 
-# The summary's ``plan_phases`` keeps the three names its readers look
-# up (cli/ttd_matrix.py's fabric table, the benchmark's pod_phase reader)
-# beside the span names they are sums of.
+# The summary's ``plan_phases`` keeps these three short names beside the
+# span names they are sums of: benchmark/readers/pod_phase.py looks them
+# up.
 _PLAN_PHASE_NAMES = {"upload": "fabric.upload",
                      "collective": "fabric.collective",
                      "splice": "fabric.splice"}
@@ -183,29 +183,14 @@ def run_pod(conf: cfg.Config, mode: int = 3, boot: str = "",
         ttd = time.monotonic() - t0
         ulog.log.info("Time to deliver", seconds=round(ttd, 6))
         print(f"Time to deliver: {ttd:.6f}s", flush=True)
-        # Executable reuse + phase attribution for THIS dissemination,
-        # sampled at ready (before any boot compiles muddy the water):
-        # the ttd_matrix fabric row reads these out of the summary line.
+        # Phase attribution for THIS dissemination, sampled at ready
+        # (before any boot compiles muddy the water).
         from ..parallel import plan_cache
-        from ..utils import telemetry as utelemetry
 
         plan_cache.log_stats()
-        # The whole pod lives in this ONE process, so the process
-        # registry IS the cluster's flight recorder: counters +
-        # histograms ride the summary line (ttd_matrix embeds them in
-        # its rows), and the links feed the run report below.
-        tel_snap = utelemetry.snapshot()
         summary = {"mode": mode, "ttd_s": round(ttd, 6),
                    "nodes": len(node_ids), "fabric": True,
-                   "collective_cache": plan_cache.stats(),
-                   "plan_phases": plan_phases(utrace.phase_totals()),
-                   "telemetry": {"counters": tel_snap.get("counters"),
-                                 "hists": tel_snap.get("hists")}}
-        pred_ms = getattr(leader, "predicted_ttd_ms", 0)
-        if pred_ms:
-            # Mode-3 plan fidelity next to the achieved TTD.
-            summary["predicted_s"] = round(pred_ms / 1000.0, 6)
-            summary["solve_ms"] = round(getattr(leader, "solve_ms", 0.0), 3)
+                   "plan_phases": plan_phases(utrace.phase_totals())}
         if boot_cfg is not None:
             booted = leader.boot_ready().get(timeout=timeout)
             ttft = time.monotonic() - t0

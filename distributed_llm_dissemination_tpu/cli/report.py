@@ -50,8 +50,8 @@ _LINK_COLS = (
 
 def report_hash(report: dict) -> str:
     """Deterministic content hash of the report (minus the hash field
-    itself) — the provenance stamp TTD_MATRIX rows embed so a row's
-    event counts are traceable to exactly one report artifact."""
+    itself) — the provenance stamp that ties event counts quoted
+    elsewhere to exactly one report artifact."""
     doc = {k: v for k, v in report.items() if k != "provenance"}
     blob = json.dumps(doc, sort_keys=True, separators=(",", ":"))
     return hashlib.sha256(blob.encode()).hexdigest()[:16]

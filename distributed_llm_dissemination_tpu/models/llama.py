@@ -74,10 +74,9 @@ CONFIGS: Dict[str, ModelConfig] = {
         n_heads=32, n_kv_heads=8, d_ff=14336,
     ),
     # Flagship at reduced depth: full 8B layer SHAPE (so each layer blob
-    # is the physical ~416 MiB the bench measures) but 4 layers, fitting
-    # one chip next to activations.  The driver's entry() compile check
-    # and the TTD matrix's physical-size scenario share it; "v8k" trims
-    # the vocab so the head blob doesn't dwarf the layers it escorts.
+    # is the physical ~416 MiB) but 4 layers, fitting one chip next to
+    # activations.  The driver's entry() compile check uses it; "v8k"
+    # trims the vocab so the head blob doesn't dwarf the layers it escorts.
     "llama3-8b-d4": ModelConfig(
         name="llama3-8b-d4", vocab=128256, d_model=4096, n_layers=4,
         n_heads=32, n_kv_heads=8, d_ff=14336,

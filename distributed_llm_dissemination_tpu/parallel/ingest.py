@@ -27,7 +27,7 @@ hop (the two physical situations want opposite designs):
   per span, and ``finalize`` adopts each buffer zero-copy as its device's
   array via DLPack (``utils.hostmem``).  The full layer materializes with
   ONE host memcpy total — faster than the naive bulk ``device_put`` of
-  the same bytes, which is exactly the bar ``bench.py`` measures.
+  the same bytes.
 
 ``ingest_bytes`` is the one-shot form (whole buffer already on host) used
 by mode-0/1/2 receivers; it routes through

@@ -19,8 +19,8 @@ The idea is the reusable-collective-program framing of arXiv:2112.01075
 (redistribution as a compiled, portable collective) applied to the
 dissemination terminal hop.  ``collectives.gather_tiles_at`` routes its
 gather programs through ``GATHER_CACHE`` and its splice programs through
-``SPLICE_CACHE``; ``stats()`` aggregates both for harness reports
-(``bench.py``, ``cli/podrun.py`` → ``cli/ttd_matrix.py``).
+``SPLICE_CACHE``; ``stats()`` aggregates both (``log_stats()`` is the
+"collective cache stats" record of ``cli/main.py`` and ``cli/podrun.py``).
 """
 
 from __future__ import annotations

@@ -225,8 +225,8 @@ def stage_blob_leaves(cfg, blob_id: int, src, codec: str = "raw",
         # Entropy forms have no device decode program (docs/codec.md):
         # pull the HBM-resident wire blob back to host, unwrap there
         # (decode_blob_host runs the DLE1 pass before the base decode),
-        # and stage via the host path.  Boot-path cost, measured by
-        # quant.codec_bench and recorded in TTD_MATRIX.
+        # and stage via the host path.  Boot-path cost:
+        # quant.codec_bench measures it on the running host.
         data = np.asarray(arr).tobytes()
         if blob_donate_ok(src):
             src.device_array = None

@@ -43,7 +43,7 @@ from ..utils.logging import log
 # Links whose modeled bottleneck rate (bytes/s) is at or below this ship
 # quantized when a WireCodec is configured; faster links stay raw — at
 # NIC rates the wire is cheaper than the encode/decode pass (measure on
-# the running host with quant.codec_bench; TTD_MATRIX records it).
+# the running host with quant.codec_bench).
 CODEC_MIN_RATE_DEFAULT = 64 << 20  # 64 MiB/s
 
 # The entropy forms' threshold: the DLE1 pass costs an extra host

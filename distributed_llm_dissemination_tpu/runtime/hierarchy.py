@@ -217,13 +217,7 @@ class SubLeaderController:
         run) to arrive and fold upward — a sub-leader exiting the
         moment ITS startup lands would otherwise race its members'
         flushes and the root's run report would miss them.  Anything
-        still dirty at the deadline is pushed as-is.  With the
-        telemetry plane disabled members never report, so there is
-        nothing to wait for."""
-        from ..utils import telemetry
-
-        if not telemetry.enabled():
-            return
+        still dirty at the deadline is pushed as-is."""
         deadline = time.monotonic() + timeout
         while time.monotonic() < deadline:
             with self._lock:

@@ -446,8 +446,7 @@ class ReceiverNode:
         self.clock_offset_ms = None
         self._metrics_stop = threading.Event()
         self._metrics_thread = None
-        interval = telemetry.metrics_interval()
-        self._metrics_interval = interval if telemetry.enabled() else 0.0
+        self._metrics_interval = telemetry.metrics_interval()
         # Corrupt-fragment reports (a frame the transport dropped for a
         # failed CRC, an injected drop, or a TTL-pruned stripe group)
         # become bounded NACKs to the fragment's source.

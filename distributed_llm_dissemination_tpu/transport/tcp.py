@@ -86,10 +86,10 @@ STRIPE_COUNT = max(1, int(os.environ.get("DLD_TCP_STRIPES", "4")))
 STRIPE_MIN = 2 << 20
 # Rate-limited sends stripe only when the commanded rate is at least this
 # (1 GB/s): past it the rate is a capacity BUDGET (an ICI/NIC line rate
-# the flow solver allotted — the physical-size rows), which stripes split
-# proportionally so the aggregate still honors it.  Below it the rate is
-# a scarcity model (a slow source being simulated) whose burst semantics
-# tests and the codec A/B rows depend on — those never stripe.
+# the flow solver allotted), which stripes split proportionally so the
+# aggregate still honors it.  Below it the rate is a scarcity model (a
+# slow source being simulated) whose burst semantics the tests depend
+# on — those never stripe.
 STRIPE_PACED_MIN_RATE = int(os.environ.get("DLD_TCP_STRIPE_MIN_RATE",
                                            str(10 ** 9)))
 # Reassembly groups for striped transfers to a receiver WITHOUT a

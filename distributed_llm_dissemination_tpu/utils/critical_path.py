@@ -20,8 +20,8 @@ transition actually happened); output is
   honest "unattributed" residual) tile the achieved TTD;
 - the **attribution summary** (``analyze``): chain phase totals, the
   predicted-vs-achieved gap decomposed per phase and per link, and the
-  reconciliation fraction the TTD_MATRIX ``attribution`` row is judged
-  on.
+  reconciliation fraction (how much of the achieved TTD the chain's
+  windows cover).
 
 Phase names are the one canonical tuple ``telemetry.SPAN_PHASES``; the
 tier-1 static drift check pins each to a live ``span_event`` call site.

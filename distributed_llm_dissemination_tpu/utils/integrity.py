@@ -12,7 +12,7 @@ vocabulary of the integrity plane:
   fragment is delivered — a bad frame is dropped and NACKed
   (``LayerNackMsg``), never committed to interval accounting, the
   journal, or a device buffer.  The algorithm is picked by measurement
-  (``hash_bench`` on the running host; TTD_MATRIX.md records it):
+  (``hash_bench`` on the running host):
   xxh3-64 when the ``xxhash`` extension is importable — it is the only
   candidate that tracks the wire rate here (~6x stdlib ``zlib.crc32``)
   — falling back to crc32 otherwise.  Negotiation is per frame,
@@ -157,7 +157,7 @@ def digest_algo() -> str:
     model its 128 collision bits are equivalent to blake2b's at ~11x
     less CPU on this host (``hash_bench``); ``DLD_DIGEST_ALGO=blake2b``
     buys a cryptographic identity where adversarial substitution is in
-    scope (TTD_MATRIX.md records the measured cost of each)."""
+    scope (``hash_bench`` measures the cost of each)."""
     algo = os.environ.get("DLD_DIGEST_ALGO", "").strip().lower()
     if algo in ("blake2b", "xxh3"):
         if algo == "xxh3" and _xxhash is None:
@@ -341,8 +341,8 @@ def digest_layer_src_range(src, off: int, size: int) -> Optional[str]:
 def hash_bench(nbytes: int = 64 << 20) -> dict:
     """Micro-bench the candidate integrity hashes on THIS host — the
     measured justification for the per-fragment and per-layer algorithm
-    choices (TTD_MATRIX.md records the numbers, and ``digest_algo`` /
-    ``fragment_checksum`` encode the conclusion).  Returns {name: GB/s};
+    choices (``digest_algo`` / ``fragment_checksum`` encode the
+    conclusion).  Returns {name: GB/s};
     xxh3 entries are 0.0 when the extension isn't importable."""
     buf = memoryview(bytearray(os.urandom(1 << 20)) * (nbytes >> 20))
 

@@ -1,13 +1,9 @@
-"""Harness provenance: tie recorded artifacts to the code that ran.
+"""Harness provenance: tie a recorded report to the code that ran.
 
-A recorded artifact vouches only for the code that produced it.  The
+A recorded report vouches only for the code that produced it.  The
 same content-hash discipline ``native/__init__.py`` uses for the C++
-solver (rebuild when the source changed) applies to measurement
-artifacts: every harness (``bench.py``, ``cli/report.py``) embeds
-``harness_hash()`` in its report, and ``artifact_is_current`` tells a
-report recorded by the working tree from one that merely sits next to
-it — unless the artifact carries an explicit, documented ``stale``
-marker honestly labeling it as superseded evidence.
+solver (rebuild when the source changed) applies to run reports:
+``cli/report.py`` embeds ``harness_hash()`` in every report it builds.
 """
 
 from __future__ import annotations
@@ -21,8 +17,8 @@ _REPO = os.path.dirname(_PKG)
 
 def harness_hash() -> str:
     """Content hash of every source file that can change a measurement:
-    the package's .py and .cc files plus the repo-root ``bench.py`` /
-    ``chip_smoke.py`` / ``__graft_entry__.py`` drivers.  Deterministic (sorted relative
+    the package's .py and .cc files plus the repo-root ``chip_smoke.py``
+    / ``__graft_entry__.py`` drivers.  Deterministic (sorted relative
     paths mixed into the digest); 16 hex chars is plenty for a
     did-the-code-change check."""
     h = hashlib.sha256()
@@ -32,7 +28,7 @@ def harness_hash() -> str:
         for name in sorted(names):
             if name.endswith((".py", ".cc")):
                 files.append(os.path.join(root, name))
-    for extra in ("bench.py", "chip_smoke.py", "__graft_entry__.py"):
+    for extra in ("chip_smoke.py", "__graft_entry__.py"):
         path = os.path.join(_REPO, extra)
         if os.path.exists(path):
             files.append(path)
@@ -43,18 +39,3 @@ def harness_hash() -> str:
             h.update(f.read())
         h.update(b"\0")
     return h.hexdigest()[:16]
-
-
-def artifact_is_current(report: dict) -> tuple:
-    """(ok, why) for a recorded artifact against the working tree:
-    current hash, or an explicit ``stale`` marker string documenting
-    why superseded evidence is still committed."""
-    marker = report.get("stale")
-    if isinstance(marker, str) and marker.strip():
-        return True, f"documented-stale: {marker}"
-    got = report.get("harness_hash")
-    want = harness_hash()
-    if got == want:
-        return True, "hash-current"
-    return False, (f"artifact hash {got!r} != working tree {want!r} "
-                   "and no documented 'stale' marker")

@@ -17,13 +17,11 @@ old ``utils/trace.py`` globals lacked:
   decode/stage seconds).  Writers are the transports (wire-level frames)
   and the receiver runtime (committed delivered bytes — the byte-exact
   number a run report reconciles against the goal state).
-- **Always-on and cheap**: a dict update under one lock per frame-scale
-  event (frames are MiB-scale, so the accounting is noise — measured in
-  TTD_MATRIX.md's telemetry-overhead row).  ``DLD_TELEMETRY=0`` disables
-  the LINK recorder, histograms, lifecycle instants and the interval
-  ring (the overhead A/B knob; ``DLD_SPANS=0`` the last two alone);
-  the phase totals and event counters stay on: harness tables read
-  them.
+- **Always on and cheap**: a dict update under one lock per frame-scale
+  event (frames are MiB-scale; what the recorder costs a cold start on
+  the chip is in PERF.md §6, PR 24).  There is no switch: the link
+  recorder, the histograms, the lifecycle instants and the interval
+  ring record in every process.
 - **One span store**: every timed piece of work is an INTERVAL span
   (``record_span``: name, pair id, parent, start and end on
   CLOCK_MONOTONIC, thread, node).  Its duration is added to a per-name
@@ -81,13 +79,6 @@ LINK_TX_FIELDS = frozenset((
 LINK_FIELDS = LINK_RX_FIELDS | LINK_TX_FIELDS
 
 
-def _links_enabled() -> bool:
-    """The always-on link recorder's kill switch (``DLD_TELEMETRY=0``) —
-    exists for the overhead A/B row in TTD_MATRIX.md, read per call so
-    tests can flip it without re-importing."""
-    return os.environ.get("DLD_TELEMETRY", "1") != "0"
-
-
 # ------------------------------------------------- pair lifecycle spans
 
 # The causal span vocabulary (docs/observability.md): every delivery
@@ -128,16 +119,6 @@ PHASE_NAMES: Tuple[str, ...] = ("integrity_crc_send", "codec_encode")
 # (``utils/trace.watch_compiles``).
 XLA_COUNTERS: Tuple[str, ...] = (
     "xla.compiles", "xla.compile_ms", "xla.cache_hits", "xla.cache_misses")
-
-
-def spans_enabled() -> bool:
-    """Span recording's own kill switch (``DLD_SPANS=0`` — the overhead
-    A/B knob) on top of the telemetry master switch: spans are part of
-    the flight recorder, so ``DLD_TELEMETRY=0`` silences them too.
-    Off, no lifecycle instant and no interval record is kept and no
-    profiler annotation is opened; a span then costs its two clock
-    reads and one addition to the phase totals."""
-    return (os.environ.get("DLD_SPANS", "1") != "0") and _links_enabled()
 
 
 def span_ring_size() -> int:
@@ -210,8 +191,6 @@ class Telemetry:
 
     def observe_ms(self, name: str, ms: float) -> None:
         """One fixed-bucket histogram sample (milliseconds)."""
-        if not _links_enabled():
-            return
         with self._lock:
             h = self._hists.get(name)
             if h is None:
@@ -241,8 +220,6 @@ class Telemetry:
         Bounded: the ring drops oldest (``telemetry.spans_dropped``
         counts), so a long service run degrades to a recent window
         instead of growing without bound."""
-        if not spans_enabled():
-            return
         ev = {"span": str(span), "phase": str(phase),
               "t_ms": round(_time.time() * 1000.0, 3),
               "mono": round(_time.monotonic(), 6)}
@@ -262,28 +239,22 @@ class Telemetry:
             self._counters[dropped] = self._counters.get(dropped, 0) + 1
         ring.append(rec)
 
-    def record_span(self, rec: dict, keep: Optional[bool] = None) -> None:
+    def record_span(self, rec: dict) -> None:
         """One finished INTERVAL span.  ``rec`` holds ``name``, ``t0``
         and ``t1`` (``time.monotonic()``), and whatever of ``id``,
         ``parent``, ``thread``, ``node``, ``fields`` the writer knows
-        (``utils/trace.span`` fills them).  Its duration always joins
-        the phase totals; the record itself is kept in the ring only
-        while ``spans_enabled()`` (``keep``: the writer's own reading of
-        that switch, taken when the span opened)."""
-        if keep is None:
-            keep = spans_enabled()
+        (``utils/trace.span`` fills them).  Its duration joins the phase
+        totals and the record goes into the ring."""
         with self._lock:
             tot = self._phases.get(rec["name"])
             if tot is None:
                 tot = self._phases[rec["name"]] = [0.0, 0]
             tot[0] += rec["t1"] - rec["t0"]
             tot[1] += 1
-            if keep:
-                if self._spans is None:
-                    self._spans = collections.deque(
-                        maxlen=span_ring_size())
-                self._ring_append_locked(self._spans, rec,
-                                         "telemetry.intervals_dropped")
+            if self._spans is None:
+                self._spans = collections.deque(maxlen=span_ring_size())
+            self._ring_append_locked(self._spans, rec,
+                                     "telemetry.intervals_dropped")
 
     def span_events(self) -> List[dict]:
         """The pair-lifecycle instants (what ships in
@@ -311,7 +282,7 @@ class Telemetry:
         (src, dest, job) row, serialized ``"src->dest#job"`` in
         snapshots, so overlapping jobs' bytes split instead of pooling
         into one undifferentiated counter."""
-        if src is None or dest is None or not _links_enabled():
+        if src is None or dest is None:
             return
         keys = [(int(src), int(dest), "")]
         if job:
@@ -429,8 +400,8 @@ def span_events() -> List[dict]:
     return _default.span_events()
 
 
-def record_span(rec: dict, keep: Optional[bool] = None) -> None:
-    _default.record_span(rec, keep)
+def record_span(rec: dict) -> None:
+    _default.record_span(rec)
 
 
 def interval_spans() -> List[dict]:
@@ -443,10 +414,6 @@ def snapshot() -> dict:
 
 def reset_run() -> None:
     _default.reset_run()
-
-
-def enabled() -> bool:
-    return _links_enabled()
 
 
 # -------------------------------------------------- histogram analysis

@@ -22,10 +22,6 @@ summed by name, while the ring has dropped nothing); ``add_phase`` is
 the older writer API and records a span too.  The entry points
 (``cli.main``, ``cli.genreq``, ``cli.podrun.run_pod``) write the ring out
 as their last log records with ``dump_spans``.
-
-``DLD_SPANS=0`` (or ``DLD_TELEMETRY=0``) is the overhead A/B switch: no
-record is kept, no annotation opened and no parent tracked; only the
-totals go on.
 """
 
 from __future__ import annotations
@@ -67,7 +63,7 @@ class span:
     ``error`` among its fields), so a trace shows failed work instead of
     omitting it."""
 
-    __slots__ = ("rec", "_on", "_ann", "_outer")
+    __slots__ = ("rec", "_ann", "_outer")
 
     def __init__(self, name: str, id=None, parent=None, node=None,
                  **fields):
@@ -84,34 +80,30 @@ class span:
 
     def __enter__(self) -> "span":
         rec = self.rec
-        self._on = _telemetry.spans_enabled()
-        self._ann = None
-        if self._on:
-            outer = self._outer = getattr(_tls, "open", None)
-            if outer is not None:
-                for key, inherited in (("parent", outer["name"]),
-                                       ("id", outer["id"]),
-                                       ("node", outer["node"])):
-                    if rec[key] is None:
-                        rec[key] = inherited
-            _tls.open = rec
-            self._ann = _annotation(rec["name"], rec["id"])
-            if self._ann is not None:
-                self._ann.__enter__()
+        outer = self._outer = getattr(_tls, "open", None)
+        if outer is not None:
+            for key, inherited in (("parent", outer["name"]),
+                                   ("id", outer["id"]),
+                                   ("node", outer["node"])):
+                if rec[key] is None:
+                    rec[key] = inherited
+        _tls.open = rec
+        self._ann = _annotation(rec["name"], rec["id"])
+        if self._ann is not None:
+            self._ann.__enter__()
         rec["t0"] = time.monotonic()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         rec = self.rec
         rec["t1"] = time.monotonic()
-        if self._on:
-            if self._ann is not None:
-                self._ann.__exit__(exc_type, exc, tb)
-            _tls.open = self._outer
-            if exc is not None:
-                rec["fields"]["error"] = repr(exc)
-            rec["thread"] = threading.current_thread().name
-        _telemetry.record_span(rec, self._on)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        _tls.open = self._outer
+        if exc is not None:
+            rec["fields"]["error"] = repr(exc)
+        rec["thread"] = threading.current_thread().name
+        _telemetry.record_span(rec)
         return False
 
 

@@ -31,6 +31,7 @@ place_compile_cache()
 import jax  # noqa: E402
 
 import signal  # noqa: E402
+import socket  # noqa: E402
 import threading  # noqa: E402
 
 import pytest  # noqa: E402
@@ -49,6 +50,18 @@ def _run_scoped_telemetry():
 
     telemetry.reset_run()
     yield
+
+
+@pytest.fixture(scope="session")
+def free_port():
+    """A function that returns a loopback port nothing is bound to (the
+    one copy; config builders of ``cli/genconf.py`` take it as
+    ``port``)."""
+    def _free_port() -> int:
+        with socket.socket() as s:
+            s.bind(("127.0.0.1", 0))
+            return s.getsockname()[1]
+    return _free_port
 
 
 @pytest.fixture(scope="session")

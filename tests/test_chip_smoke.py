@@ -173,14 +173,6 @@ def test_pod_child_lands_four_seats_on_four_devices():
     assert {b["kind"] for b in r["boots"].values()} == {"stage"}
 
 
-def test_bench_exits_nonzero_without_a_tpu():
-    out = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
-                         cwd=REPO, capture_output=True, text=True,
-                         timeout=100)
-    assert out.returncode != 0
-    assert out.stdout == ""  # no number under a device metric's name
-
-
 # ------------------------------------------------ one process per chip
 
 

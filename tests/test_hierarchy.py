@@ -736,3 +736,106 @@ def test_rollout_wave_plans_through_group(kind):
     finally:
         ctl.close()
         close_all(leader, list(recvs.values()), ts)
+
+
+@pytest.mark.parametrize("kind", ["inmem", "tcp"])
+def test_versioned_delta_waves_plan_through_group(kind):
+    """Hierarchy x versioned rollout x content-delta codec, both
+    backends: after v1 reached one group of 3 through the group plan, a
+    v2 that perturbs every 1024th byte of two layers rolls in two
+    version-qualified waves — wave 1 to the group-ingress sub-leader,
+    wave 2 to the members.  Every v2 pair ships as an encoded
+    ``delta:<v1-digest>`` stream (the root encodes against its own v1,
+    the sub-leader re-encodes for its members), so the root's NIC
+    carries a fraction of the changed bytes, wave 2 rides the group and
+    not the root, and every replica ends byte-, version- and
+    digest-exact."""
+    from distributed_llm_dissemination_tpu.core.types import (
+        LayerSrc,
+        SourceType,
+    )
+    from distributed_llm_dissemination_tpu.runtime.codec import (
+        WireCodecPlane,
+    )
+    from distributed_llm_dissemination_tpu.utils import (
+        integrity,
+        telemetry,
+    )
+
+    size, n_layers, changed = 256 * 1024, 3, 2
+    telemetry.reset_run()
+    trace.reset_counters()
+    ids = [0, 1, 2, 3]
+    sub_id, members = 1, [1, 2, 3]
+    ts, _ = make_transports(kind, ids)
+    leader = HierarchicalFlowLeaderNode(
+        Node(0, 0, ts[0]), {lid: mem_layer(lid, size)
+                            for lid in range(n_layers)},
+        {m: {lid: LayerMeta() for lid in range(n_layers)}
+         for m in members},
+        {i: 200_000_000 for i in ids},
+        groups={0: {"leader": sub_id, "members": members}},
+        expected_nodes={sub_id}, codecs=WireCodecPlane(None))
+    recvs = {m: FlowRetransmitReceiverNode(
+        Node(m, 0 if m == sub_id else sub_id, ts[m]), {},
+        heartbeat_interval=HB, codecs=WireCodecPlane(None))
+        for m in members}
+    ctl = SubLeaderController(recvs[sub_id], 0, members)
+
+    def link_rx(frm, to):
+        links = telemetry.snapshot()["links"]
+        return links.get(f"{frm}->{to}", {}).get("rx_bytes", 0)
+
+    try:
+        for r in recvs.values():
+            r.announce()
+        leader.start_distribution().get(timeout=TIMEOUT)
+        leader.ready().get(timeout=TIMEOUT)
+        v2 = {}
+        with leader._lock:
+            for i in range(changed):
+                # Salted so the two v2 layers never perturb the SAME
+                # positions (each would be the other's closest base).
+                data = bytearray(leader.layers[i].inmem_data)
+                for off in range(1 + 7 * i, len(data), 1024):
+                    data[off] ^= 0xA5
+                v2[100 + i] = bytes(data)
+                leader.layers[100 + i] = LayerSrc(
+                    inmem_data=data, data_size=len(data),
+                    meta=LayerMeta(location=LayerLocation.INMEM,
+                                   source_type=SourceType.MEM))
+        digests = {lid: integrity.layer_digest(b) for lid, b in v2.items()}
+        before = {m: link_rx(0, m) for m in members}
+        root_wire = []
+        for w, dests in enumerate(([sub_id], [2, 3])):
+            leader.submit_job(
+                f"wave-{w + 1}",
+                {d: {lid: LayerMeta() for lid in v2} for d in dests},
+                priority=1, kind="push", version="v2", digests=digests)
+            _wait_for(lambda: leader.jobs.table().get(
+                f"wave-{w + 1}", {}).get("State") == "done",
+                what=f"wave {w + 1} completion")
+            now = {m: link_rx(0, m) for m in members}
+            root_wire.append({m: now[m] - before[m] for m in members})
+            before = now
+        for m in members:
+            for lid, want in v2.items():
+                src = recvs[m].layers[lid]
+                assert bytes(src.inmem_data) == want, (m, lid)
+                assert src.meta.version == "v2", (m, lid)
+                if integrity.digests_enabled():
+                    assert lid in recvs[m]._digest_ok, (m, lid)
+        # The root's NIC carried encoded deltas only, and only into the
+        # group's ingress: wave 2 rode the group chain.
+        total = sum(sum(w.values()) for w in root_wire)
+        assert 0 < total <= changed * size // 4, root_wire
+        assert root_wire[1][2] == 0 and root_wire[1][3] == 0, root_wire
+        assert sum(link_rx(sub_id, m) for m in (2, 3)) > 0
+        totals = trace.counter_totals()
+        assert totals.get("codec.delta_pairs_chosen", 0) >= changed
+        assert totals.get("codec.delta_reconstructed", 0) >= changed
+        assert 0 < totals.get("codec.delta_wire_bytes", 0) < \
+            totals.get("codec.delta_raw_bytes", 0)
+    finally:
+        ctl.close()
+        close_all(leader, list(recvs.values()), ts)

@@ -9,7 +9,6 @@ sees the other's devices (the reference's per-host process model,
 
 import json
 import os
-import socket
 import subprocess
 import sys
 import textwrap
@@ -112,12 +111,6 @@ _CHILD = textwrap.dedent("""
 """)
 
 
-def _free_port() -> int:
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
-
-
 def test_host_aligned_device_order_single_process():
     # Single process: the plain device list, untouched.
     import jax
@@ -211,13 +204,13 @@ def test_host_aligned_reports_uneven_counts(monkeypatch):
         host_aligned_device_order(conf, {1: {0: None}})
 
 
-def test_two_process_hbm_dissemination():
+def test_two_process_hbm_dissemination(free_port):
     """The full multi-host loop through the REAL CLI: two processes join
     one JAX runtime, the mesh's stages align to each node's host, and the
     receiver lands its delivered layers in (its own host's) device memory
     — the leader reports TTD, the receiver logs the HBM staging."""
-    port = _free_port()
-    p0, p1 = _free_port(), _free_port()
+    port = free_port()
+    p0, p1 = free_port(), free_port()
     conf_path = os.path.join(REPO, ".pytest-2proc-hbm.json")
     conf_json = {
         "Nodes": [
@@ -272,10 +265,10 @@ def test_two_process_hbm_dissemination():
             os.remove(conf_path)
 
 
-def test_two_process_cpu_smoke():
+def test_two_process_cpu_smoke(free_port):
     """Two real OS processes form one JAX runtime from the same config:
     each contributes its local CPU device; both see global=2."""
-    port = _free_port()
+    port = free_port()
     conf_json = json.dumps({
         "Nodes": [
             {"Id": 0, "Addr": "127.0.0.1:9080", "IsLeader": True},
@@ -350,12 +343,12 @@ _TRAIN_CHILD = textwrap.dedent("""
 
 
 @pytest.mark.slow  # ~33 s wall: over the 30 s tier-1 per-test budget
-def test_two_process_training_step():
+def test_two_process_training_step(free_port):
     """TRAINING across processes: two OS processes join one runtime
     (4 virtual CPU devices each), build ONE global 8-device train mesh,
     and run AdamW steps whose gradient psums cross the process boundary
     (gloo) — both report identical, decreasing losses."""
-    port = _free_port()
+    port = free_port()
     conf_json = json.dumps({
         "Nodes": [
             {"Id": 0, "Addr": "127.0.0.1:9082", "IsLeader": True},

@@ -122,17 +122,6 @@ def test_link_recorder_unknown_endpoint_records_nothing():
     assert reg.snapshot()["links"] == {}
 
 
-def test_telemetry_disabled_gates_links_not_counters(monkeypatch):
-    monkeypatch.setenv("DLD_TELEMETRY", "0")
-    reg = telemetry.Telemetry()
-    reg.link_add(0, 1, rx_bytes=10)
-    reg.observe_ms("h", 1.0)
-    reg.count("integrity.crc_drop")  # pre-existing planes stay on
-    snap = reg.snapshot()
-    assert snap["links"] == {} and snap["hists"] == {}
-    assert snap["counters"] == {"integrity.crc_drop": 1}
-
-
 def test_trace_api_delegates_to_run_scoped_registry():
     """Satellite: the old process-global trace sums are gone — the
     trace.py writer API lands in the run-scoped registry, and one
@@ -331,6 +320,15 @@ def test_adopted_leader_still_yields_complete_report():
         standby.announce()
         worker.announce()
         leader.start_distribution().get(timeout=TIMEOUT)
+        # The standby must have OBSERVED a lease before the kill, or
+        # its expiry detector was never armed and no promotion can fire
+        # (tests/test_failover.py has the same wait): under load this
+        # leader's whole short life could pass without one lease
+        # reaching the controller's hook.
+        deadline = time.monotonic() + TIMEOUT
+        while not ctl._armed and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert ctl._armed, "standby never observed a lease"
         leader.close()  # the mid-run death
         assert ctl.promoted.wait(timeout=30.0), "standby never promoted"
         ctl.leader.ready().get(timeout=30.0)
@@ -580,7 +578,7 @@ def test_every_trace_rule_string_exists_in_package_source():
 # --------------------------------- pair-lifecycle spans + critical path
 
 
-def test_span_ring_records_bounded_and_gated(monkeypatch):
+def test_span_ring_records_and_is_bounded(monkeypatch):
     reg = telemetry.Telemetry()
     reg.span_event("2.7", "planned", node=0, src=0, dest=2, layer=7)
     reg.span_event("2.7", "acked", node=0, dest=2, layer=7)
@@ -596,16 +594,6 @@ def test_span_ring_records_bounded_and_gated(monkeypatch):
                         bytes=i)
     assert len(reg2.span_events()) == 64
     assert reg2.snapshot()["counters"]["telemetry.spans_dropped"] == 6
-    # Kill switches: DLD_SPANS=0, and the telemetry master switch.
-    monkeypatch.setenv("DLD_SPANS", "0")
-    reg3 = telemetry.Telemetry()
-    reg3.span_event("1.1", "planned", node=0)
-    assert reg3.span_events() == []
-    monkeypatch.delenv("DLD_SPANS")
-    monkeypatch.setenv("DLD_TELEMETRY", "0")
-    reg4 = telemetry.Telemetry()
-    reg4.span_event("1.1", "planned", node=0)
-    assert reg4.span_events() == []
     # reset_run clears the ring.
     reg.reset_run()
     assert reg.span_events() == []

@@ -486,6 +486,50 @@ def test_leader_killed_mid_action_standby_completes_it(kind, monkeypatch):
         close_all(leader, [standby] + workers, ts)
 
 
+# ------------------------------ the real leader's replan actuator
+
+
+@pytest.mark.parametrize("kind", ["inmem", "tcp"])
+def test_demoted_link_is_planned_around_by_the_leader(kind):
+    """The replan actuator on a real mode-3 leader (docs/autonomy.md):
+    with two holders of every layer, a demotion of the leader's own
+    link to the dest prices that arc at its measured rate, so the solve
+    that follows sources the dest from the other holder — the link
+    table shows where the bytes went."""
+    telemetry.reset_run()
+    before = trace.counter_totals().get("policy.link_demotions", 0)
+    size, lids = 64 * 1024, [0, 1]
+    ids = [0, 1, 2]
+    ts, _ = make_transports(kind, ids)
+    leader = FlowRetransmitLeaderNode(
+        Node(0, 0, ts[0]), {lid: mem_layer(lid, size) for lid in lids},
+        {2: {lid: LayerMeta() for lid in lids}},
+        {i: 10 ** 9 for i in ids}, expected_nodes={1, 2})
+    peer = FlowRetransmitReceiverNode(
+        Node(1, 0, ts[1]), {lid: mem_layer(lid, size) for lid in lids},
+        heartbeat_interval=HB)
+    dest = FlowRetransmitReceiverNode(Node(2, 0, ts[2]), {},
+                                      heartbeat_interval=HB)
+    try:
+        leader.policy_demote_link(0, 2, 1000)
+        peer.announce()
+        dest.announce()
+        leader.start_distribution().get(timeout=TIMEOUT)
+        leader.ready().get(timeout=TIMEOUT)
+        for lid in lids:
+            assert bytes(dest.layers[lid].inmem_data) == layer_bytes(
+                lid, size)
+        links = telemetry.snapshot()["links"]
+        slow = links.get("0->2", {}).get("delivered_bytes", 0)
+        # The demoted arc keeps its honest budget: 1000 B/s over a plan
+        # of a millisecond or so is a byte a layer.
+        assert slow <= 16 * len(lids), links
+        assert links["1->2"]["delivered_bytes"] + slow == len(lids) * size
+        assert trace.counter_totals()["policy.link_demotions"] == before + 1
+    finally:
+        close_all(leader, [peer, dest], ts)
+
+
 # --------------------------------------------------- static drift check
 
 

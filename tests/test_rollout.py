@@ -312,6 +312,22 @@ def test_explicit_zero_split_honored():
         close_all(leader, [], ts)
 
 
+def test_traffic_pools_mask_policy_quarantined_replicas():
+    """The serve-rotation mask (docs/autonomy.md): a replica the policy
+    engine quarantined leaves BOTH A/B pools and is listed under its own
+    key — traffic routes around a breacher, operators still see it."""
+    class _Leader:
+        def serve_quarantined(self):
+            return {2, 4}
+
+    driver = rmod.RolloutDriver.__new__(rmod.RolloutDriver)
+    driver.leader = _Leader()
+    rec = {"waves": [[1, 2], [3], [4, 5]], "split": 0.25,
+           "wave_states": [rmod.W_PASSED, rmod.W_SOAKING, rmod.W_STAGED]}
+    assert driver._traffic_locked(rec) == {
+        "split": 0.25, "v2": [1, 3], "v1": [5], "quarantined": [2, 4]}
+
+
 @pytest.mark.timeout(60)
 def test_rollout_ctl_mutating_verbs_require_job_token(monkeypatch):
     """Resume re-submits a wave's swap job and a commit flips serving —
@@ -921,6 +937,10 @@ def test_rollout_ctl_pause_resume_split_and_query():
     loop.register(RolloutCtlMsg, replies.put)
     loop.start()
     requester.close()  # this test drives ctl, not generation
+    # Two loops drain one transport queue: a stopped loop still takes
+    # whatever lands inside its last 0.1 s poll, so see it gone before
+    # the first reply is due.
+    requester.loop._thread.join(timeout=2.0)
 
     def ctl(**kw):
         ts[9].send(0, RolloutCtlMsg(9, **kw))

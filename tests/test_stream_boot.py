@@ -492,8 +492,7 @@ def test_compile_cache_placement_rules(monkeypatch):
         ["grep", "-rlE", "--include=*.py",
          'jax_compilation_cache_dir", |DLD_COMPILE_' 'CACHE_DIR',
          os.path.join(repo, "distributed_llm_dissemination_tpu"),
-         os.path.join(repo, "chip_smoke.py"),
-         os.path.join(repo, "bench.py")],
+         os.path.join(repo, "chip_smoke.py")],
         capture_output=True, text=True).stdout.split()
     assert not hits, hits
 

@@ -129,9 +129,6 @@ def test_census_buckets_by_name():
 # stable thread name (control plane) so the census stays meaningful.
 THREAD_SPAWN_ALLOWLIST = {
     "cli/main.py": 3,            # telemetry-watch, lp-warm, churn-leave
-    "cli/ttd_matrix.py": 6,      # harness loopback probes + req hammers
-    #                              (live_swap + rollout + autonomy) +
-    #                              elasticity concurrent joiners
     "parallel/fabric.py": 1,     # plan-window
     "parallel/spmd_fabric.py": 1,  # spmd-fabric
     "runtime/failover.py": 1,    # replicate-<standby>

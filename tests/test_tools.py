@@ -6,6 +6,7 @@ conf/config.json; these tests cover our equivalents end to end.
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -241,33 +242,86 @@ def test_v5e32_config_matches_llama70b():
     assert len(seen) == 80
 
 
-def test_local_4node_runs_end_to_end(tmp_path):
-    """Spawn the real CLI against conf/local_4node.json (mode 1, real TCP,
-    5 processes) and assert the leader prints Time to deliver — the
-    reference's manual smoke run, automated."""
-    procs = []
-    try:
-        for i in range(1, 5):
-            procs.append(subprocess.Popen(
-                [sys.executable, "-m",
-                 "distributed_llm_dissemination_tpu.cli.main",
-                 "-id", str(i), "-f", f"{CONF_DIR}/local_4node.json",
-                 "-m", "1"],
-                stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
-            ))
-        leader = subprocess.run(
+@pytest.mark.parametrize("mode", [0, 1, 2, 3])
+def test_local_4node_runs_end_to_end(tmp_path, free_port, mode):
+    """Spawn the real CLI against conf/local_4node.json (real TCP on
+    free loopback ports, 5 processes) in every mode and assert the
+    leader prints Time to deliver — the reference's manual smoke run,
+    automated."""
+    with open(os.path.join(REPO, CONF_DIR, "local_4node.json")) as f:
+        conf = json.load(f)
+    for n in conf["Nodes"]:
+        n["Addr"] = f"127.0.0.1:{free_port()}"
+    conf_path = str(tmp_path / "local_4node.json")
+    with open(conf_path, "w") as f:
+        json.dump(conf, f)
+
+    def spawn(node_id, **kw):
+        return subprocess.Popen(
             [sys.executable, "-m",
              "distributed_llm_dissemination_tpu.cli.main",
-             "-id", "0", "-f", f"{CONF_DIR}/local_4node.json", "-m", "1"],
-            stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=60,
-        )
-        assert b"Time to deliver" in leader.stdout, leader.stderr[-2000:]
-        for p in procs:
+             "-id", str(node_id), "-f", conf_path, "-m", str(mode)],
+            stdout=subprocess.PIPE, **kw)
+
+    procs = []
+    try:
+        # The leader first: its listener is up before a receiver dials.
+        leader = spawn(0, stderr=subprocess.PIPE)
+        procs.append(leader)
+        for i in range(1, 5):
+            procs.append(spawn(i, stderr=subprocess.DEVNULL))
+        out, err = leader.communicate(timeout=60)
+        m = re.search(rb"Time to deliver: ([0-9.]+)s", out)
+        assert m, err[-2000:]
+        ttd = float(m.group(1))
+        assert 0 < ttd < 30
+        if mode == 3:
+            # The millisecond-granular flow solver: a 3x1MiB
+            # dissemination must not be paced to the reference's
+            # 1-second integer-time floor.
+            assert ttd < 0.5, f"mode 3 TTD {ttd}s looks 1s-padded"
+        for p in procs[1:]:
             assert p.wait(timeout=30) == 0
     finally:
         for p in procs:
             if p.poll() is None:
                 p.kill()
+
+
+def test_genconf_scenarios_parse_and_match_shapes(tmp_path):
+    # The four BASELINE benchmark topologies regenerate deterministically,
+    # parse through the loader, and keep their driver-named shapes.
+    from distributed_llm_dissemination_tpu.cli import genconf
+
+    genconf.main(["-o", str(tmp_path)])
+    shapes = {
+        "bench_8node_llama8b.json": (8, 32, 400 << 20),
+        "bench_16node_llama70b.json": (16, 80, int(1.6 * (1 << 30))),
+        "bench_32node_pipeline.json": (32, 80, int(1.6 * (1 << 30))),
+        "bench_64node_llama405b.json": (64, 126, int(3.2 * (1 << 30))),
+    }
+    for name, (nodes, layers, size) in shapes.items():
+        c = cfg.read_json(str(tmp_path / name))
+        assert len(c.nodes) == nodes
+        assigned = {lid for v in c.assignment.values() for lid in v}
+        assert assigned == set(range(layers))
+        assert c.layer_size == size
+        # The shipped copy matches the generator (no drift).
+        shipped = cfg.read_json(os.path.join(REPO, CONF_DIR, name))
+        assert shipped == c
+
+
+def test_pipeline_scenario_assignment_is_contiguous(tmp_path):
+    from distributed_llm_dissemination_tpu.cli import genconf
+
+    genconf.main(["-o", str(tmp_path)])
+    c = cfg.read_json(str(tmp_path / "bench_32node_pipeline.json"))
+    pos = 0
+    for dest in sorted(c.assignment):
+        lids = sorted(c.assignment[dest])
+        assert lids == list(range(pos, pos + len(lids))), dest
+        pos += len(lids)
+    assert pos == 80
 
 
 @pytest.mark.timeout(240)

@@ -129,39 +129,6 @@ def test_many_frames_cannot_push_the_lifecycle_instants_out(monkeypatch):
     assert trace.counter_totals()["telemetry.spans_dropped"] == 8
 
 
-@pytest.mark.parametrize("switch", ["DLD_SPANS", "DLD_TELEMETRY"])
-def test_the_overhead_switch_stops_the_records_and_keeps_the_totals(
-        monkeypatch, switch):
-    """``DLD_SPANS=0`` / ``DLD_TELEMETRY=0``: no interval record, no
-    annotation, no parent tracking; the phase totals go on."""
-    import jax  # noqa: F401  (so that a span would open an annotation)
-
-    opened = []
-    monkeypatch.setattr(trace, "_annotation",
-                        lambda name, span_id: opened.append(name))
-    monkeypatch.setenv(switch, "0")
-    with trace.span("ingest.finalize", id="2.3", node=2) as sp:
-        with trace.span("ingest.finalize.wait"):
-            time.sleep(0.002)
-        sp.set(bytes=4)
-    trace.span_at("wire.queue", 1.0, 1.5)
-    trace.add_phase("codec_encode", 0.25)
-    assert trace.spans() == [] and opened == []
-    assert sp.seconds >= 0.002
-    totals = trace.phase_totals()
-    assert totals["wire.queue"] == {"ms": 500.0, "n": 1}
-    assert totals["codec_encode"]["n"] == 1
-    assert totals["ingest.finalize"]["ms"] >= totals[
-        "ingest.finalize.wait"]["ms"] >= 2.0
-    assert "telemetry.intervals_dropped" not in trace.counter_totals()
-    monkeypatch.delenv(switch)
-    with trace.span("wire.crc"):
-        pass
-    assert [s["name"] for s in trace.spans()] == ["wire.crc"]
-    assert opened == ["wire.crc"]
-    assert trace.spans()[0]["parent"] is None  # no stale parent left
-
-
 def test_phase_totals_are_the_sums_of_the_ring():
     """While the ring has dropped nothing."""
     trace.span_at("fabric.collective", 10.0, 12.0)
