@@ -22,8 +22,10 @@ Mapping:
   the wall clock by the ``"span counters"`` record's clock pair; the
   ``decode.stage`` spans' ``fast_bytes`` / ``slow_bytes`` and the
   ``fabric.publish`` spans' ``bytes`` / ``host_copy_bytes`` / ``pieces``
-  are added up and printed on stderr (give one round's logs for the
-  round's sum).
+  are added up and printed on stderr, and beside them the counters
+  ``wire.buf.reused_bytes`` / ``wire.buf.fresh_bytes`` of the
+  ``"span counters"`` records (give one round's logs for the round's
+  sum).
 
 Usage:
     python -m distributed_llm_dissemination_tpu.cli.trace logs/ -o run.trace.json
@@ -262,6 +264,22 @@ def fabric_publish_totals(events: List[dict]) -> dict:
                          ("bytes", "host_copy_bytes", "pieces"))
 
 
+def recv_buffer_totals(records: Iterable[dict]) -> dict:
+    """Where the logs' receivers got their reassembly buffers: the
+    ``"span counters"`` records' ``wire.buf.reused_bytes`` (leased from
+    a pool slab that lay free, already faulted) and
+    ``wire.buf.fresh_bytes`` (mapped anew) added up, over one round's
+    logs the round's sums (``utils/buffers.py``).  Empty when no log
+    carries either."""
+    totals = {"reused_bytes": 0, "fresh_bytes": 0}
+    for rec in records:
+        if rec.get("message") == "span counters":
+            for key in totals:
+                totals[key] += (rec.get("counters") or {}).get(
+                    f"wire.buf.{key}", 0)
+    return totals if any(totals.values()) else {}
+
+
 def to_trace_events(records: Iterable[dict],
                     align_clocks: bool = True) -> List[dict]:
     """Chrome trace events from merged log records.
@@ -485,8 +503,13 @@ def main(argv: list[str] | None = None) -> int:
     if not args.paths:
         p.error("give log paths, or --xplane")
 
-    events = to_trace_events(iter_records(args.paths),
-                             align_clocks=not args.raw_clocks)
+    records = list(iter_records(args.paths))
+    events = to_trace_events(records, align_clocks=not args.raw_clocks)
+    leased = recv_buffer_totals(records)
+    if leased:
+        print("wire.buf leased {reused_bytes} B from slabs that lay free, "
+              "mapped {fresh_bytes} B anew".format(**leased),
+              file=sys.stderr)
     widened = decode_widen_totals(events)
     if widened:
         print("decode.stage widened {fast_bytes} B with the kernel, "

@@ -1744,6 +1744,13 @@ class ReceiverNode:
             # a live leader), then stop the retirement thread.
             window.drain(timeout=5.0)
             window.close()
+        # A closed node holds no layer: whatever still points at the
+        # node (a transport's last event, the caller that built it) no
+        # longer pins its receive buffers, so their slabs go back to
+        # the pool (utils/buffers.py) for the next delivery.  The
+        # caller's own dict is left as it was.
+        with self._lock:
+            self.layers = {}
 
     def layer_placement(self) -> dict:
         """Where every held layer ended: the location its ack carried,
@@ -3270,8 +3277,9 @@ class FlowRetransmitReceiverNode(RetransmitReceiverNode):
                 return None  # finished layer: bounce path re-acks dups
             entry = self._partial.get(layer_id)
             if entry is None:
-                entry = (alloc_recv_buffer(total_size),
-                         intervals.ClaimedCoverage())
+                entry = (alloc_recv_buffer(
+                    total_size, sparse=bool(self._shard_specs.get(layer_id))),
+                    intervals.ClaimedCoverage())
             buf, cov = entry
             tok, claims = cov.claim(offset, end)
             if tok is None:
@@ -3552,6 +3560,10 @@ class FlowRetransmitReceiverNode(RetransmitReceiverNode):
         if self._gap_thread is not None:
             self._gap_thread.join(timeout=2.0)
         super().close()
+        with self._lock:
+            self._partial = {}
+        with self._ingests_lock:
+            self._ingests = {}
 
     def _get_or_create_ingest(self, layer_id, total_size):
         """The layer's incremental device ingest, created on first use;
@@ -3827,8 +3839,10 @@ class FlowRetransmitReceiverNode(RetransmitReceiverNode):
                     # hundreds of ms at real layer sizes; coverage is
                     # tracked by intervals, so unwritten bytes are never
                     # exposed).
-                    entry = (alloc_recv_buffer(msg.total_size),
-                             intervals.ClaimedCoverage())
+                    entry = (alloc_recv_buffer(
+                        msg.total_size, sparse=bool(
+                            self._shard_specs.get(lid) or msg.shard)),
+                        intervals.ClaimedCoverage())
                 buf, cov = entry
                 if placed:
                     # The sink already claimed exactly this range and the

@@ -71,6 +71,11 @@ class _Task:
         except BaseException as e:  # noqa: BLE001 — surfaced to wait()
             self.error = e
         finally:
+            # A finished task pins nothing: its callable is a bound
+            # method of a transport, and through it a node and that
+            # node's receive buffers (utils/buffers.py leases them back
+            # only when the last reference is gone).
+            self.fn = self.args = None
             self._done.set()
 
     def wait(self, timeout: Optional[float] = None) -> bool:
@@ -156,6 +161,7 @@ class WorkerPool:
                     self._idle -= 1
                     self._pending -= 1
             task.run()
+            del task  # an idle worker holds no finished job
 
 
 _rx: Optional[WorkerPool] = None

@@ -66,6 +66,32 @@ def test_worker_pool_bounds_and_names_workers():
     assert all(name.startswith("tpool-test-") for name in seen)
 
 
+def test_idle_worker_and_finished_task_pin_nothing():
+    """A job's arguments die with the job: an idle worker keeps no
+    reference to the last task it ran, and a finished task none to its
+    callable and arguments (a transport's bound method, and through it a
+    node's receive buffers, utils/buffers.py)."""
+    import gc
+    import weakref
+
+    class Payload:
+        pass
+
+    pool = threads.WorkerPool(1, "tpool-idle")
+    payload = Payload()
+    gone = weakref.ref(payload)
+    task = pool.submit(lambda p: None, payload)
+    assert task.wait(5.0)
+    del payload
+    # the one worker is idle in ``queue.get`` again; ``task`` still lives
+    deadline = time.monotonic() + 5.0
+    while gone() is not None and time.monotonic() < deadline:
+        time.sleep(0.01)
+        gc.collect()
+    assert gone() is None
+    assert task.error is None
+
+
 def test_worker_pool_run_all_caller_slot_and_error():
     pool = threads.WorkerPool(2, "tpool-err")
     ran = []

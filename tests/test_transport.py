@@ -402,11 +402,13 @@ def test_striped_layer_transfer_reassembles(small_stripes, monkeypatch):
         assert bytes(got.layer_src.inmem_data) == payload
         assert got.layer_src.offset == 0
         assert got.total_size == len(payload)
-        # The transfer really striped (4 stripe frames), fanning out over
-        # pooled connections (exact dial count depends on thread timing —
-        # a fast stripe can finish before a sibling checks the pool).
+        # The transfer really striped: four stripe frames, over at most
+        # one pooled connection each.  How many connections the stripes
+        # shared is thread timing (a fast stripe hands its connection
+        # back before a sibling looks in the pool; under a loaded
+        # machine all four can ride one), so only the ceiling is held.
         assert sorted(stripes_seen) == [0, 1, 2, 3]
-        assert 2 <= len(dials) <= 4, dials
+        assert 1 <= len(dials) <= 4, dials
         # Nothing half-assembled left behind.
         assert ts[1]._stripe_groups == {}
     finally:
