@@ -248,7 +248,9 @@ def run_smoke(model: str, platform: str, serve_s: float = 40.0) -> dict:
         _one_chip_phase(model, platform, serve_s, kids, left, result)
         _reference_phase(model, platform, left, result)
         n_dev = result["device"]["count"]
-        if n_dev >= 4:
+        if result["pod_refusal"]:
+            result["four_chip"] = f"skipped: {result['pod_refusal']}"
+        elif n_dev >= 4:
             _log("four-chip phase: run_pod on a four-stage mesh")
             result["four_chip"] = _run_child(
                 "pod", ["pod", model, platform], _child_env(platform),
@@ -277,6 +279,7 @@ def _one_chip_phase(model, platform, serve_s, kids, left, result) -> None:
     desc = _run_child("describe", ["describe", model], _child_env("cpu"),
                       min(120.0, left()))
     blob_bytes = {int(b): n for b, n in desc["blobs"].items()}
+    result["pod_refusal"] = desc["pod_refusal"]
     blobs = {str(b): {} for b in blob_bytes}
     addrs = _free_addrs(4)
     # physical_config()'s shape: leader and one peer seeder hold every
@@ -492,12 +495,27 @@ def _reference_phase(model, platform, left, result) -> None:
 
 
 def _child_describe(model: str) -> dict:
-    from distributed_llm_dissemination_tpu.models import serde
-    from distributed_llm_dissemination_tpu.models.llama import CONFIGS
+    """The model's blobs and their bytes (a blob's size depends on its
+    kind of layer), and what ``run_pod`` says to its family: None, or the
+    sentence it refuses it with."""
+    from distributed_llm_dissemination_tpu.models import family, serde
 
-    cfg = CONFIGS[model]
+    cfg = family.config(model)
     return {"blobs": {b: serde.blob_nbytes(cfg, b)
-                      for b in range(serde.head_blob_id(cfg) + 1)}}
+                      for b in range(serde.head_blob_id(cfg) + 1)},
+            "pod_refusal": _pod_refusal(family, cfg)}
+
+
+def _pod_refusal(family, cfg):
+    """``cli.podrun.run_pod`` knows one family: the four-chip phase is
+    no part of the smoke of another."""
+    try:
+        family.only(cfg, ("llama",), "chip_smoke's four-chip phase",
+                    "it drives cli.podrun.run_pod, whose stage boots and "
+                    "pod decode know Llama's block and K/V cache only")
+    except family.FamilyNotSupported as e:
+        return str(e)
+    return None
 
 
 def _device_report(jax) -> dict:
@@ -516,10 +534,9 @@ def _child_reference(model: str, platform: str, served_path: str) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from distributed_llm_dissemination_tpu.models import serde
+    from distributed_llm_dissemination_tpu.models import family, serde
     from distributed_llm_dissemination_tpu.models.generate import generate
     from distributed_llm_dissemination_tpu.models.llama import (
-        CONFIGS,
         forward,
         forward_jit,
     )
@@ -562,7 +579,7 @@ def _child_reference(model: str, platform: str, served_path: str) -> dict:
         pass
     report["versions"] = versions
 
-    cfg = CONFIGS[model]
+    cfg = family.config(model)
     dev = jax.devices()[0]
     cpu = jax.devices("cpu")[0]
     with open(served_path) as f:

@@ -20,7 +20,9 @@ Mapping:
 - the entry points' ``"spans"`` dumps (``utils/trace.dump_spans``)
   become one slice per interval span on a per-thread track, placed on
   the wall clock by the ``"span counters"`` record's clock pair; the
-  ``decode.stage`` spans' ``fast_bytes`` / ``slow_bytes`` and the
+  ``decode.stage`` spans' ``fast_bytes`` / ``slow_bytes``, the
+  ``boot.assemble`` spans' ``kinds``, the ``serve.generate`` spans'
+  ``moe_slots`` / ``moe_held`` / ``moe_touched`` and the
   ``fabric.publish`` spans' ``bytes`` / ``host_copy_bytes`` / ``pieces``
   are added up and printed on stderr, and beside them the counters
   ``wire.buf.reused_bytes`` / ``wire.buf.fresh_bytes`` of the
@@ -262,6 +264,25 @@ def fabric_publish_totals(events: List[dict]) -> dict:
     (``runtime/send.py`` ``contribute_device_plan``)."""
     return _field_totals(events, "fabric.publish",
                          ("bytes", "host_copy_bytes", "pieces"))
+
+
+def assemble_totals(events: List[dict]) -> dict:
+    """The stacks of parameters the logs' boots built: the
+    ``boot.assemble`` slices' ``kinds`` added up — one stack a kind of
+    layer held (``models/family.py``), so 1 a boot where every layer is
+    alike."""
+    return _field_totals(events, "boot.assemble", ("kinds",))
+
+
+def routed_slot_totals(events: List[dict]) -> dict:
+    """What the logs' served requests routed: the ``serve.generate``
+    slices' ``moe_slots`` (positions x routed layers x top-k),
+    ``moe_held`` (slots whose expert is held here) and ``moe_touched``
+    (per routed layer and step, the distinct experts that got a slot:
+    what a gathered dispatch would read; a family that does not count it
+    adds nothing) added up.  Empty for a family that routes nothing."""
+    return _field_totals(events, "serve.generate",
+                         ("moe_slots", "moe_held", "moe_touched"))
 
 
 def recv_buffer_totals(records: Iterable[dict]) -> dict:
@@ -515,6 +536,17 @@ def main(argv: list[str] | None = None) -> int:
         print("decode.stage widened {fast_bytes} B with the kernel, "
               "{slow_bytes} B with the strided slices ({spans} spans)"
               .format(**widened), file=sys.stderr)
+    assembled = assemble_totals(events)
+    if assembled:
+        print("boot.assemble built {kinds} stacks of parameters, one a "
+              "kind of layer held ({spans} spans)".format(**assembled),
+              file=sys.stderr)
+    routed = routed_slot_totals(events)
+    if routed:
+        print("serve.generate routed {moe_slots} slots, {moe_held} of them "
+              "to experts held here, over {moe_touched} expert-reads a "
+              "gathered dispatch would make ({spans} spans)"
+              .format(**routed), file=sys.stderr)
     published = fabric_publish_totals(events)
     if published:
         print("fabric.publish put {bytes} B on the fabric in {pieces} "

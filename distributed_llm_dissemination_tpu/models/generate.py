@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from . import family
 from .llama import ModelConfig
 
-Cache = Dict[str, jax.Array]  # the family's; every leaf [n_layers, ...]
+Cache = Dict[str, Any]  # the family's; stacked as its parameters are
 Counters = Dict[str, jax.Array]  # the family's; int32 scalars
 
 
@@ -76,7 +76,9 @@ def init_cache(cfg, batch: int, max_len: int) -> Cache:
 def _forward_with_cache(params, tokens, positions, cache, cfg):
     """Stacked-layer forward that threads the cache; returns (logits for
     the LAST position, updated cache, the blocks' counters added up over
-    the layers)."""
+    the layers).  One ``lax.scan`` for each run of one kind of layer
+    (``family.run_slices``: a uniform family's one scan over its whole
+    tree), each over the run's part of the parameters and of the state."""
     fam = family.of(cfg)
     x = fam.embed(params, tokens, cfg)
 
@@ -87,9 +89,19 @@ def _forward_with_cache(params, tokens, positions, cache, cfg):
         )
         return x, (layer_cache, counted)
 
-    x, (cache, counted) = jax.lax.scan(body, x, (params["layers"], cache))
+    state = dict(family.by_kind(cfg, cache))
+    total: Counters = {}
+    for kind, start, stop, run in family.run_slices(
+            cfg, (params["layers"], cache)):
+        x, (new, counted) = jax.lax.scan(body, x, run)
+        state[kind] = jax.tree.map(
+            lambda old, part: part if part.shape == old.shape
+            else old.at[start:stop].set(part), state[kind], new)
+        for name, c in counted.items():
+            total[name] = (total[name] + c.sum(0) if name in total
+                           else c.sum(0))
     logits = fam.logits(params, x[:, -1:, :], cfg)[:, 0, :]
-    return logits, cache, jax.tree.map(lambda c: c.sum(0), counted)
+    return logits, family.of_kinds(cfg, state), total
 
 
 def _pick(logits, step_key, temperature: float):

@@ -185,16 +185,16 @@ def model_keys(cfg, key: jax.Array):
 
 def init_params(cfg, key: jax.Array) -> Dict[str, Any]:
     """Full model params of any family.  Layer weights are STACKED along
-    a leading n_layers axis — one pytree leaf per weight kind — so a
-    layer is a slice (disseminable blob) and scan/pipeline stages index
-    it; the head blob's leaves lie beside ``"layers"``."""
+    a leading layer axis — one pytree leaf per weight kind, by kind of
+    layer where a family has several (``family.stack``) — so a layer is
+    a slice (disseminable blob) and scan/pipeline stages index it; the
+    head blob's leaves lie beside ``"layers"``."""
     fam = family.of(cfg)
     k_emb, layer_keys, k_out = model_keys(cfg, key)
-    per_layer = [fam.init_layer_params(cfg, lk) for lk in layer_keys]
-    stacked = {
-        name: jnp.stack([lp[name] for lp in per_layer])
-        for name in per_layer[0]
-    }
+    stacked = family.stack(
+        cfg, range(cfg.n_layers),
+        lambda lid: family.init_layer_params(cfg, layer_keys[lid], lid),
+        jnp.stack)
     return {**fam.init_head_params(cfg, k_emb, k_out), "layers": stacked}
 
 
@@ -368,20 +368,30 @@ def layer_with_cache(
 
 # ------------------------------------------------------------------ forward
 
-def forward(params: Dict[str, Any], tokens: jax.Array, cfg) -> jax.Array:
-    """Logits for [batch, seq] int tokens, for a configuration of any
-    family (``models/family.py``: embedding, block and head are the
-    family's).  Layers run under lax.scan over the stacked layer axis —
-    one traced layer body regardless of depth."""
+def apply_layers(layers, x: jax.Array, positions: jax.Array, cfg,
+                 layer_ids=None) -> jax.Array:
+    """The blocks of ``layer_ids`` (default: every layer) over ``x``, in
+    order and without a cache: one ``lax.scan`` for each run of one kind
+    in ``layers`` (``family.run_slices``) — one traced body a run
+    whatever its length, and for a uniform family the one scan over its
+    stacked tree."""
     fam = family.of(cfg)
-    b, s = tokens.shape
-    positions = jnp.arange(s)
-    x = fam.embed(params, tokens, cfg)
 
     def body(x, layer_p):
         return fam.layer_apply(layer_p, x, positions, cfg), None
 
-    x, _ = jax.lax.scan(body, x, params["layers"])
+    for _, _, _, (run,) in family.run_slices(cfg, (layers,), layer_ids):
+        x, _ = jax.lax.scan(body, x, run)
+    return x
+
+
+def forward(params: Dict[str, Any], tokens: jax.Array, cfg) -> jax.Array:
+    """Logits for [batch, seq] int tokens, for a configuration of any
+    family (``models/family.py``: embedding, block and head are the
+    family's; ``apply_layers`` runs the stack)."""
+    fam = family.of(cfg)
+    x = fam.embed(params, tokens, cfg)
+    x = apply_layers(params["layers"], x, jnp.arange(tokens.shape[1]), cfg)
     return fam.logits(params, x, cfg)
 
 

@@ -51,11 +51,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import serde
+from . import family, serde
 from .llama import ModelConfig
 from .serde import (
     Spec,
     blob_nbytes,
+    blob_specs as _blob_specs,
     head_blob_id,
     head_param_specs,
     layer_param_specs,
@@ -73,11 +74,6 @@ _SCALE_DT = np.float32
 _QMAX = 127.0
 _QMAX4 = 7.0
 _GROUP4 = 128  # int4 scale-group width (one TPU lane tile of columns)
-
-
-def _blob_specs(cfg: ModelConfig, blob_id: int) -> List[Spec]:
-    return (head_param_specs(cfg) if blob_id == head_blob_id(cfg)
-            else layer_param_specs(cfg))
 
 
 def _rows_cols(shape: Tuple[int, ...]) -> Tuple[int, int]:
@@ -425,7 +421,7 @@ def codec_bench(cfg: Optional[ModelConfig] = None, blob_id: int = 0,
             "decode_device_gbps": 0.0,
         }
         if device:
-            specs = tuple(layer_param_specs(cfg))
+            specs = tuple(_blob_specs(cfg, blob_id))
             dt_name = np.dtype(cfg.dtype).name
             base = ENTROPY_CODECS.get(codec, codec)
             fn = device_decode_jit(base)
@@ -548,17 +544,12 @@ def host_unwrap(codec: str, data) -> Tuple[str, Any]:
 def stacked_from_blobs_host(
     cfg: ModelConfig, blobs: Dict[int, Any], layer_ids: Sequence[int],
     codec: str,
-) -> Dict[str, np.ndarray]:
-    """Host path: stacked layer params from wire blobs under ``codec``."""
-    if codec == "raw":
-        return serde.stacked_from_blobs(cfg, blobs, layer_ids)
-    per_layer = [
-        decode_blob_host(cfg, lid, blobs[lid], codec) for lid in layer_ids
-    ]
-    return {
-        name: np.stack([lp[name] for lp in per_layer])
-        for name, _ in layer_param_specs(cfg)
-    }
+) -> Dict[str, Any]:
+    """Host path: stacked layer params from wire blobs under ``codec``,
+    by kind of layer as the family holds them."""
+    return family.stack(
+        cfg, layer_ids,
+        lambda lid: decode_blob_host(cfg, lid, blobs[lid], codec), np.stack)
 
 
 def head_from_blob_host(cfg: ModelConfig, data, codec: str):
@@ -568,15 +559,22 @@ def head_from_blob_host(cfg: ModelConfig, data, codec: str):
 
 def stacked_from_device(
     cfg: ModelConfig, blob_arrays: Sequence[Any], codec: str,
-    donate: bool = False,
+    donate: bool = False, layer_ids: Optional[Sequence[int]] = None,
 ) -> Dict[str, Any]:
-    """Device path: stacked layer params from HBM wire blobs.
-    ``donate``: consume the wire blobs in place (the caller must drop its
-    own references — they are deleted after this call)."""
-    return device_decode_jit(codec, donate)(
-        tuple(blob_arrays), tuple(layer_param_specs(cfg)),
-        np.dtype(cfg.dtype).name,
-    )
+    """Device path: stacked layer params from the HBM wire blobs of
+    ``layer_ids`` (default: layers ``0 ..``), one decode program for each
+    kind of layer among them.  ``donate``: consume the wire blobs in
+    place (the caller must drop its own references — they are deleted
+    after this call)."""
+    if layer_ids is None:
+        layer_ids = range(len(blob_arrays))
+    arrays = dict(zip(layer_ids, blob_arrays))
+    decode = device_decode_jit(codec, donate)
+    dt_name = np.dtype(cfg.dtype).name
+    return family.of_kinds(cfg, {
+        kind: decode(tuple(arrays[lid] for lid in ids),
+                     tuple(layer_param_specs(cfg, ids[0])), dt_name)
+        for kind, ids in family.group(cfg, layer_ids).items()})
 
 
 def head_from_device(cfg: ModelConfig, blob_u8, codec: str,

@@ -10,8 +10,9 @@ pytree the jitted forward consumes.
 
 Format (deterministic, self-describing via the ModelConfig):
 - Blob ``i`` for ``0 <= i < n_layers`` is layer ``i``'s weights — each leaf
-  in the fixed ``layer_param_specs`` order, as raw C-order bytes of
-  ``cfg.dtype``.
+  in the fixed ``layer_param_specs`` order of ITS kind of layer (a
+  family's layers need not be alike: ``blob_specs``), as raw C-order
+  bytes of ``cfg.dtype``.
 - Blob ``head_blob_id(cfg) == n_layers`` holds the non-layer params in
   ``head_param_specs`` order (Llama: ``embed``, ``ln_f``, ``lm_head``),
   same encoding.
@@ -28,7 +29,7 @@ Two decode paths, bit-identical by construction (and by test):
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, List, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -40,10 +41,11 @@ from . import family
 from .llama import ModelConfig, Spec
 
 
-def layer_param_specs(cfg) -> List[Spec]:
-    """(name, shape) of one layer's leaves, in canonical blob order: the
-    configuration's family says (``models/family.py``)."""
-    return family.of(cfg).layer_param_specs(cfg)
+def layer_param_specs(cfg, layer_id: Optional[int] = None) -> List[Spec]:
+    """(name, shape) of layer ``layer_id``'s leaves, in canonical blob
+    order: the configuration's family says (``models/family.py``).  Where
+    every layer is alike the id may be left out."""
+    return family.layer_param_specs(cfg, layer_id)
 
 
 def head_param_specs(cfg) -> List[Spec]:
@@ -56,11 +58,25 @@ def head_blob_id(cfg: ModelConfig) -> int:
     return cfg.n_layers
 
 
+def blob_specs(cfg, blob_id: int) -> List[Spec]:
+    """(name, shape) of blob ``blob_id``'s leaves: a blob's leaves depend
+    on its id (the head blob's are the head's, a layer blob's those of
+    its kind of layer)."""
+    return (head_param_specs(cfg) if blob_id == head_blob_id(cfg)
+            else layer_param_specs(cfg, blob_id))
+
+
+def blob_kind(cfg, blob_id: int) -> str:
+    """``"head"`` or the blob's kind of layer (``family.layer_kinds``)."""
+    return ("head" if blob_id == head_blob_id(cfg)
+            else family.layer_kinds(cfg)[blob_id])
+
+
 def blob_nbytes(cfg: ModelConfig, blob_id: int) -> int:
-    """Exact byte size of a blob (== cfg.layer_nbytes() for layer blobs)."""
-    specs = (head_param_specs(cfg) if blob_id == head_blob_id(cfg)
-             else layer_param_specs(cfg))
-    return family.spec_nbytes(specs, cfg.dtype)
+    """Exact byte size of a blob: the sum over ITS leaves (for a family
+    whose layers are all alike, ``cfg.layer_nbytes()`` for every layer
+    blob)."""
+    return family.spec_nbytes(blob_specs(cfg, blob_id), cfg.dtype)
 
 
 def _encode(leaves: Sequence[np.ndarray]) -> bytes:
@@ -69,11 +85,12 @@ def _encode(leaves: Sequence[np.ndarray]) -> bytes:
 
 def blobs_from_params(cfg: ModelConfig, params: Dict[str, Any]) -> Dict[int, bytes]:
     """Serialize a full params pytree into its dissemination blobs."""
-    layers = jax.device_get(params["layers"])
+    stacks = family.by_kind(cfg, jax.device_get(params["layers"]))
     blobs: Dict[int, bytes] = {}
-    specs = layer_param_specs(cfg)
-    for i in range(cfg.n_layers):
-        blobs[i] = _encode([np.asarray(layers[name][i]) for name, _ in specs])
+    for kind, ids in family.group(cfg).items():
+        for at, lid in enumerate(ids):
+            blobs[lid] = _encode([np.asarray(stacks[kind][name][at])
+                                  for name, _ in layer_param_specs(cfg, lid)])
     head = {name: np.asarray(jax.device_get(params[name]))
             for name, _ in head_param_specs(cfg)}
     blobs[head_blob_id(cfg)] = _encode(
@@ -110,13 +127,8 @@ def params_from_blobs(
     missing = [i for i in range(cfg.n_layers + 1) if i not in blobs]
     if missing:
         raise ValueError(f"missing blobs for full model: {missing}")
-    specs = layer_param_specs(cfg)
-    per_layer = [_split_blob(cfg, blobs[i], specs) for i in range(cfg.n_layers)]
-    stacked = {
-        name: np.stack([lp[name] for lp in per_layer]) for name, _ in specs
-    }
-    head = _split_blob(cfg, blobs[head_blob_id(cfg)], head_param_specs(cfg))
-    return {**head, "layers": stacked}
+    return {**head_from_blob(cfg, blobs[head_blob_id(cfg)]),
+            "layers": stacked_from_blobs(cfg, blobs, range(cfg.n_layers))}
 
 
 def head_from_blob(cfg: ModelConfig, data) -> Dict[str, np.ndarray]:
@@ -127,12 +139,14 @@ def head_from_blob(cfg: ModelConfig, data) -> Dict[str, np.ndarray]:
 
 def stacked_from_blobs(
     cfg: ModelConfig, blobs: Dict[int, Any], layer_ids: Sequence[int]
-) -> Dict[str, np.ndarray]:
+) -> Dict[str, Any]:
     """Host path: stacked params for a *contiguous subset* of layers — a
-    pipeline stage's slice of the model."""
-    specs = layer_param_specs(cfg)
-    per_layer = [_split_blob(cfg, blobs[i], specs) for i in layer_ids]
-    return {name: np.stack([lp[name] for lp in per_layer]) for name, _ in specs}
+    pipeline stage's slice of the model — stacked by kind of layer, as
+    the family holds them (``family.stack``)."""
+    return family.stack(
+        cfg, layer_ids,
+        lambda lid: _split_blob(cfg, blobs[lid], layer_param_specs(cfg, lid)),
+        np.stack)
 
 
 def seeded_blob(cfg: ModelConfig, blob_id: int, seed: int = 0) -> bytes:
@@ -154,9 +168,9 @@ def seeded_blob(cfg: ModelConfig, blob_id: int, seed: int = 0) -> bytes:
         return _encode(leaves)
     if not 0 <= blob_id < cfg.n_layers:
         raise ValueError(f"blob {blob_id} out of range for {cfg.name}")
-    p = fam.init_layer_params(cfg, layer_keys[blob_id])
+    p = family.init_layer_params(cfg, layer_keys[blob_id], blob_id)
     return _encode([np.asarray(jax.device_get(p[name]))
-                    for name, _ in layer_param_specs(cfg)])
+                    for name, _ in layer_param_specs(cfg, blob_id)])
 
 
 # ------------------------------------------------------------- device path

@@ -317,9 +317,7 @@ def _decode_gathered(wire: bytes, gathered_dev, total: int, codec: str,
     from ..utils import trace
 
     cfg, blob_id = decode
-    specs = tuple(serde.head_param_specs(cfg)
-                  if blob_id == serde.head_blob_id(cfg)
-                  else serde.layer_param_specs(cfg))
+    specs = tuple(serde.blob_specs(cfg, blob_id))
     dt_name = np.dtype(cfg.dtype).name
     if not codec:
         codec = "raw"

@@ -74,11 +74,14 @@ def blob_donate_ok(src) -> bool:
 
 
 def _stage_forward_jitted():
-    """The stage boot's forward (a scan of layer_apply over the stacked
-    stage params), jitted once per process.  The dummy activation input
-    is DONATED (it is boot-local and dead after the call) so XLA can run
-    the scan's carry in place; the stacked params are NOT — they are the
-    boot's product (``BootResult.params``) and must stay resident."""
+    """The stage boot's forward (``llama.apply_layers`` over the stacked
+    stage params: a scan for each run of one kind of layer), jitted once
+    per process.  The dummy activation input is DONATED (it is boot-local
+    and dead after the call) so XLA can run the scan's carry in place;
+    the stacked params are NOT — they are the boot's product
+    (``BootResult.params``) and must stay resident.  ``layer_ids`` (a
+    tuple, static) says which layers the stage holds: their kinds are the
+    family's to tell."""
     global _stage_fwd
     with _stage_fwd_lock:
         if _stage_fwd is None:
@@ -87,19 +90,13 @@ def _stage_forward_jitted():
             import jax
             import jax.numpy as jnp
 
-            from ..models import family
+            from ..models.llama import apply_layers
 
-            @functools.partial(jax.jit, static_argnums=(2,),
+            @functools.partial(jax.jit, static_argnums=(2, 3),
                                donate_argnums=(1,))
-            def stage_forward(stacked, x, cfg):
-                layer_apply = family.of(cfg).layer_apply
-                positions = jnp.arange(x.shape[1])
-
-                def body(x, layer_p):
-                    return layer_apply(layer_p, x, positions, cfg), None
-
-                out, _ = jax.lax.scan(body, x, stacked)
-                return out
+            def stage_forward(stacked, x, cfg, layer_ids):
+                return apply_layers(stacked, x, jnp.arange(x.shape[1]), cfg,
+                                    layer_ids)
 
             _stage_fwd = stage_forward
     return _stage_fwd
@@ -207,8 +204,10 @@ def stage_blob_leaves(cfg, blob_id: int, src, codec: str = "raw",
     (non-donated) 1-blob codec jit — callers release consumable blobs by
     dropping references (``blob_donate_ok``), never by ``donate_argnums``
     (a concurrent flow-retransmit reader holding the array would crash
-    on an XLA-deleted buffer); ``span`` (the caller's ``decode.stage``)
-    then learns how the program widens the blob (``fast_bytes``,
+    on an XLA-deleted buffer); the program is the one of the blob's KIND
+    (``serde.blob_specs``: a blob's leaves depend on its id).  ``span``
+    (the caller's ``decode.stage``) learns the ``kind`` and, on this
+    path, how the program widens the blob (``fast_bytes``,
     ``slow_bytes``: ``quant.widen_bytes``).  Host path: numpy decode +
     async ``device_put`` per leaf (under ``sharding`` when given)."""
     import jax
@@ -216,9 +215,9 @@ def stage_blob_leaves(cfg, blob_id: int, src, codec: str = "raw",
 
     from ..models import quant, serde
 
-    head = blob_id == serde.head_blob_id(cfg)
-    specs = tuple(serde.head_param_specs(cfg) if head
-                  else serde.layer_param_specs(cfg))
+    specs = tuple(serde.blob_specs(cfg, blob_id))
+    if span is not None:
+        span.set(kind=serde.blob_kind(cfg, blob_id))
     arr = _device_blob(src)
     data = None
     if arr is not None and codec in quant.ENTROPY_CODECS:
@@ -344,7 +343,7 @@ def boot_from_layers(
     import jax.numpy as jnp
     import numpy as np
 
-    from ..models import quant, serde
+    from ..models import family, quant, serde
     from ..models.llama import forward_jit
 
     t0 = time.monotonic()
@@ -435,13 +434,11 @@ def boot_from_layers(
                     # blobs' device copies).
                     streamed[lid] = stage_blob_leaves(
                         cfg, lid, layers[lid], codec=codec, sharding=sharding)
-                specs = serde.layer_param_specs(cfg)
+                # One stack a kind of layer, each staged leaf taken out
+                # of its blob's dict as it is stacked (``family.stack``).
                 with jax.named_scope("boot.assemble"):
-                    stacked = {
-                        name: jnp.concatenate(
-                            [streamed[lid].pop(name) for lid in layer_ids])
-                        for name, _ in specs
-                    }
+                    stacked = family.stack(cfg, layer_ids, streamed.get,
+                                           jnp.concatenate)
                 # The decoded params exist: blobs whose device copy the boot
                 # may consume are released now (same bookkeeping as the
                 # donated bulk decode — host fallbacks keep serving late
@@ -461,8 +458,8 @@ def boot_from_layers(
                 dev_blobs[lid] is not None for lid in layer_ids):
             donate = all(blob_donate_ok(layers[lid]) for lid in layer_ids)
             stacked = quant.stacked_from_device(
-                cfg, [dev_blobs[lid] for lid in layer_ids], codec, donate=donate
-            )
+                cfg, [dev_blobs[lid] for lid in layer_ids], codec,
+                donate=donate, layer_ids=layer_ids)
             via = "device bitcast" if codec == "raw" else f"device {codec} dequant"
             if donate:
                 for lid in layer_ids:
@@ -478,12 +475,10 @@ def boot_from_layers(
                 )
                 for lid in layer_ids
             }
-            host = quant.stacked_from_blobs_host(cfg, blobs, layer_ids, codec)
-            stacked = {
-                name: jax.device_put(a, sharding) if sharding is not None
-                else jnp.asarray(a)
-                for name, a in host.items()
-            }
+            stacked = jax.tree.map(
+                lambda a: jax.device_put(a, sharding) if sharding is not None
+                else jnp.asarray(a),
+                quant.stacked_from_blobs_host(cfg, blobs, layer_ids, codec))
             via = VIA_HOST_ASSEMBLY
 
         if full:
@@ -503,7 +498,7 @@ def boot_from_layers(
                     for name, a in head.items()
                 }
             params = {**head, "layers": stacked}
-        asm.set(via=via)
+        asm.set(via=via, kinds=len(family.group(cfg, layer_ids)))
     if full:
         if tokens is None:
             tokens = jnp.zeros((1, 16), jnp.int32)
@@ -530,7 +525,7 @@ def boot_from_layers(
     if sharding is not None:
         x = jax.device_put(x, sharding)
     with trace.span("boot.first_forward", node=node_id, kind="stage"):
-        acts = _stage_forward_jitted()(stacked, x, cfg)
+        acts = _stage_forward_jitted()(stacked, x, cfg, tuple(layer_ids))
         jax.block_until_ready(acts)
     dt = time.monotonic() - t0
     log.info("pipeline stage booted from disseminated layers", kind="stage",
@@ -575,7 +570,7 @@ def precompile_boot(
     import jax.numpy as jnp
     import numpy as np
 
-    from ..models import quant, serde
+    from ..models import family, quant, serde
     from ..models.llama import forward_jit
 
     if streamed is None:
@@ -585,7 +580,6 @@ def precompile_boot(
         layer_ids, full = classify_held_blobs(cfg, blob_ids)
     except ValueError:
         return {"compiled": []}  # boot_from_layers would reject this set
-    n = len(layer_ids)
     dt = cfg.dtype
     dt_name = np.dtype(dt).name
 
@@ -635,9 +629,20 @@ def precompile_boot(
 
     compiled = []
     t0 = time.monotonic()
-    layer_specs = tuple(serde.layer_param_specs(cfg))
-    stacked_abs = {name: sds((n, *shape), dt, leaf_sharding)
-                   for name, shape in layer_specs}
+    # One stack, and one set of decode programs, for each KIND of layer
+    # held (a uniform family: one): the programs number with the kinds,
+    # not with the depth.
+    groups = family.group(cfg, layer_ids)
+    kind_specs = {kind: tuple(serde.layer_param_specs(cfg, ids[0]))
+                  for kind, ids in groups.items()}
+    stacked_abs = family.of_kinds(cfg, {
+        kind: {name: sds((len(groups[kind]), *shape), dt, leaf_sharding)
+               for name, shape in specs}
+        for kind, specs in kind_specs.items()})
+
+    def blob_abs(lid):
+        return sds((quant.blob_nbytes_codec(cfg, lid, codec),), jnp.uint8,
+                   dev_sharding)
 
     if device_blobs:
         # The -hbm path decodes HBM-resident wire blobs under these
@@ -649,33 +654,26 @@ def precompile_boot(
         # these devices (blob_donate_ok minus the per-blob host-fallback
         # check, unknowable from shapes alone).
         if streamed:
-            # One plain 1-blob program covers every layer blob; the
-            # head's is warmed below.
+            # One plain 1-blob program covers every layer blob of a
+            # kind (else the first blob of a new kind would compile in
+            # the stager, mid-wire); the head's is warmed below.
             decode = quant.device_decode_jit(codec, donate=False)
-            one = (sds((quant.blob_nbytes_codec(cfg, layer_ids[0], codec),),
-                       jnp.uint8, dev_sharding),)
-            decode.lower(one, layer_specs, dt_name).compile()
-            compiled.append(f"decode[{codec}]x1")
         else:
             mode = env_util.boot_donate_mode()
             donate = (mode == "force"
                       or (mode == "auto"
                           and all(d.platform != "cpu" for d in devs)))
             decode = quant.device_decode_jit(codec, donate)
-            blob_abs = tuple(
-                sds((quant.blob_nbytes_codec(cfg, lid, codec),),
-                    jnp.uint8, dev_sharding)
-                for lid in layer_ids
-            )
-            decode.lower(blob_abs, layer_specs, dt_name).compile()
-            compiled.append(f"decode[{codec}]x{n}")
+        for kind, ids in groups.items():
+            held = ids[:1] if streamed else ids
+            decode.lower(tuple(blob_abs(lid) for lid in held),
+                         kind_specs[kind], dt_name).compile()
+            compiled.append(f"decode[{codec}]x{len(held)}"
+                            + ("" if len(groups) == 1 else f"/{kind}"))
         if full:
-            head_abs = (sds(
-                (quant.blob_nbytes_codec(cfg, head_id, codec),),
-                jnp.uint8, dev_sharding),)
             decode.lower(
-                head_abs, tuple(serde.head_param_specs(cfg)), dt_name
-            ).compile()
+                (blob_abs(head_id),), tuple(serde.head_param_specs(cfg)),
+                dt_name).compile()
             compiled.append(f"decode[{codec}]head")
 
     if full:
@@ -687,7 +685,8 @@ def precompile_boot(
         compiled.append("forward")
     else:
         x_abs = sds((1, 16, cfg.d_model), dt, x_sharding)
-        _stage_forward_jitted().lower(stacked_abs, x_abs, cfg).compile()
+        _stage_forward_jitted().lower(stacked_abs, x_abs, cfg,
+                                      tuple(layer_ids)).compile()
         compiled.append("stage_forward")
     return {"compiled": compiled,
             "compile_s": round(time.monotonic() - t0, 2),

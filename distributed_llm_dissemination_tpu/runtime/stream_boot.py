@@ -15,14 +15,16 @@ a dedicated worker thread:
 
 - **device path** (``-hbm``): the HBM-resident wire blob is decoded
   per-blob under the same codec jits the bulk boot uses, 1-blob
-  programs — one compile (or persistent-cache read) covers every layer,
-  and each decode overlaps the remaining transfers;
+  programs — one compile (or persistent-cache read) covers every layer
+  of a kind (``models/family.py``; ``boot.precompile_boot`` warms each
+  kind's), and each decode overlaps the remaining transfers;
 - **host path**: the blob is decoded on host (numpy views) and each
   leaf ``device_put`` — asynchronous, so the host→device DMA of layer k
   rides under the receive of layer k+1.
 
 ``boot_from_layers`` then assembles the staged leaves with one
-device-local concatenate per leaf (HBM-bandwidth work) — bit-identical
+device-local concatenate per leaf of each kind of layer (HBM-bandwidth
+work) — bit-identical
 to the bulk assembly regardless of COMPLETION ORDER, because each blob
 decodes independently and the concat is in layer-id order.
 

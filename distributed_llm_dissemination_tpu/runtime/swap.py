@@ -365,15 +365,18 @@ class SwapController:
             rec["params"] = params
 
     def _assemble(self, per_slot: dict, head_leaves: dict):
-        """The serving tree ``models.generate.generate`` consumes:
-        stacked layer leaves [L, ...] + the head."""
+        """The serving tree ``models.generate.generate`` consumes: the
+        layers' leaves stacked by kind of layer as the family holds them
+        (``family.stack``; [L, ...] where every layer is alike) + the
+        head."""
         import jax.numpy as jnp
 
-        n = self.r.boot_cfg.n_layers
-        names = list(per_slot[0])
-        layers = {name: jnp.stack([jnp.asarray(per_slot[i][name])
-                                   for i in range(n)])
-                  for name in names}
+        from ..models import family
+
+        cfg = self.r.boot_cfg
+        layers = family.stack(
+            cfg, range(cfg.n_layers), lambda i: dict(per_slot[i]),
+            lambda leaves: jnp.stack([jnp.asarray(a) for a in leaves]))
         return {**{name: jnp.asarray(a) for name, a in head_leaves.items()},
                 "layers": layers}
 

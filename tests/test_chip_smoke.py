@@ -81,6 +81,28 @@ def test_smoke_drives_the_main_path_and_keeps_the_parent_off_jax(
     assert r["versions"]["jax"]
 
 
+@pytest.mark.timeout(300)
+def test_smoke_drives_a_family_whose_layers_are_of_several_kinds(
+        tmp_path_factory):
+    """The tiny third family beside Llama: five blobs of four sizes over
+    TCP to one destination that stages each by its kind, boots and
+    serves what a second process's ``generate`` gives on the same blobs;
+    the four-chip phase is ``run_pod``'s, which refuses the family."""
+    r = _run_smoke_in_child(
+        "tiny-lfm2", "cpu", timeout=240,
+        cache_dir=str(tmp_path_factory.mktemp("smoke_lfm2_cache")))
+    assert r["ok"] is False and "kernel check failed" in r["error"]  # the CPU
+    assert len(r["blobs"]) == 5
+    assert len({b["bytes"] for b in r["blobs"].values()}) == 4
+    assert all(b["location"] == "HBM" for b in r["blobs"].values())
+    assert r["boot"]["kind"] == "full"
+    assert "host assembly" not in r["boot"]["via"]
+    for req in r["requests"]:
+        assert req["agree"] and req["tokens"] == req["reference_tokens"]
+    assert r["reference"]["ok"], r["reference"]
+    assert "cannot run 'tiny-lfm2' of the lfm2 family" in r["pod_refusal"]
+
+
 def test_smoke_second_process_runs_from_the_compile_cache(tiny_cpu_smoke):
     cache = tiny_cpu_smoke["compile_cache"]
     second = cache["second_process"]

@@ -74,11 +74,18 @@ def test_an_unknown_boot_model_lists_the_names_of_every_family():
 
 @pytest.mark.parametrize("name", family.known())
 def test_layer_nbytes_is_the_sum_over_the_familys_specs(name):
+    """A blob's bytes are the sum over the leaves of ITS layer; where
+    every layer is alike that is ``cfg.layer_nbytes()`` for each."""
     cfg = family.config(name)
-    want = sum(int(np.prod(shape)) for _, shape
-               in serde.layer_param_specs(cfg)) * np.dtype(cfg.dtype).itemsize
-    assert cfg.layer_nbytes() == want == serde.blob_nbytes(cfg, 0)
-    assert quant.blob_nbytes_codec(cfg, 0, "raw") == want
+    item = np.dtype(cfg.dtype).itemsize
+    for b in range(cfg.n_layers):
+        want = sum(int(np.prod(shape)) for _, shape
+                   in serde.layer_param_specs(cfg, b)) * item
+        assert serde.blob_nbytes(cfg, b) == want
+        assert quant.blob_nbytes_codec(cfg, b, "raw") == want
+    if set(family.layer_kinds(cfg)) == {family.ONE_KIND}:
+        assert serde.layer_param_specs(cfg) == serde.layer_param_specs(cfg, 0)
+        assert cfg.layer_nbytes() == serde.blob_nbytes(cfg, 0)
 
 
 @pytest.mark.parametrize("name", sorted(PARENT_BLOBS))
