@@ -25,7 +25,8 @@ Mapping:
   ``moe_slots`` / ``moe_held`` / ``moe_touched`` and the
   ``fabric.publish`` spans' ``bytes`` / ``host_copy_bytes`` / ``pieces``
   are added up and printed on stderr, and beside them the counters
-  ``wire.buf.reused_bytes`` / ``wire.buf.fresh_bytes`` of the
+  ``wire.buf.reused_bytes`` / ``wire.buf.fresh_bytes`` and
+  ``wire.pace.job_bytes`` / ``wire.pace.wait_ms`` of the
   ``"span counters"`` records (give one round's logs for the round's
   sum).
 
@@ -285,20 +286,34 @@ def routed_slot_totals(events: List[dict]) -> dict:
                          ("moe_slots", "moe_held", "moe_touched"))
 
 
-def recv_buffer_totals(records: Iterable[dict]) -> dict:
-    """Where the logs' receivers got their reassembly buffers: the
-    ``"span counters"`` records' ``wire.buf.reused_bytes`` (leased from
-    a pool slab that lay free, already faulted) and
-    ``wire.buf.fresh_bytes`` (mapped anew) added up, over one round's
-    logs the round's sums (``utils/buffers.py``).  Empty when no log
-    carries either."""
-    totals = {"reused_bytes": 0, "fresh_bytes": 0}
+def _counter_totals(records: Iterable[dict], prefix: str, keys) -> dict:
+    """The ``"span counters"`` records' counters ``<prefix><key>`` added
+    up, over one round's logs the round's sums.  Empty when no log
+    carries any of them."""
+    totals = dict.fromkeys(keys, 0)
     for rec in records:
         if rec.get("message") == "span counters":
             for key in totals:
                 totals[key] += (rec.get("counters") or {}).get(
-                    f"wire.buf.{key}", 0)
+                    prefix + key, 0)
     return totals if any(totals.values()) else {}
+
+
+def recv_buffer_totals(records: Iterable[dict]) -> dict:
+    """Where the logs' receivers got their reassembly buffers:
+    ``wire.buf.reused_bytes`` (leased from a pool slab that lay free,
+    already faulted) and ``wire.buf.fresh_bytes`` (mapped anew)
+    (``utils/buffers.py``)."""
+    return _counter_totals(records, "wire.buf.",
+                           ("reused_bytes", "fresh_bytes"))
+
+
+def job_pace_totals(records: Iterable[dict]) -> dict:
+    """What the logs' senders put through a flow job's pacer:
+    ``wire.pace.job_bytes`` and the milliseconds their threads slept in
+    one, ``wire.pace.wait_ms`` — 0 while every job ran behind its plan
+    (``utils/rate.JobPacer``)."""
+    return _counter_totals(records, "wire.pace.", ("job_bytes", "wait_ms"))
 
 
 def to_trace_events(records: Iterable[dict],
@@ -530,6 +545,11 @@ def main(argv: list[str] | None = None) -> int:
     if leased:
         print("wire.buf leased {reused_bytes} B from slabs that lay free, "
               "mapped {fresh_bytes} B anew".format(**leased),
+              file=sys.stderr)
+    paced = job_pace_totals(records)
+    if paced:
+        print("wire.pace held {job_bytes} B to their jobs' plans, "
+              "sending threads slept {wait_ms} ms in it".format(**paced),
               file=sys.stderr)
     widened = decode_widen_totals(events)
     if widened:

@@ -25,7 +25,7 @@ from ..core.types import (
 from ..transport.messages import ClientReqMsg, FlowRetransmitMsg, LayerMsg
 from ..utils import telemetry, threads, trace
 from ..utils.logging import log
-from ..utils.rate import TokenBucket
+from ..utils.rate import JobPacer, TokenBucket
 from .node import Node
 
 # Flow jobs are sent as sub-fragments of at most this many bytes (the
@@ -600,7 +600,10 @@ def handle_flow_retransmit(
     codecs=None,
 ) -> None:
     """Execute one flow job: send ``[offset, offset+data_size)`` of a layer
-    to the dest at the commanded rate (node.go:1592-1643).
+    to the dest at the commanded rate (node.go:1592-1643).  The rate is
+    the plan's budget for the JOB: one ``JobPacer`` is made here and
+    every fragment carries it, so the transport's stripes and fragments
+    share it (docs/transport.md "Pacing").
 
     ``revokes``: the sender's preemption-revoke registry.  A queued job
     whose (job, dest, layer) the leader revoked before it started is
@@ -657,6 +660,11 @@ def handle_flow_retransmit(
                              layer=msg.layer_id, job=msg.job_id,
                              codec=codec, bytes=msg.data_size)
         frag_bytes = _fragment_bytes(msg.rate)
+        # The job's ONE pacer: every fragment carries it and every
+        # stripe writes through it, so the job is held to its plan and a
+        # piece that queued for a worker loses no budget.
+        pacer = (JobPacer(msg.rate, span_id=span, job=msg.job_id)
+                 if msg.rate > 0 else None)
         sent = 0
         while sent < msg.data_size:
             if (sent > 0 and revokes is not None
@@ -674,7 +682,8 @@ def handle_flow_retransmit(
             node.transport.send(
                 msg.dest_id,
                 LayerMsg(node.my_id, msg.layer_id, partial, view.data_size,
-                         job_id=msg.job_id, codec=codec, span_id=span),
+                         job_id=msg.job_id, codec=codec, span_id=span,
+                         pacer=pacer),
             )
             sent += n
     elif layer.meta.location == LayerLocation.CLIENT:

@@ -529,6 +529,12 @@ class LayerMsg:
     # receiving transport finished landing this frame — the start of
     # its ``wire.queue`` span (0 = not stamped).
     landed_mono: float = dataclasses.field(default=0.0, compare=False)
+    # Local only, never on the wire: the pacer of the flow job this
+    # fragment belongs to (``utils/rate.JobPacer``), shared by all of
+    # the job's fragments and stripes.  None = no plan budget: the
+    # transport paces the message alone at ``layer_src.meta.limit_rate``.
+    pacer: object = dataclasses.field(default=None, compare=False,
+                                      repr=False)
 
     msg_type = MsgType.LAYER
 

@@ -44,7 +44,7 @@ from ..utils import integrity, telemetry, threads, trace
 from ..utils.backoff import Backoff
 from ..utils.buffers import alloc_recv_buffer
 from ..utils.logging import log
-from ..utils.rate import PacedWriter
+from ..utils.rate import JobPacer, PacedWriter
 from .base import AddrRegistry, Transport
 from .messages import (
     LayerHeader,
@@ -78,18 +78,20 @@ _SEND_RETRIES = max(1, int(os.environ.get("DLD_TCP_SEND_RETRIES", "3")))
 # absolute offset (wire-compatible — see LayerHeader.stripe_*), so a
 # receiver reassembles striped and un-striped frames through one path.
 # STRIPE_MIN keeps every stripe big enough that TCP slow-start and framing
-# overhead stay noise.  Rate-limited sends never stripe (N paced streams
-# would multiply the commanded rate).
+# overhead stay noise.
 STRIPE_THRESHOLD = int(os.environ.get("DLD_TCP_STRIPE_THRESHOLD",
                                       str(8 << 20)))
 STRIPE_COUNT = max(1, int(os.environ.get("DLD_TCP_STRIPES", "4")))
 STRIPE_MIN = 2 << 20
 # Rate-limited sends stripe only when the commanded rate is at least this
 # (1 GB/s): past it the rate is a capacity BUDGET (an ICI/NIC line rate
-# the flow solver allotted), which stripes split proportionally so the
-# aggregate still honors it.  Below it the rate is a scarcity model (a
-# slow source being simulated) whose burst semantics the tests depend
-# on — those never stripe.
+# the flow solver allotted), and all stripes write through ONE pacer —
+# their flow job's (``LayerMsg.pacer``, shared with the job's other
+# fragments; ``utils/rate.JobPacer``), or one made for a message that
+# brings none — so the aggregate honors the budget whichever stripe
+# ran late.  Below it the rate is a scarcity model (a slow source being
+# simulated) whose burst semantics the tests depend on — those never
+# stripe and pace per message (``PacedWriter``).
 STRIPE_PACED_MIN_RATE = int(os.environ.get("DLD_TCP_STRIPE_MIN_RATE",
                                            str(10 ** 9)))
 # Reassembly groups for striped transfers to a receiver WITHOUT a
@@ -1320,22 +1322,17 @@ class TcpTransport(Transport):
         tid = f"{next(self._stripe_tid):x}"
         n = len(spans)
         errors: List[BaseException] = []
+        # One budget for all stripes: the flow job's pacer, or — for a
+        # paced message that brings none — one of its own.
+        pacer = message.pacer
+        if pacer is None and src.meta.limit_rate > 0:
+            pacer = JobPacer(src.meta.limit_rate, span_id=message.span_id,
+                             job=message.job_id)
 
         def send_stripe(idx: int, rel_off: int, size: int) -> None:
-            meta = src.meta
-            if meta.limit_rate > 0:
-                # Split the commanded budget proportionally: N paced
-                # stripes together still flow at (almost exactly) the
-                # allotted rate.
-                meta = LayerMeta(
-                    location=meta.location,
-                    limit_rate=max(1, meta.limit_rate * size
-                                   // src.data_size),
-                    source_type=meta.source_type,
-                )
             sub = LayerSrc(
                 inmem_data=src.inmem_data, fp=src.fp, data_size=size,
-                offset=src.offset + rel_off, meta=meta,
+                offset=src.offset + rel_off, meta=src.meta,
             )
             stripe = {"idx": idx, "n": n, "off": rel_off,
                       "span": src.data_size, "tid": tid}
@@ -1346,7 +1343,8 @@ class TcpTransport(Transport):
                              message.total_size, job_id=message.job_id,
                              shard=message.shard, codec=message.codec,
                              span_id=message.span_id,
-                             span_parent=message.span_parent),
+                             span_parent=message.span_parent,
+                             pacer=pacer),
                     stripe=stripe)
             except BaseException as e:  # noqa: BLE001 — re-raised below
                 errors.append(e)
@@ -1448,7 +1446,12 @@ class TcpTransport(Transport):
             "payload": header.to_payload(),
         }
         if data is not None:
-            if src.meta.limit_rate > 0:
+            if message.pacer is not None:
+                # A plan budget: the job's one pacer, shared with every
+                # other fragment and stripe of the job.
+                _send_frame(sock, envelope)
+                message.pacer.write(sock.sendall, data)
+            elif src.meta.limit_rate > 0:
                 _send_frame(sock, envelope)
                 log.debug(
                     "sending with limit",

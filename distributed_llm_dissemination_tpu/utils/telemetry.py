@@ -102,7 +102,7 @@ SPAN_PHASES: Tuple[str, ...] = (
 # to a name here.
 SPAN_NAMES: Tuple[str, ...] = (
     "plan.solve", "plan.dispatch",
-    "wire.recv", "wire.crc", "wire.digest", "wire.queue",
+    "wire.recv", "wire.crc", "wire.digest", "wire.queue", "wire.pace",
     "ingest.write", "ingest.finalize", "ingest.finalize.wait",
     "ingest.finalize.splice", "ingest.finalize.ready", "ingest.ack",
     "decode.stage",

@@ -453,7 +453,7 @@ def test_striped_disk_source(small_stripes, tmp_path):
 def test_striped_rate_limited_low_rate_does_not_stripe(small_stripes):
     """Slow rate-limited sends keep their single paced stream (striping
     would change the modeled burst semantics); only budget-scale rates
-    (>= STRIPE_PACED_MIN_RATE) stripe, with the budget split."""
+    (>= STRIPE_PACED_MIN_RATE) stripe, all stripes through one pacer."""
     ts = make_transports("tcp", 2)
     try:
         stripes_seen = []
