@@ -286,6 +286,17 @@ def routed_slot_totals(events: List[dict]) -> dict:
                          ("moe_slots", "moe_held", "moe_touched"))
 
 
+def draft_totals(events: List[dict]) -> dict:
+    """How the logs' served requests were decoded by draft and verify
+    (``models/generate.py``): the ``serve.generate`` slices'
+    ``decode_steps``, ``mtp_drafted`` (drafts put to the stack) and
+    ``mtp_accepted`` (drafts whose second token was emitted) added up, so
+    that steps + accepted + one first token a request are the tokens
+    answered.  Empty for a family without a module that drafts."""
+    return _field_totals(events, "serve.generate",
+                         ("decode_steps", "mtp_drafted", "mtp_accepted"))
+
+
 def _counter_totals(records: Iterable[dict], prefix: str, keys) -> dict:
     """The ``"span counters"`` records' counters ``<prefix><key>`` added
     up, over one round's logs the round's sums.  Empty when no log
@@ -567,6 +578,11 @@ def main(argv: list[str] | None = None) -> int:
               "to experts held here, over {moe_touched} expert-reads a "
               "gathered dispatch would make ({spans} spans)"
               .format(**routed), file=sys.stderr)
+    drafted = draft_totals(events)
+    if drafted:
+        print("serve.generate decoded in {decode_steps} steps, "
+              "{mtp_accepted} of {mtp_drafted} drafts accepted "
+              "({spans} spans)".format(**drafted), file=sys.stderr)
     published = fabric_publish_totals(events)
     if published:
         print("fabric.publish put {bytes} B on the fabric in {pieces} "

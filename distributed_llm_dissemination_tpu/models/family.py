@@ -38,6 +38,17 @@ hook is the case of one kind, and its tree stays what it always was —
 ``{leaf: [n_layers, ...]}``, every leaf stacked over the layers
 (``by_kind`` / ``of_kinds`` turn one view into the other).
 
+**Blobs beside the stack.**  A kind may be delivered as a layer blob and
+be no layer of the stack: a module the forward does not pass through (a
+multi-token-prediction module, which drafts in ``generate``).  The
+family names such kinds in ``side_kinds(cfg)``; their layer ids come
+after the stack's.  Everything that handles BLOBS treats the kind as one
+more (its leaves, its decode program, its stack of parameters and of
+serving state: ``layer_kinds``, ``group``, ``stack``); what walks the
+STACK leaves it out (``runs``, ``run_slices``).  A family that has such
+a module and can draft with it says so in ``drafts(cfg)`` and answers
+``draft(params, h, nxt, positions, cache, cfg, at)`` (``drafter``).
+
 ``serde`` and ``quant`` (blob layout), ``llama.forward`` (a scan over
 each run of stacked layers), ``generate`` (prefill and decode) and
 ``runtime/boot.py`` ask here; nothing else branches on a family.  The
@@ -54,7 +65,7 @@ import numpy as np
 
 # family name -> module beside this file
 FAMILIES: Dict[str, str] = {"llama": ".llama", "longcat": ".longcat",
-                            "lfm2": ".lfm2"}
+                            "lfm2": ".lfm2", "joyai": ".joyai"}
 # The one kind of a family whose layers are all alike.
 ONE_KIND = "layer"
 
@@ -118,6 +129,22 @@ def layer_kinds(cfg) -> Tuple[str, ...]:
     return (ONE_KIND,) * cfg.n_layers
 
 
+def side_kinds(cfg) -> Tuple[str, ...]:
+    """The kinds that are delivered as layer blobs and are no layers of
+    the stack (none, unless the family says)."""
+    hook = getattr(of(cfg), "side_kinds", None)
+    return tuple(hook(cfg)) if hook else ()
+
+
+def drafter(cfg) -> Optional[Callable]:
+    """The family's ``draft`` where ``cfg`` holds a module that drafts
+    (``generate`` then decodes by draft and verify at temperature 0),
+    else None."""
+    fam = of(cfg)
+    drafts = getattr(fam, "drafts", None)
+    return fam.draft if drafts is not None and drafts(cfg) else None
+
+
 def _ids(cfg, layer_ids: Optional[Sequence[int]]) -> Sequence[int]:
     return range(cfg.n_layers) if layer_ids is None else layer_ids
 
@@ -161,12 +188,15 @@ def runs(cfg, layer_ids: Optional[Sequence[int]] = None
          ) -> List[Tuple[str, int, int]]:
     """The stack in order as runs of one kind: ``(kind, start, stop)``,
     ``start:stop`` the run's place in its kind's stack.  A uniform family
-    is one run."""
+    is one run.  A blob beside the stack (``side_kinds``) is in no run."""
     kinds = layer_kinds(cfg)
+    aside = side_kinds(cfg)
     seen: Dict[str, int] = {}
     out: List[Tuple[str, int, int]] = []
     for lid in _ids(cfg, layer_ids):
         kind = kinds[lid]
+        if kind in aside:
+            continue
         at = seen.get(kind, 0)
         seen[kind] = at + 1
         if out and out[-1][0] == kind:

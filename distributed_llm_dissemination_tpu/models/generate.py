@@ -25,6 +25,24 @@ vectors in its own module); the loops here are the same for every
 family, and what a family's blocks count on the way (routing
 slots, say) comes back beside the tokens in the same transfer
 (``generate_counted``).
+
+**Draft and verify.**  A family that holds a module which drafts
+(``family.drafter``: a multi-token-prediction module) decodes, at
+temperature 0, by steps that yield one token or two: the step forwards
+the committed token and the module's draft of the next — two positions —
+through the stack and the cache; the first position's argmax IS the next
+token; where it equals the draft the second position's argmax is emitted
+too; the module then drafts again from the last accepted position.  A
+rejected draft leaves a stale row in the cache (the stack's and the
+module's) that the next step overwrites before anything attends it: a
+step writes rows ``pos, pos + 1`` and every query masks the rows past its
+own position.  The tokens are those of the one-token decode (the draft
+decides how many a step yields, never which); the whole decode is one
+jitted ``while_loop`` of static shapes.  Counted beside the family's own:
+``decode_steps``, ``mtp_drafted`` (drafts put to the stack) and
+``mtp_accepted`` (drafts whose second token was emitted), so that
+``decode_steps + mtp_accepted + 1`` is the number of tokens a request was
+answered with.  Sampling (temperature > 0) keeps the one-token decode.
 """
 
 from __future__ import annotations
@@ -73,12 +91,13 @@ def init_cache(cfg, batch: int, max_len: int) -> Cache:
     return family.of(cfg).init_cache(cfg, batch, max_len)
 
 
-def _forward_with_cache(params, tokens, positions, cache, cfg):
-    """Stacked-layer forward that threads the cache; returns (logits for
-    the LAST position, updated cache, the blocks' counters added up over
-    the layers).  One ``lax.scan`` for each run of one kind of layer
-    (``family.run_slices``: a uniform family's one scan over its whole
-    tree), each over the run's part of the parameters and of the state."""
+def _hidden_with_cache(params, tokens, positions, cache, cfg):
+    """Stacked-layer forward that threads the cache; returns (the stack's
+    last hidden state ``[b, s, d]``, updated cache, the blocks' counters
+    added up over the layers).  One ``lax.scan`` for each run of one kind
+    of layer (``family.run_slices``: a uniform family's one scan over its
+    whole tree), each over the run's part of the parameters and of the
+    state."""
     fam = family.of(cfg)
     x = fam.embed(params, tokens, cfg)
 
@@ -100,8 +119,16 @@ def _forward_with_cache(params, tokens, positions, cache, cfg):
         for name, c in counted.items():
             total[name] = (total[name] + c.sum(0) if name in total
                            else c.sum(0))
-    logits = fam.logits(params, x[:, -1:, :], cfg)[:, 0, :]
-    return logits, family.of_kinds(cfg, state), total
+    return x, family.of_kinds(cfg, state), total
+
+
+def _forward_with_cache(params, tokens, positions, cache, cfg):
+    """``_hidden_with_cache`` with the head over the LAST position:
+    returns (its logits, updated cache, counters)."""
+    x, cache, total = _hidden_with_cache(params, tokens, positions, cache,
+                                         cfg)
+    logits = family.of(cfg).logits(params, x[:, -1:, :], cfg)[:, 0, :]
+    return logits, cache, total
 
 
 def _pick(logits, step_key, temperature: float):
@@ -168,6 +195,118 @@ def _decode_step_fn(cfg: ModelConfig, temperature: float):
     return step
 
 
+# ------------------------------------------------------ draft and verify
+
+_DRAFT_COUNTERS = ("decode_steps", "mtp_drafted", "mtp_accepted")
+
+
+def _drafts(cfg, temperature: float) -> bool:
+    """Whether a request decodes by draft and verify: greedy, and the
+    family holds a module that drafts."""
+    return temperature <= 0 and family.drafter(cfg) is not None
+
+
+def _argmax(logits):
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def _add(counted: Counters, *more: Counters) -> Counters:
+    out = dict(counted)
+    for m in more:
+        for name, c in m.items():
+            out[name] = out[name] + c if name in out else c
+    return out
+
+
+@functools.lru_cache(maxsize=32)
+def _draft_prefill_fn(cfg, p: int):
+    """The prompt through the stack AND the module: (first token, the
+    module's draft of the second, cache, counters — every name the decode
+    will add to, so that the two programs' trees agree)."""
+    fam, draft = family.of(cfg), family.drafter(cfg)
+
+    @jax.jit
+    def prefill(params, prompt, cache):
+        with jax.named_scope("generate.prefill"):
+            positions = jnp.arange(p)
+            x, cache, counted = _hidden_with_cache(params, prompt, positions,
+                                                   cache, cfg)
+            first = _argmax(fam.logits(params, x[:, -1:, :], cfg)[:, 0, :])
+            nxt = jnp.concatenate([prompt[:, 1:], first[:, None]], axis=1)
+            logits, cache, more = draft(params, x, nxt, positions, cache,
+                                        cfg, p - 1)
+            zero = {name: jnp.zeros((), jnp.int32)
+                    for name in _DRAFT_COUNTERS}
+            return first, _argmax(logits), cache, _add(counted, more, zero)
+
+    return prefill
+
+
+def _draft_step(params, cache, token, guess, pos, may_accept, cfg):
+    """One step: the committed ``token`` (at ``pos``) and the ``guess`` at
+    the next through the stack, then the module over the same two
+    positions.  Returns (the two positions' argmax ``[b, 2]``, whether
+    the second is emitted too, the next draft, cache, counters).
+    ``may_accept`` is False where a second token would be one too many.
+    With a batch, a step yields two tokens only where every sequence's
+    draft held (the rows of a batch share their positions)."""
+    fam, draft = family.of(cfg), family.drafter(cfg)
+    with jax.named_scope("generate.step"):
+        positions = pos + jnp.arange(2)
+        x, cache, counted = _hidden_with_cache(
+            params, jnp.stack([token, guess], axis=1), positions, cache, cfg)
+        picks = _argmax(fam.logits(params, x, cfg))
+        ok = jnp.all(picks[:, 0] == guess) & may_accept
+        logits, cache, more = draft(params, x, picks, positions, cache, cfg,
+                                    ok.astype(jnp.int32))
+    n = jnp.asarray(token.shape[0], jnp.int32)
+    return picks, ok, _argmax(logits), cache, _add(counted, more, {
+        "decode_steps": jnp.ones((), jnp.int32), "mtp_drafted": n,
+        "mtp_accepted": n * ok.astype(jnp.int32)})
+
+
+@functools.lru_cache(maxsize=32)
+def _draft_decode_fn(cfg, p: int, max_new: int):
+    """The whole decode after the prefill as ONE program: a ``while_loop``
+    of ``_draft_step`` until ``max_new`` tokens stand in the output
+    (between half as many steps as tokens and as many)."""
+
+    @jax.jit
+    def decode(params, cache, first, guess, counted):
+        # one slot past max_new: a step always writes two
+        out = jnp.zeros((first.shape[0], max_new + 1), jnp.int32)
+
+        def step(c):
+            n = c["n"]
+            picks, ok, guess, cache, more = _draft_step(
+                params, c["cache"], c["token"], c["guess"], p + n - 1,
+                n + 1 < max_new, cfg)
+            return {"n": n + 1 + ok.astype(jnp.int32),
+                    "token": jnp.where(ok, picks[:, 1], picks[:, 0]),
+                    "guess": guess, "cache": cache,
+                    # a rejected second token is overwritten by the next
+                    # step's first
+                    "out": jax.lax.dynamic_update_slice(c["out"], picks,
+                                                        (0, n)),
+                    "counted": jax.tree.map(jnp.add, c["counted"], more)}
+
+        c = jax.lax.while_loop(
+            lambda c: c["n"] < max_new, step,
+            {"n": jnp.ones((), jnp.int32), "token": first, "guess": guess,
+             "cache": cache, "out": out.at[:, 0].set(first),
+             "counted": counted})
+        return c["out"][:, :max_new], c["counted"]
+
+    return decode
+
+
+@functools.lru_cache(maxsize=32)
+def _draft_step_fn(cfg):
+    """ONE jitted ``_draft_step`` (the per-token flip path's: position
+    and ``may_accept`` are traced, so every step reuses the program)."""
+    return jax.jit(functools.partial(_draft_step, cfg=cfg))
+
+
 def generate_stepwise(
     params_fn,
     prompt: jax.Array,
@@ -200,6 +339,19 @@ def generate_stepwise(
     b, p = prompt.shape
     cache = init_cache(cfg, b, p + max_new)
     params, _ = params_fn()
+    if _drafts(cfg, temperature):
+        token, guess, cache, _ = _draft_prefill_fn(cfg, p)(params, prompt,
+                                                           cache)
+        out, step = [token], _draft_step_fn(cfg)
+        while len(out) < max_new:
+            params, _ = params_fn()
+            n = len(out)
+            picks, ok, guess, cache, _ = step(
+                params, cache, token, guess, jnp.asarray(p + n - 1, jnp.int32),
+                jnp.asarray(n + 1 < max_new))
+            out += [picks[:, 0], picks[:, 1]] if bool(ok) else [picks[:, 0]]
+            token = out[-1]
+        return jnp.stack(out, axis=1)
     logits, cache, _ = _prefill_fn(cfg, p)(params, prompt, cache)
     keys = (jax.random.split(key, max_new) if key is not None
             else jnp.zeros((max_new, 2), jnp.uint32))
@@ -240,6 +392,13 @@ def generate_counted(
         raise ValueError("sampling needs a PRNG key")
     b, p = prompt.shape
     cache = init_cache(cfg, b, p + max_new)
+    if _drafts(cfg, temperature):
+        first, guess, cache, counted = _draft_prefill_fn(cfg, p)(
+            params, prompt, cache)
+        if max_new == 1:
+            return first[:, None], counted
+        return _draft_decode_fn(cfg, p, max_new)(params, cache, first, guess,
+                                                 counted)
 
     logits, cache, counted = _prefill_fn(cfg, p)(params, prompt, cache)
     keys = (jax.random.split(key, max_new) if key is not None

@@ -62,9 +62,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import longcat
+from . import mla
 from .llama import Spec, rope
-from .longcat import _rms
+from .mla import _rms
 
 HF_ARCHITECTURE = "Lfm2Moe"  # models/hf.py refuses it by name
 
@@ -212,9 +212,9 @@ def _mm(spec: str, x, w):
     PR 31).  Weights are read once either way, so a step that their bytes
     bound costs the same."""
     if w.dtype != jnp.bfloat16:
-        return longcat._mm(spec, x, w)
+        return mla._mm(spec, x, w)
     ins, out = spec.split("->")
-    both = longcat._mm(spec, jnp.concatenate(
+    both = mla._mm(spec, jnp.concatenate(
         _two_terms(x.astype(jnp.float32), w.dtype),
         axis=ins.split(",")[0].index("s")), w)
     first, second = jnp.split(both, 2, axis=out.index("s"))

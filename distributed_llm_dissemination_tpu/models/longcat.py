@@ -43,7 +43,8 @@ Serving goes through a **latent cache**: per position ``ckv`` (after its
 norm and scale) and the rotated ``kr``, one pair per attention sub-block,
 so two per layer.  Prefill and decode up-project the whole cache through
 ``wkv_b`` at every step; ``wkv_b`` is not absorbed into the query and
-output projections.
+output projections.  The attention itself is ``models/mla.py``'s, shared
+with ``models/joyai.py``; this family's part is the two scales.
 """
 
 from __future__ import annotations
@@ -55,8 +56,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import family
+from . import family, mla
 from .llama import Spec
+from .mla import _mm, _rms
 
 HF_ARCHITECTURE = "LongcatFlash"  # models/hf.py refuses it by name
 
@@ -193,89 +195,19 @@ def init_head_params(cfg: LongcatConfig, k_emb: jax.Array,
 # slot moves a whole token's output (PERF.md section 6, PR 27).
 
 
-def _mm(spec: str, x, w):
-    """A product of ``w.dtype`` operands accumulated in float32.  XLA's
-    CPU backend has no bfloat16 x bfloat16 = float32 dot, so off the TPU
-    the rounded operands are widened first: the same products, the same
-    accumulator."""
-    x = x.astype(w.dtype)
-    if w.dtype == jnp.float32:
-        return jnp.einsum(spec, x, w)
-    return jax.lax.platform_dependent(
-        x, w,
-        tpu=lambda x, w: jnp.einsum(spec, x, w,
-                                    preferred_element_type=jnp.float32),
-        default=lambda x, w: jnp.einsum(spec, x.astype(jnp.float32),
-                                        w.astype(jnp.float32)))
-
-
-def _rms(x, w, eps: float, scale: float = 1.0):
-    """``RMS(x)·w·scale`` in float32."""
-    x32 = x.astype(jnp.float32)
-    rms = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return x32 * (rms * scale) * w.astype(jnp.float32)
-
-
-def _rope_pairs(x, positions, theta: float):
-    """Rotary embedding over INTERLEAVED pairs ``(x[2i], x[2i+1])`` with
-    ``theta ** (-i / (rd/2))``; x: [..., seq, heads, rd] float32.  The
-    rotated pairs come back de-interleaved (all first members, then all
-    second): queries and keys go through the same permutation, so every
-    score is the published one, and nothing is interleaved back."""
-    half = x.shape[-1] // 2
-    freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    angles = positions[:, None].astype(jnp.float32) * freqs  # [seq, rd/2]
-    cos = jnp.cos(angles)[:, None, :]
-    sin = jnp.sin(angles)[:, None, :]
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-
-
 def _mla_project(p, i: int, xn, positions, cfg: LongcatConfig):
-    """The normed hidden state to this sub-block's queries ``[b, s, H,
-    nope + rope]`` (scaled, rope part rotated) and to what the latent
-    cache keeps per position, both in ``cfg.dtype`` (they are the next
-    products' operands, with or without a cache): ``ckv [b, s, kv_rank]``
-    (normed, scaled) and the rotated shared ``kr [b, s, rope]``."""
-    b, s, d = xn.shape
-    # (Wqb·cq)·scale = Wqb·(cq·scale): the scale rides the norm's
-    # float32 pass.
-    cq = _rms(_mm("bsd,dr->bsr", xn, p[f"wq_a_{i}"]), p[f"q_norm_{i}"],
-              cfg.norm_eps, np.sqrt(d / cfg.q_rank))
-    q = _mm("bsr,rq->bsq", cq, p[f"wq_b_{i}"]).reshape(
-        b, s, cfg.heads_held, cfg.nope_dim + cfg.rope_dim)
-    q = jnp.concatenate(
-        [q[..., :cfg.nope_dim],
-         _rope_pairs(q[..., cfg.nope_dim:], positions, cfg.rope_theta)], -1)
-    kv = _mm("bsd,dr->bsr", xn, p[f"wkv_a_{i}"])
-    ckv = _rms(kv[..., :cfg.kv_rank], p[f"kv_norm_{i}"], cfg.norm_eps,
-               np.sqrt(d / cfg.kv_rank))
-    kr = _rope_pairs(kv[..., None, cfg.kv_rank:], positions,
-                     cfg.rope_theta)[..., 0, :]
-    return (q.astype(cfg.dtype), ckv.astype(cfg.dtype),
-            kr.astype(cfg.dtype))
+    """Sub-block ``i``'s queries and latent pair (``mla.project``), with
+    this family's two scales and in ``cfg.dtype``."""
+    d = xn.shape[-1]
+    return mla.project(p, xn, positions, cfg, sfx=f"_{i}",
+                       q_scale=np.sqrt(d / cfg.q_rank),
+                       kv_scale=np.sqrt(d / cfg.kv_rank))
 
 
 def _mla_attend(p, i: int, q, ckv, kr, mask, cfg: LongcatConfig):
-    """Queries ``[b, s, H, nope + rope]`` against latent keys ``ckv [b, t,
-    kv_rank]`` / ``kr [b, t, rope]`` (a sequence's own, or the whole
-    cache) under the additive ``mask [s, t]``; the held heads' share of
-    ``Wo·attention``, float32."""
-    b, t, _ = ckv.shape
-    h, nope = cfg.heads_held, cfg.nope_dim
-    up = _mm("btr,rk->btk", ckv, p[f"wkv_b_{i}"]).reshape(
-        b, t, h, nope + cfg.v_dim).astype(q.dtype)
-    scores = (
-        jnp.einsum("bshd,bthd->bhst", q[..., :nope], up[..., :nope],
-                   preferred_element_type=jnp.float32)
-        + jnp.einsum("bshd,btd->bhst", q[..., nope:], kr,
-                     preferred_element_type=jnp.float32)
-    ) / np.sqrt(nope + cfg.rope_dim)
-    probs = jax.nn.softmax(scores + mask, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bhst,bthd->bshd", probs, up[..., nope:],
-                     preferred_element_type=jnp.float32)
-    return _mm("bsq,qd->bsd", out.reshape(b, q.shape[1], h * cfg.v_dim),
-               p[f"wo_{i}"])
+    """Sub-block ``i``'s held heads' share of ``Wo·attention``
+    (``mla.attend``), float32."""
+    return mla.attend(p, q, ckv, kr, mask, cfg, sfx=f"_{i}")
 
 
 def _dense_ffn(p, i: int, xn):

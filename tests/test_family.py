@@ -39,6 +39,39 @@ PARENT_BLOBS = {
 
 # ------------------------------------------------------------- the table
 
+def test_a_kind_beside_the_stack_is_in_no_run_and_keeps_its_program_and_stack():
+    """A family may deliver a blob as a layer that is no layer of the
+    stack (``side_kinds``: JoyAI's prediction module): the runs leave it
+    out — a forward does not read its leaves — and every blob-handling
+    path treats it as one more kind."""
+    from distributed_llm_dissemination_tpu.models import joyai
+    from distributed_llm_dissemination_tpu.runtime import boot
+
+    cfg = joyai.CONFIGS["tiny-joyai"]
+    assert family.side_kinds(cfg) == ("mtp",) and family.side_kinds(TINY) == ()
+    assert [k for k, _, _ in family.runs(cfg)] == ["dense", "moe"]
+    assert family.group(cfg)["mtp"] == [cfg.n_layers - 1]
+    params = llama.init_params(cfg, jax.random.key(0))
+    toks = jnp.arange(8, dtype=jnp.int32)[None]
+    want = np.asarray(llama.forward(params, toks, cfg))
+    other = dict(params, layers=dict(
+        params["layers"], mtp=jax.tree.map(lambda a: a * 0,
+                                           params["layers"]["mtp"])))
+    assert np.array_equal(np.asarray(llama.forward(other, toks, cfg)), want)
+    assert [k for k, *_ in family.run_slices(cfg, (params["layers"],))] == [
+        "dense", "moe"]
+    blobs = serde.blobs_from_params(cfg, params)
+    dev = quant.stacked_from_device(
+        cfg, [jnp.asarray(np.frombuffer(blobs[b], np.uint8))
+              for b in range(cfg.n_layers)], "raw")
+    assert set(dev) == {"dense", "moe", "mtp"}
+    assert dev["mtp"]["eh_proj"].shape == (1, 128, 64)
+    warmed = boot.precompile_boot(cfg, range(cfg.n_layers + 1),
+                                  device_blobs=True, streamed=True)
+    assert "decode[raw]x1/mtp" in warmed["compiled"]
+
+
+
 
 def test_every_family_answers_under_the_same_names():
     asked = ("CONFIGS", "HF_ARCHITECTURE", "layer_param_specs",
