@@ -26,6 +26,7 @@ pods enable ep and dp too.
 from __future__ import annotations
 
 import functools
+import threading
 from typing import Any, Dict, Tuple
 
 import jax
@@ -36,6 +37,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..parallel.mesh import make_mesh
 from ..parallel.ring_attention import ring_attention
+from ..utils import trace
 from . import family
 from .llama import ModelConfig, rms_norm, rope, route_topk
 
@@ -389,8 +391,51 @@ def example_batch(cfg: ModelConfig, mesh: Mesh, batch: int = 0, seq: int = 0):
 
 # ------------------------------------------------------------ pp inference
 
+# A pod's pipelined programs, kept for the life of the process the way
+# ``models/generate.py`` keeps the one-chip ones: key -> jitted function.
+_PP_PROGRAMS: Dict[tuple, Any] = {}
+_PP_PROGRAMS_MAX = 32  # generate.py's lru_cache size; the oldest key goes
+_PP_PROGRAMS_LOCK = threading.Lock()
+
+
+def _kept_pp_program(program, *key):
+    """The jitted function that ``program(*key)`` builds, built on the
+    first call with this key and kept.  A function object that stays the
+    same is what ``jax.jit``'s own in-memory caches key on: an equal key
+    neither traces, lowers nor loads again.  ``serve.pp_program.built``
+    / ``.reused`` count which of the two a call was.  Built under the
+    lock (wrapping is cheap, ``jax.jit`` compiles at the first call): two
+    callers of one key must get ONE function, or each compiles its own."""
+    with _PP_PROGRAMS_LOCK:
+        fn = _PP_PROGRAMS.get((program, *key))
+        if fn is not None:
+            trace.count("serve.pp_program.reused")
+            return fn
+        fn = program(*key)
+        if len(_PP_PROGRAMS) >= _PP_PROGRAMS_MAX:
+            _PP_PROGRAMS.pop(next(iter(_PP_PROGRAMS)))
+        _PP_PROGRAMS[(program, *key)] = fn
+        trace.count("serve.pp_program.built")
+        return fn
+
 
 def build_pp_forward(cfg: ModelConfig, mesh: Mesh, pp_axis: str):
+    """The pod's pipelined forward, ONE function per ``(cfg, mesh,
+    pp_axis)`` for the life of the process (``_kept_pp_program``; a mesh
+    made anew over the same devices and axis names is an equal key).
+    Batch and prompt length are traced shapes that ``jax.jit`` keys on."""
+    return _kept_pp_program(_pp_forward_program, cfg, mesh, pp_axis)
+
+
+def build_pp_decode(cfg: ModelConfig, mesh: Mesh, pp_axis: str,
+                    max_new: int):
+    """The pod's pipelined greedy decode, ONE function per ``(cfg, mesh,
+    pp_axis, max_new)`` for the life of the process, like
+    ``build_pp_forward``."""
+    return _kept_pp_program(_pp_decode_program, cfg, mesh, pp_axis, max_new)
+
+
+def _pp_forward_program(cfg: ModelConfig, mesh: Mesh, pp_axis: str):
     """jitted (layers, counts, head, tokens) -> logits over a
     pipeline-sharded mesh: each stage holds its stacked slice resident
     (the Assignment's placement — what dissemination landed), head leaves
@@ -404,7 +449,11 @@ def build_pp_forward(cfg: ModelConfig, mesh: Mesh, pp_axis: str):
     through unchanged.
 
     Any extra mesh axes (e.g. tp) replicate the computation — this is the
-    serving form of the staged placement, not the full 5-axis program."""
+    serving form of the staged placement, not the full 5-axis program.
+
+    The function is KEPT across deliveries, so it closes over nothing
+    that lives on a device (``cfg``, ``mesh``, ``pp``, ``fwd`` only): a
+    swap, or the benchmark's cold round, deletes every live array."""
     _llama_only(cfg)
     from .llama import layer_apply
 
@@ -449,8 +498,8 @@ def build_pp_forward(cfg: ModelConfig, mesh: Mesh, pp_axis: str):
     return jax.jit(f)
 
 
-def build_pp_decode(cfg: ModelConfig, mesh: Mesh, pp_axis: str,
-                    max_new: int):
+def _pp_decode_program(cfg: ModelConfig, mesh: Mesh, pp_axis: str,
+                       max_new: int):
     """jitted (layers, counts, head, prompt) -> greedy token ids
     [b, max_new]: the KV-cached decode loop (``models/generate.py``) run
     as a lockstep pipeline collective over the staged placement — the
@@ -459,13 +508,15 @@ def build_pp_decode(cfg: ModelConfig, mesh: Mesh, pp_axis: str,
 
     Mechanics: in pipeline-rotation round r only stage r's application
     is REAL (the rotated copies other stages chew are in-fill garbage,
-    same as ``build_pp_forward``), so each stage masks its per-layer KV
+    same as ``_pp_forward_program``), so each stage masks its per-layer KV
     cache writes to ``(round == my_stage) & (layer < count)`` — the
     cache stays exact while every process executes the identical
     program.  The final hidden state wraps to stage 0, is psum-broadcast
     as [b, d_model], and argmax picks the next token identically on
     every device, so the replicated decode loop can never diverge.
-    Uneven padded slices work exactly as in ``build_pp_forward``."""
+    Uneven padded slices work exactly as in ``_pp_forward_program``, and
+    like it the kept function closes over no device array (the KV cache
+    is made inside the program)."""
     _llama_only(cfg)
     from .llama import layer_with_cache
 

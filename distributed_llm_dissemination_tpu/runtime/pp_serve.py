@@ -31,6 +31,13 @@ Generation is first-class: ``pod_decode`` / ``spmd_pod_decode`` run the
 KV-cached greedy loop (``models.sharded.build_pp_decode``) across the
 stages, and UNEVEN contiguous stage slices serve (padded to the deepest
 stage; the counts vector masks the tail) — both lifted in round 4.
+
+The two programs are the PROCESS's, not a delivery's: ``build_pp_forward``
+/ ``build_pp_decode`` keep one jitted function per (configuration,
+sub-mesh, axis, length), so a pod that stays up and takes a new delivery
+serves it without tracing, lowering or loading anything again (counters
+``serve.pp_program.built`` / ``.reused``).  Every caller here passes the
+delivery's arrays as ARGUMENTS; a kept program closes over none.
 """
 
 from __future__ import annotations
