@@ -28,23 +28,34 @@ Mapping:
   ``wire.buf.reused_bytes`` / ``wire.buf.fresh_bytes`` and
   ``wire.pace.job_bytes`` / ``wire.pace.wait_ms`` of the
   ``"span counters"`` records (give one round's logs for the round's
-  sum).
+  sum); and one block, *the wire hop* (``wire_hop``): what the sending
+  seats' ``wire.job`` / ``wire.fragment`` / ``wire.send`` /
+  ``wire.send.write`` spans and the destination's ``wire.serve`` /
+  ``wire.recv`` say of one delivery together, and each seat's
+  ``proc.cpu_ms``.
 
 Usage:
     python -m distributed_llm_dissemination_tpu.cli.trace logs/ -o run.trace.json
     python -m ....trace merged.jsonl            # from collect_logs output
-    python -m ....trace --xplane <profiler dir or .xplane.pb>
+    python -m ....trace --xplane <profiler dir or .xplane.pb> [logs...]
 
 ``--xplane`` reads a JAX profiler capture instead of logs: the program's
 span annotations sit in its host plane on the same clock as the device
 planes, so every idle gap of the device is split by what the host was
-doing in it (``idle_gap_table``).
+doing in it (``idle_gap_table``).  Only the process that holds the chip
+is in a capture.  Given the round's seat logs beside it, the dumped
+spans of the seats that are NOT in the capture (leader, seeder) are laid
+onto the capture's clock and split the gaps like the annotations do:
+the destination's spans are in both, so the offset between the two
+clocks is measured (``clock_bridge``), printed, and refused when its
+spread is over a millisecond.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
+import statistics
 import sys
 from typing import Iterable, List
 
@@ -326,6 +337,189 @@ def job_pace_totals(records: Iterable[dict]) -> dict:
     (``utils/rate.JobPacer``)."""
     return _counter_totals(records, "wire.pace.", ("job_bytes", "wait_ms"))
 
+# ------------------------------------------------------------- the wire hop
+
+
+def dumped_spans(records: Iterable[dict]) -> dict:
+    """``{seat: [span, ...]}`` of the logs' ``"spans"`` records: a span
+    belongs to the seat it names (``node``), else to its log's."""
+    out: dict = {}
+    for rec in records:
+        if rec.get("message") == "spans":
+            for sp in rec.get("spans") or ():
+                out.setdefault(str(sp.get("node", rec.get("node", "?"))),
+                               []).append(sp)
+    return out
+
+
+def _quantile(values, q: float) -> float:
+    xs = sorted(values)
+    return xs[min(len(xs) - 1, int(q * len(xs)))]
+
+
+def _depth_seconds(intervals, w0: float, w1: float) -> dict:
+    """The seconds of ``[w0, w1]`` with 0 / 1-3 / 4-7 / 8+ of
+    ``intervals`` open at once."""
+    out = {"0": 0.0, "1-3": 0.0, "4-7": 0.0, "8+": 0.0}
+    edges = sorted(edge for t0, t1 in intervals if t1 > w0 and t0 < w1
+                   for edge in ((max(t0, w0), 1), (min(t1, w1), -1)))
+    depth, at = 0, w0
+    for t, step in edges + [(w1, 0)]:
+        key = ("0" if depth == 0 else "1-3" if depth < 4
+               else "4-7" if depth < 8 else "8+")
+        out[key] += t - at
+        depth, at = depth + step, t
+    return {k: round(v, 6) for k, v in out.items()}
+
+
+def frames_by_key(spans, name: str) -> dict:
+    """``{(id, offset): [span, ...]}`` of the spans ``name``, each list
+    in order of start — the key a frame has on both ends of the hop
+    (a retransmitted frame is there twice)."""
+    out: dict = {}
+    for sp in sorted(spans, key=lambda sp: sp["t0"]):
+        fields = sp.get("fields") or {}
+        if sp["name"] == name and "offset" in fields:
+            out.setdefault((sp.get("id"), fields["offset"]), []).append(sp)
+    return out
+
+
+def hop_lags(send_spans, recv_spans) -> list:
+    """Per frame, the receiving end's ``wire.recv`` start less the
+    sending end's ``wire.send.write`` start, frames joined on (``id``,
+    ``offset``); of a frame written more than once (a retry, a
+    retransmit) the k-th write meets the k-th receive."""
+    writes = frames_by_key(send_spans, "wire.send.write")
+    recvs = frames_by_key(recv_spans, "wire.recv")
+    return [r["t0"] - w["t0"] for key, ws in writes.items()
+            for w, r in zip(ws, recvs.get(key, ()))]
+
+
+def wire_hop(records: Iterable[dict]) -> dict:
+    """What the dumps of one delivery say of the hop between a sending
+    thread and a free receive thread (docs/observability.md): per
+    ``wire.job`` the commanded and the achieved rate and where its
+    threads' seconds went; how many frames were being written and read
+    at once, over the delivery; the lag between a frame's first byte
+    written and its first byte read; how full the receive pool ran; and
+    every seat's CPU.  Empty without a ``wire.job`` span."""
+    records = list(records)
+    dumps = dumped_spans(records)
+    spans = [sp for seat in dumps.values() for sp in seat]
+    jobs = [sp for sp in spans if sp["name"] == "wire.job"]
+    if not jobs:
+        return {}
+
+    def named(name, seat=None):
+        return [sp for sp in (dumps.get(seat, ()) if seat else spans)
+                if sp["name"] == name]
+
+    def fsum(found, field):
+        return round(sum((sp.get("fields") or {}).get(field, 0)
+                         for sp in found), 6)
+
+    def wall(found):
+        return round(sum(sp["t1"] - sp["t0"] for sp in found), 6)
+
+    serves = frames_by_key(spans, "wire.serve")
+    rows = []
+    for job in sorted(jobs, key=lambda sp: sp["t0"]):
+        seat = str(job.get("node", "?"))
+
+        def inside(name):
+            return [sp for sp in named(name, seat)
+                    if sp.get("id") == job.get("id")
+                    and job["t0"] <= sp["t0"] and sp["t1"] <= job["t1"]]
+
+        f = job.get("fields") or {}
+        sends, writes = inside("wire.send"), inside("wire.send.write")
+        served = [sv for key in frames_by_key(sends, "wire.send")
+                  for sv in serves.get(key, ())]
+        dur = max(job["t1"] - job["t0"], 1e-9)
+        rows.append({
+            "seat": seat, "id": job.get("id"), "job": f.get("job", ""),
+            "bytes": f.get("bytes", 0),
+            "commanded_mibps": round(f.get("rate", 0) / 2 ** 20, 1),
+            "achieved_mibps": round(f.get("bytes", 0) / dur / 2 ** 20, 1),
+            "fragments": f.get("fragments", 0), "frames": len(sends),
+            "write_s": wall(writes), "write_cpu_s": fsum(writes, "cpu"),
+            "barrier_s": fsum(inside("wire.fragment"), "barrier_s"),
+            "send_queued_s": fsum(sends, "queued_s"),
+            "serve_queued_s": fsum(served, "queued_s"),
+            "pace_s": wall(inside("wire.pace")),
+            "crc_s": fsum(sends, "crc_s")})
+    writes, recvs = named("wire.send.write"), named("wire.recv")
+    ends = writes + recvs
+    w0 = min(sp["t0"] for sp in jobs + ends)
+    w1 = max(sp["t1"] for sp in ends) if ends else max(
+        sp["t1"] for sp in jobs)
+    lags = hop_lags(writes, recvs)
+    hop = {"jobs": rows, "delivery_s": round(w1 - w0, 6),
+           "writing": _depth_seconds(
+               [(sp["t0"], sp["t1"]) for sp in writes], w0, w1),
+           "reading": _depth_seconds(
+               [(sp["t0"], sp["t1"]) for sp in recvs], w0, w1),
+           "frames_joined": len(lags), "frames_read": len(recvs)}
+    if lags:
+        hop["lag_ms"] = {"median": round(statistics.median(lags) * 1e3, 3),
+                         "p90": round(_quantile(lags, 0.9) * 1e3, 3)}
+    pools = {}
+    for seat, mine in sorted(dumps.items()):
+        served = [sp for sp in mine if sp["name"] == "wire.serve"]
+        if not served:
+            continue
+        threads = len({sp.get("thread") for sp in served})
+        inner = [sp for sp in mine if sp.get("parent") == "wire.serve"]
+        pools[seat] = {
+            "threads": threads, "frames": len(served),
+            "busy_s": wall(served),
+            "occupancy": round(wall(served)
+                               / (threads * max(w1 - w0, 1e-9)), 4),
+            "queued_s": fsum(served, "queued_s"),
+            "self_s": round(wall(served) - wall(inner), 6)}
+    hop["receive_pools"] = pools
+    hop["cpu_ms"] = {
+        str(rec.get("node", "?")): {
+            "cpu_ms": rec["counters"]["proc.cpu_ms"],
+            "sys_ms": rec["counters"].get("proc.cpu_sys_ms", 0)}
+        for rec in records if rec.get("message") == "span counters"
+        and "proc.cpu_ms" in (rec.get("counters") or {})}
+    return hop
+
+
+def print_wire_hop(hop: dict, file) -> None:
+    """``wire_hop``'s table as lines of text."""
+    def say(line):
+        print(line, file=file)
+
+    say(f"the wire hop: {len(hop['jobs'])} jobs over "
+        f"{hop['delivery_s']:.3f} s")
+    for r in hop["jobs"]:
+        say("  seat {seat} job {id}: {commanded_mibps} -> {achieved_mibps} "
+            "MiB/s, {fragments} fragments, {frames} frames; threads' "
+            "seconds: wire.send.write {write_s} (cpu {write_cpu_s}), "
+            "barrier {barrier_s}, queued for data-tx {send_queued_s} / "
+            "for data-rx {serve_queued_s}, wire.pace {pace_s}, checksum "
+            "cpu {crc_s}".format(**r))
+    for what, name in (("writing", "wire.send.write"),
+                       ("reading", "wire.recv")):
+        d = hop[what]
+        say(f"  seconds with 0 / 1-3 / 4-7 / 8+ frames inside {name}: "
+            f"{d['0']} / {d['1-3']} / {d['4-7']} / {d['8+']}")
+    if "lag_ms" in hop:
+        say("  wire.recv.t0 - wire.send.write.t0 over {n} of {m} frames: "
+            "median {median} ms, p90 {p90} ms".format(
+                n=hop["frames_joined"], m=hop["frames_read"],
+                **hop["lag_ms"]))
+    for seat, p in hop["receive_pools"].items():
+        say("  seat {seat} receive pool: wire.serve {busy_s} s in {frames} "
+            "frames on {threads} data-rx threads = occupancy {occupancy}; "
+            "queued {queued_s} s, self time {self_s} s".format(
+                seat=seat, **p))
+    for seat, c in sorted(hop["cpu_ms"].items()):
+        say(f"  seat {seat}: proc.cpu_ms {c['cpu_ms']} "
+            f"(system {c['sys_ms']})")
+
 
 def to_trace_events(records: Iterable[dict],
                     align_clocks: bool = True) -> List[dict]:
@@ -499,9 +693,11 @@ def idle_gap_table(planes, device_plane: str = DEVICE_PLANE,
                              for lo, hi in longest]}
 
 
-def load_xplane(path: str) -> list:
+def load_xplane(path: str, annotations: list = None) -> list:
     """A profiler capture (its directory, or the ``.xplane.pb``) as
-    ``idle_gap_table`` wants it."""
+    ``idle_gap_table`` wants it.  ``annotations``: a list that gets
+    ``(name, id, start_ns)`` of every event that is one of the program's
+    spans (``clock_bridge`` matches them with a dump)."""
     import glob
     import os
 
@@ -513,15 +709,97 @@ def load_xplane(path: str) -> list:
         if not hits:
             raise SystemExit(f"no .xplane.pb under {path}")
         path = hits[-1]
-    return [(plane.name,
-             [(line.name, [(e.name, float(e.start_ns), float(e.duration_ns))
-                           for e in line.events]) for line in plane.lines])
-            for plane in ProfileData.from_file(path).planes]
+    planes = []
+    for plane in ProfileData.from_file(path).planes:
+        lines = []
+        for line in plane.lines:
+            events = []
+            for e in line.events:
+                events.append((e.name, float(e.start_ns),
+                               float(e.duration_ns)))
+                if annotations is not None and e.name.startswith(
+                        SPAN_LAYERS):
+                    annotations.append(
+                        (e.name, dict(e.stats).get("id"),
+                         float(e.start_ns)))
+            lines.append((line.name, events))
+        planes.append((plane.name, lines))
+    return planes
+
+
+def _id_key(span_id):
+    """A span id as the profiler hands it back: ``"2.10"`` went in as
+    text and comes out as the number 2.1."""
+    try:
+        return float(span_id)
+    except (TypeError, ValueError):
+        return span_id
+
+
+# A bridge between the two clocks is refused over this spread.
+BRIDGE_MAX_SPREAD_S = 1e-3
+
+
+def clock_bridge(annotations: list, dumps: dict) -> dict:
+    """The capture's clock less CLOCK_MONOTONIC, measured on the spans
+    that are in both: a ``trace.span`` of the process that was captured
+    is an annotation in the capture (``annotations``: ``(name, id,
+    start_ns)``) and a record of its dump (``dumps``: ``dumped_spans``).
+    The spans of one (name, id) are matched in order of their start
+    where both sides have as many; ``offset_s`` is the median of
+    ``annotation.start - dump.t0``, ``spread_s`` the distance between
+    its quartiles, ``seats`` the seats that matched (they are in the
+    capture), ``others`` those that did not."""
+    starts: dict = {}
+    for name, span_id, start_ns in annotations:
+        starts.setdefault((name, _id_key(span_id)), []).append(start_ns)
+    diffs, seats, others = [], [], []
+    for seat, spans in sorted(dumps.items()):
+        mine: dict = {}
+        for sp in spans:
+            mine.setdefault((sp["name"], _id_key(sp.get("id"))),
+                            []).append(sp["t0"])
+        found = [a * 1e-9 - t0 for key, t0s in mine.items()
+                 if len(starts.get(key, ())) == len(t0s)
+                 for a, t0 in zip(sorted(starts[key]), sorted(t0s))]
+        (seats if found else others).append(seat)
+        diffs += found
+    if not diffs:
+        raise SystemExit("no span of the logs is an annotation of the "
+                         "capture: are they of the same round?")
+    q1, _, q3 = (statistics.quantiles(diffs, n=4) if len(diffs) > 1
+                 else (diffs[0],) * 3)
+    bridge = {"offset_s": round(statistics.median(diffs), 9),
+              "spread_s": round(q3 - q1, 9), "matched": len(diffs),
+              "seats": seats, "others": others}
+    if bridge["spread_s"] > BRIDGE_MAX_SPREAD_S:
+        raise SystemExit(f"clock bridge refused: {bridge}")
+    return bridge
+
+
+def bridged_planes(planes: list, annotations: list, records) -> tuple:
+    """``planes`` with one more plane a seat that is NOT in the capture:
+    its dumped spans on the capture's clock.  Returns the planes and the
+    bridge."""
+    dumps = dumped_spans(records)
+    bridge = clock_bridge(annotations, dumps)
+    planes = list(planes)
+    for seat in bridge["others"]:
+        by_thread: dict = {}
+        for sp in dumps[seat]:
+            by_thread.setdefault(sp.get("thread", "spans"), []).append(
+                (sp["name"], (sp["t0"] + bridge["offset_s"]) * 1e9,
+                 (sp["t1"] - sp["t0"]) * 1e9))
+        planes.append((f"/dump:seat {seat}", sorted(by_thread.items())))
+    return planes, bridge
 
 
 def main(argv: list[str] | None = None) -> int:
     p = argparse.ArgumentParser(prog="trace", description=__doc__)
-    p.add_argument("paths", nargs="*", help="log files or directories")
+    p.add_argument("paths", nargs="*",
+                   help="log files or directories (with --xplane: the "
+                        "same round's seat logs, whose spans join the "
+                        "table)")
     p.add_argument("--xplane", default="",
                    help="a JAX profiler capture (directory or .xplane.pb):"
                         " print its idle gaps split by the program's "
@@ -540,11 +818,21 @@ def main(argv: list[str] | None = None) -> int:
                         "node's timestamps as logged)")
     args = p.parse_args(argv)
     if args.xplane:
-        json.dump(idle_gap_table(load_xplane(args.xplane),
-                                 window_event=args.window,
-                                 from_span=args.from_span,
-                                 to_span=args.to_span),
-                  sys.stdout, indent=1)
+        annotations = [] if args.paths else None
+        planes, bridge = load_xplane(args.xplane, annotations), None
+        if args.paths:
+            planes, bridge = bridged_planes(
+                planes, annotations, iter_records(args.paths))
+            print("clock bridge: capture - CLOCK_MONOTONIC = "
+                  "{offset_s} s over {matched} spans of seats {seats}, "
+                  "spread {spread_s} s; laid onto the capture's clock: "
+                  "seats {others}".format(**bridge), file=sys.stderr)
+        table = idle_gap_table(planes, window_event=args.window,
+                               from_span=args.from_span,
+                               to_span=args.to_span)
+        if bridge:
+            table["clock_bridge"] = bridge
+        json.dump(table, sys.stdout, indent=1)
         print()
         return 0
     if not args.paths:
@@ -562,6 +850,9 @@ def main(argv: list[str] | None = None) -> int:
         print("wire.pace held {job_bytes} B to their jobs' plans, "
               "sending threads slept {wait_ms} ms in it".format(**paced),
               file=sys.stderr)
+    hop = wire_hop(records)
+    if hop:
+        print_wire_hop(hop, sys.stderr)
     widened = decode_widen_totals(events)
     if widened:
         print("decode.stage widened {fast_bytes} B with the kernel, "

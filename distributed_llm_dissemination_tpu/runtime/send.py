@@ -665,27 +665,36 @@ def handle_flow_retransmit(
         # piece that queued for a worker loses no budget.
         pacer = (JobPacer(msg.rate, span_id=span, job=msg.job_id)
                  if msg.rate > 0 else None)
-        sent = 0
-        while sent < msg.data_size:
-            if (sent > 0 and revokes is not None
-                    and revokes.consume(msg.job_id, msg.dest_id,
-                                        msg.layer_id,
-                                        gen=getattr(msg, "gen", 0))):
-                trace.count("jobs.revoked_pairs")
-                log.warn("in-flight flow send revoked mid-job; stopping",
-                         layerID=msg.layer_id, dest=msg.dest_id,
-                         job=msg.job_id, sent=sent)
-                return
-            n = min(frag_bytes, msg.data_size - sent)
-            partial = _sub_layer_src(view, send_loc, msg.offset + sent, n,
-                                     msg.rate)
-            node.transport.send(
-                msg.dest_id,
-                LayerMsg(node.my_id, msg.layer_id, partial, view.data_size,
-                         job_id=msg.job_id, codec=codec, span_id=span,
-                         pacer=pacer),
-            )
-            sent += n
+        # ``wire.job``: the command taken → the last fragment returned;
+        # the transport's ``wire.fragment`` spans are its children by
+        # thread (docs/observability.md).
+        with trace.span("wire.job", id=span, node=node.my_id,
+                        job=msg.job_id, bytes=msg.data_size, rate=msg.rate,
+                        codec=codec) as job_span:
+            sent = fragments = 0
+            while sent < msg.data_size:
+                if (sent > 0 and revokes is not None
+                        and revokes.consume(msg.job_id, msg.dest_id,
+                                            msg.layer_id,
+                                            gen=getattr(msg, "gen", 0))):
+                    trace.count("jobs.revoked_pairs")
+                    log.warn("in-flight flow send revoked mid-job; "
+                             "stopping", layerID=msg.layer_id,
+                             dest=msg.dest_id, job=msg.job_id, sent=sent)
+                    job_span.set(revoked=True, sent=sent)
+                    break
+                n = min(frag_bytes, msg.data_size - sent)
+                partial = _sub_layer_src(view, send_loc, msg.offset + sent,
+                                         n, msg.rate)
+                node.transport.send(
+                    msg.dest_id,
+                    LayerMsg(node.my_id, msg.layer_id, partial,
+                             view.data_size, job_id=msg.job_id, codec=codec,
+                             span_id=span, pacer=pacer),
+                )
+                sent += n
+                fragments += 1
+            job_span.set(fragments=fragments)
     elif layer.meta.location == LayerLocation.CLIENT:
         def _simulate_client_fetch() -> None:
             if layer.inmem_data is not None:

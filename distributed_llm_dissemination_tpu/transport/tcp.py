@@ -436,7 +436,10 @@ class _ReadinessLoop:
                 self._sel.unregister(sock)
             except (KeyError, ValueError, OSError):
                 return
-            threads.rx_pool().submit(tr._serve_layer_body, sock, envelope)
+            # Stamped here, read by the pool thread that picks the frame
+            # up: ``wire.serve``'s ``queued_s`` is the wait in between.
+            threads.rx_pool().submit(tr._serve_layer_body, sock, envelope,
+                                     time.monotonic())
             return
 
     def _on_drain(self, sock, rec: dict) -> None:
@@ -598,23 +601,40 @@ class TcpTransport(Transport):
                 continue
         _readiness_loop().watch_conn(self, conn)
 
-    def _serve_layer_body(self, conn: socket.socket, envelope: dict) -> None:
+    def _serve_layer_body(self, conn: socket.socket, envelope: dict,
+                          queued_at: Optional[float] = None) -> None:
         """Pool worker: blocking-read one layer frame's body through the
         unchanged receive paths (zero-copy sink placement, stripe
         regroup, cut-through relay), then return the connection to the
-        readiness loop at the frame boundary."""
+        readiness loop at the frame boundary.  The whole task is one
+        ``wire.serve`` span — picked up → the connection handed back —
+        whose children by thread are the frame's ``wire.recv`` and
+        ``wire.crc``; ``queued_s`` is the wait since the loop had the
+        envelope (``queued_at``)."""
+        picked = time.monotonic()
         try:
-            conn.setblocking(True)
-            self._receive_layer(conn, envelope)
-        except (ConnectionError, OSError, ValueError, KeyError) as e:
+            header = LayerHeader.from_payload(envelope["payload"])
+        except (ValueError, KeyError) as e:
             if not self._closed.is_set():
                 log.error("receive loop failed", err=e)
             self._discard_accepted(conn)
             return
-        except BaseException:
-            self._discard_accepted(conn)
-            raise
-        _readiness_loop().watch_conn(self, conn)
+        with trace.span("wire.serve", id=self._pair_id(header),
+                        node=self.node_id, offset=header.offset,
+                        bytes=header.layer_size,
+                        queued_s=round(picked - (queued_at or picked), 6)):
+            try:
+                conn.setblocking(True)
+                self._receive_layer(conn, envelope, header)
+            except (ConnectionError, OSError, ValueError, KeyError) as e:
+                if not self._closed.is_set():
+                    log.error("receive loop failed", err=e)
+                self._discard_accepted(conn)
+                return
+            except BaseException:
+                self._discard_accepted(conn)
+                raise
+            _readiness_loop().watch_conn(self, conn)
 
     def _frame_ok(self, header: LayerHeader, view,
                   notify: bool = True) -> Tuple[bool, float]:
@@ -685,8 +705,10 @@ class TcpTransport(Transport):
             rx_placed_frames=1 if placed else 0,
             wire_s=dur_ms / 1000.0, verify_s=crc_ms / 1000.0)
 
-    def _receive_layer(self, conn: socket.socket, envelope: dict) -> None:
-        header = LayerHeader.from_payload(envelope["payload"])
+    def _receive_layer(self, conn: socket.socket, envelope: dict,
+                       header: Optional[LayerHeader] = None) -> None:
+        if header is None:
+            header = LayerHeader.from_payload(envelope["payload"])
         if header.stripe_n > 1:
             self._receive_stripe(conn, envelope, header)
             return
@@ -1194,7 +1216,10 @@ class TcpTransport(Transport):
             raise KeyError(f"addr of {dest_id} does not exist")
 
         if isinstance(message, LayerMsg):
-            streams = self._send_layer_pooled(dest, message)
+            streams = self._send_layer_pooled(
+                dest, message,
+                pair=message.span_id or telemetry.span_id(dest_id,
+                                                          message.layer_id))
             # Sent without raising: file the frame(s) on the (src, dest)
             # link — ``tx_stripe_frames / tx_frames`` is the run's
             # average stripe occupancy for the link.
@@ -1232,10 +1257,14 @@ class TcpTransport(Transport):
                     raise
                 time.sleep(next(delays, 0.05))
 
-    def _send_layer_pooled(self, dest: str, message: LayerMsg) -> int:
+    def _send_layer_pooled(self, dest: str, message: LayerMsg,
+                           pair: Optional[str] = None) -> int:
         """One layer transfer over pooled data connection(s); returns
         the number of concurrent streams the payload rode (1 =
         un-striped) for the sender-side stripe-occupancy accounting.
+        One ``wire.fragment`` span, entry → every stream returned
+        (``pair``: the delivery pair's span id, on it and on every
+        ``wire.send`` under it).
 
         Payloads past ``STRIPE_THRESHOLD`` split into stripes riding
         several pooled connections CONCURRENTLY (``_send_layer_striped``)
@@ -1250,22 +1279,30 @@ class TcpTransport(Transport):
         connection error, and interval reassembly tolerates the re-send.
         """
         src = message.layer_src
-        if (STRIPE_COUNT > 1
-                and src.data_size >= max(STRIPE_THRESHOLD, 2 * STRIPE_MIN)
-                and (src.meta.limit_rate == 0
-                     or src.meta.limit_rate >= STRIPE_PACED_MIN_RATE)
-                and src.meta.location in (LayerLocation.INMEM,
-                                          LayerLocation.HBM,
-                                          LayerLocation.DISK)):
-            spans = stripe_offsets(src.data_size, STRIPE_COUNT, STRIPE_MIN)
-            if len(spans) > 1 and self._send_layer_striped(
-                    dest, message, spans):
-                return len(spans)
-        self._send_one_stream(dest, message)
-        return 1
+        with trace.span("wire.fragment", id=pair or message.span_id or None,
+                        node=self.node_id, bytes=src.data_size,
+                        offset=src.offset) as frag:
+            if (STRIPE_COUNT > 1
+                    and src.data_size >= max(STRIPE_THRESHOLD,
+                                             2 * STRIPE_MIN)
+                    and (src.meta.limit_rate == 0
+                         or src.meta.limit_rate >= STRIPE_PACED_MIN_RATE)
+                    and src.meta.location in (LayerLocation.INMEM,
+                                              LayerLocation.HBM,
+                                              LayerLocation.DISK)):
+                spans = stripe_offsets(src.data_size, STRIPE_COUNT,
+                                       STRIPE_MIN)
+                if len(spans) > 1 and self._send_layer_striped(
+                        dest, message, spans, frag):
+                    frag.set(streams=len(spans))
+                    return len(spans)
+            self._send_one_stream(dest, message, frag=frag)
+            frag.set(streams=1, barrier_s=0.0, stolen=0)
+            return 1
 
     def _send_one_stream(self, dest: str, message: LayerMsg,
-                         stripe: Optional[dict] = None) -> None:
+                         stripe: Optional[dict] = None, frag=None,
+                         queued_at: Optional[float] = None) -> None:
         """One byte stream (a whole payload, or one stripe of one) over a
         pooled data connection, with the stale-connection retry: attempt
         0 uses a pooled conn (free to fail — the peer may have restarted
@@ -1273,44 +1310,73 @@ class TcpTransport(Transport):
         exponential backoff (utils/backoff.py) before the OSError
         surfaces.  A half-sent fragment on a dead connection is harmless
         — the receiver drops partial bodies on connection error, and
-        interval reassembly tolerates the re-send."""
-        delays = Backoff(base=0.05, factor=2.0, max_delay=0.8,
-                         retries=_SEND_RETRIES,
-                         seed=(hash(dest) ^ message.layer_id) & 0xFFFF
-                         ).delays()
-        for attempt in range(_SEND_RETRIES + 1):
-            fresh = attempt > 0
-            last = attempt >= _SEND_RETRIES
-            sock = None
-            try:
-                sock = (self._dial_data(dest) if fresh
-                        else self._acquire_data_conn(dest))
-                self._send_layer(sock, message, stripe=stripe)
-            except OSError:
-                if sock is not None:
-                    sock.close()  # state unknown: never pool a broken conn
-                if last:
+        interval reassembly tolerates the re-send.
+
+        One ``wire.send`` span a frame, retries inside: a thread started
+        on it → its connection is back in the pool.  ``frag``: the
+        ``wire.fragment`` span it belongs to (its parent, named
+        explicitly: a stripe may run on a ``data-tx-*`` thread);
+        ``queued_at``: when the stripe was handed to the tx pool."""
+        started = time.monotonic()
+        src = message.layer_src
+        parent = frag.rec if frag is not None else {}
+        with trace.span(
+                "wire.send", id=parent.get("id") or message.span_id or None,
+                parent=parent.get("name"), node=self.node_id,
+                bytes=src.data_size, offset=src.offset,
+                stripe=stripe["idx"] if stripe else 0,
+                queued_s=round(started - (queued_at or started), 6)) as sp:
+            delays = Backoff(base=0.05, factor=2.0, max_delay=0.8,
+                             retries=_SEND_RETRIES,
+                             seed=(hash(dest) ^ message.layer_id) & 0xFFFF
+                             ).delays()
+            crc_s = dial_s = 0.0
+            for attempt in range(_SEND_RETRIES + 1):
+                fresh = attempt > 0
+                last = attempt >= _SEND_RETRIES
+                sock = None
+                try:
+                    sock = None if fresh else self._pooled_data_conn(dest)
+                    sp.set(attempts=attempt + 1,
+                           conn="pooled" if sock is not None
+                           else "redialed" if fresh else "dialed")
+                    if sock is None:
+                        t_dial = time.monotonic()
+                        sock = self._dial_data(dest)
+                        dial_s += time.monotonic() - t_dial
+                        sp.set(dial_s=round(dial_s, 6))
+                    crc_s += self._send_layer(sock, message, stripe=stripe)
+                    sp.set(crc_s=round(crc_s, 6))
+                except OSError:
+                    if sock is not None:
+                        sock.close()  # state unknown: never pool it
+                    if last:
+                        raise
+                    time.sleep(next(delays, 0.05))
+                    continue
+                except Exception:
+                    # Non-socket failure (e.g. an unserveable LayerSrc)
+                    # can strike after the header frame is on the wire:
+                    # the conn is mid-message — close it, never pool it,
+                    # don't retry.
+                    if sock is not None:
+                        sock.close()
                     raise
-                time.sleep(next(delays, 0.05))
-                continue
-            except Exception:
-                # Non-socket failure (e.g. an unserveable LayerSrc) can
-                # strike after the header frame is on the wire: the conn
-                # is mid-message — close it, never pool it, don't retry.
-                if sock is not None:
-                    sock.close()
-                raise
-            self._release_data_conn(dest, sock)
-            return
+                self._release_data_conn(dest, sock)
+                return
 
     def _send_layer_striped(self, dest: str, message: LayerMsg,
-                            spans) -> bool:
+                            spans, frag=None) -> bool:
         """Send one logical payload as ``len(spans)`` stripes over that
         many pooled data connections in parallel.  Each stripe is an
         independent single-stream send (own pooled checkout, own stale
         retry); the first stripe runs on the calling thread.  Returns
         False without touching the wire when the source can't serve
-        concurrent range reads (the caller then streams it whole)."""
+        concurrent range reads (the caller then streams it whole).
+        ``frag``: the fragment's ``wire.fragment`` span, which gets the
+        barrier's seconds (``barrier_s``: the caller's own stripe ended
+        → every stripe returned) and what the caller ``stolen`` from the
+        tx pool's queue meanwhile."""
         src = message.layer_src
         if src.meta.location == LayerLocation.HBM and src.inmem_data is None:
             # One device→host fetch up front; stripes then slice host RAM.
@@ -1329,7 +1395,10 @@ class TcpTransport(Transport):
             pacer = JobPacer(src.meta.limit_rate, span_id=message.span_id,
                              job=message.job_id)
 
-        def send_stripe(idx: int, rel_off: int, size: int) -> None:
+        own_end = []  # when stripe 0, the caller's own, returned
+
+        def send_stripe(idx: int, rel_off: int, size: int,
+                        queued_at: Optional[float]) -> None:
             sub = LayerSrc(
                 inmem_data=src.inmem_data, fp=src.fp, data_size=size,
                 offset=src.offset + rel_off, meta=src.meta,
@@ -1345,17 +1414,24 @@ class TcpTransport(Transport):
                              span_id=message.span_id,
                              span_parent=message.span_parent,
                              pacer=pacer),
-                    stripe=stripe)
+                    stripe=stripe, frag=frag, queued_at=queued_at)
             except BaseException as e:  # noqa: BLE001 — re-raised below
                 errors.append(e)
+            finally:
+                if idx == 0:
+                    own_end.append(time.monotonic())
 
         # Concurrent stripes ride the bounded tx pool (utils/threads.py)
         # — stripe 0 runs on the calling thread (run_all's guaranteed-
         # progress slot), so a saturated pool serializes extra stripes
         # instead of spawning a thread per stripe.
-        threads.tx_pool().run_all(
-            [(send_stripe, i, off, size)
+        handed = time.monotonic()
+        stolen = threads.tx_pool().run_all(
+            [(send_stripe, i, off, size, handed if i else None)
              for i, (off, size) in enumerate(spans)])
+        if frag is not None:
+            frag.set(barrier_s=round(time.monotonic() - own_end[0], 6),
+                     stolen=stolen)
         if errors:
             raise errors[0]
         return True
@@ -1363,12 +1439,12 @@ class TcpTransport(Transport):
     def _dial_data(self, dest: str) -> socket.socket:
         return _dial(_parse_addr(dest), self._closed)
 
-    def _acquire_data_conn(self, dest: str) -> socket.socket:
+    def _pooled_data_conn(self, dest: str) -> Optional[socket.socket]:
+        """An idle pooled data connection to ``dest``, or None (the
+        caller dials)."""
         with self._lock:
             pool = self._data_pool.get(dest)
-            if pool:
-                return pool.pop()
-        return self._dial_data(dest)
+            return pool.pop() if pool else None
 
     def _release_data_conn(self, dest: str, sock: socket.socket) -> None:
         with self._lock:
@@ -1378,7 +1454,7 @@ class TcpTransport(Transport):
         sock.close()
 
     def _send_layer(self, sock: socket.socket, message: LayerMsg,
-                    stripe: Optional[dict] = None) -> None:
+                    stripe: Optional[dict] = None) -> float:
         """Header then raw body (transport.go:308-373).  In-memory bodies
         ride the header's scatter-gather ``sendmsg`` (no concat, one
         syscall batch); disk bodies keep the kernel ``sendfile`` path —
@@ -1387,8 +1463,15 @@ class TcpTransport(Transport):
         stamped with the advisory checksum (xxh3-64 where available,
         crc32 otherwise — ``integrity.fragment_checksum``) of exactly
         its payload bytes (per stripe), computed BEFORE anything touches
-        the wire."""
+        the wire.  Returns the thread's CPU seconds in that checksum
+        (``wire.send``'s ``crc_s``); the write itself is one
+        ``wire.send.write`` span, the header frame's first byte → the
+        body's last byte accepted by the kernel, with the thread's CPU
+        seconds inside it as ``cpu`` — the rest of it is a full socket
+        buffer (the reader is behind) or a ``wire.pace`` sleep, its
+        child."""
         src = message.layer_src
+        crc_s = 0.0
         header = LayerHeader(
             src_id=message.src_id,
             layer_id=message.layer_id,
@@ -1438,39 +1521,56 @@ class TcpTransport(Transport):
                     header.xxh3 = value
                 else:
                     header.crc = value
-                trace.add_phase("integrity_crc_send",
-                                time.thread_time() - t_crc)
+                crc_s = time.thread_time() - t_crc
         envelope = {
             "type": int(MsgType.LAYER),
             "src": str(message.src_id),
             "payload": header.to_payload(),
         }
-        if data is not None:
-            if message.pacer is not None:
-                # A plan budget: the job's one pacer, shared with every
-                # other fragment and stripe of the job.
-                _send_frame(sock, envelope)
-                message.pacer.write(sock.sendall, data)
-            elif src.meta.limit_rate > 0:
-                _send_frame(sock, envelope)
-                log.debug(
-                    "sending with limit",
-                    layerID=message.layer_id,
-                    mibps=src.meta.limit_rate >> 20,
-                )
-                PacedWriter(sock.sendall, src.meta.limit_rate).write(data)
-            else:
-                body = json.dumps(envelope).encode()
-                _sendmsg_all(sock, (_LEN.pack(len(body)), body, data))
-        elif src.meta.location == LayerLocation.DISK:
+        if data is None:
+            if src.meta.location != LayerLocation.DISK:
+                raise ValueError(
+                    f"cannot send layer {message.layer_id} from {src.meta}")
             if not src.fp:
                 raise ValueError("no data source specified")
+        with trace.span("wire.send.write", id=message.span_id or None,
+                        node=self.node_id, bytes=src.data_size,
+                        offset=src.offset) as sp:
+            cpu0 = time.thread_time()
+            try:
+                self._write_frame(sock, message, envelope, data)
+            finally:
+                sp.set(cpu=round(time.thread_time() - cpu0, 6))
+        return crc_s
+
+    def _write_frame(self, sock: socket.socket, message: LayerMsg,
+                     envelope: dict, data) -> None:
+        """The header frame and the body onto the socket: ``data`` (an
+        in-memory body) through the job's pacer, a per-message
+        ``PacedWriter`` or one scatter-gather ``sendmsg``; a disk body
+        (``data`` None) through kernel ``sendfile``."""
+        src = message.layer_src
+        if data is None:
             _send_frame(sock, envelope)
             # Zero-copy kernel sendfile, the io.Copy(SectionReader) path.
             with open(src.fp, "rb") as f:
                 sock.sendfile(f, offset=src.offset, count=src.data_size)
+        elif message.pacer is not None:
+            # A plan budget: the job's one pacer, shared with every
+            # other fragment and stripe of the job.
+            _send_frame(sock, envelope)
+            message.pacer.write(sock.sendall, data)
+        elif src.meta.limit_rate > 0:
+            _send_frame(sock, envelope)
+            log.debug(
+                "sending with limit",
+                layerID=message.layer_id,
+                mibps=src.meta.limit_rate >> 20,
+            )
+            PacedWriter(sock.sendall, src.meta.limit_rate).write(data)
         else:
-            raise ValueError(f"cannot send layer {message.layer_id} from {src.meta}")
+            body = json.dumps(envelope).encode()
+            _sendmsg_all(sock, (_LEN.pack(len(body)), body, data))
 
     def broadcast(self, message: Message) -> None:
         with self._lock:

@@ -103,6 +103,8 @@ SPAN_PHASES: Tuple[str, ...] = (
 SPAN_NAMES: Tuple[str, ...] = (
     "plan.solve", "plan.dispatch",
     "wire.recv", "wire.crc", "wire.digest", "wire.queue", "wire.pace",
+    "wire.job", "wire.fragment", "wire.send", "wire.send.write",
+    "wire.serve",
     "ingest.write", "ingest.finalize", "ingest.finalize.wait",
     "ingest.finalize.splice", "ingest.finalize.ready", "ingest.ack",
     "decode.stage",
@@ -112,9 +114,9 @@ SPAN_NAMES: Tuple[str, ...] = (
     "serve.pod_forward", "serve.pod_decode",
     "fabric.compile", "fabric.publish", "fabric.collect", "fabric.upload",
     "fabric.collective", "fabric.collective.wait", "fabric.splice")
-# Durations still filed through ``trace.add_phase`` (no start kept):
-# sender-side checksum CPU seconds, and the codec plane's encode.
-PHASE_NAMES: Tuple[str, ...] = ("integrity_crc_send", "codec_encode")
+# The duration still filed through ``trace.add_phase`` (no start kept):
+# the codec plane's encode.
+PHASE_NAMES: Tuple[str, ...] = ("codec_encode",)
 # Compilation counters of the device-holding process
 # (``utils/trace.watch_compiles``).
 XLA_COUNTERS: Tuple[str, ...] = (
@@ -171,6 +173,9 @@ class Telemetry:
         self._events: Optional[collections.deque] = None
         self._spans: Optional[collections.deque] = None
         self._phases: Dict[str, list] = {}
+        # The process's (user, system) CPU seconds at the last
+        # ``reset_run()``; until one, its CPU counts from its start.
+        self._cpu0 = (0.0, 0.0)
 
     # ------------------------------------------------------------ scalars
 
@@ -325,6 +330,19 @@ class Telemetry:
         with self._lock:
             return dict(sorted(self._counters.items()))
 
+    def proc_cpu(self) -> dict:
+        """What CPU this process burnt since the last ``reset_run()`` (or
+        since it began), in whole milliseconds: ``proc.cpu_ms`` (user +
+        system) and ``proc.cpu_sys_ms``.  One ``os.times()`` reading at
+        call time — ``utils/trace.dump_spans`` puts both beside the
+        event counters, so a resident seat reports a round and a
+        one-shot ``cli.main`` its run."""
+        now = os.times()
+        user0, sys0 = self._cpu0
+        sys_s = now.system - sys0
+        return {"proc.cpu_ms": round((now.user - user0 + sys_s) * 1000),
+                "proc.cpu_sys_ms": round(sys_s * 1000)}
+
     def _phase_totals_locked(self) -> dict:
         return {name: {"ms": round(s * 1000, 1), "n": n}
                 for name, (s, n) in sorted(self._phases.items())}
@@ -341,6 +359,7 @@ class Telemetry:
 
     def reset_run(self) -> None:
         with self._lock:
+            self._cpu0 = os.times()[:2]
             self._counters.clear()
             self._gauges.clear()
             self._hists.clear()

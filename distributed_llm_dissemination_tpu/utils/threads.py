@@ -120,7 +120,7 @@ class WorkerPool:
             ).start()
         return task
 
-    def run_all(self, calls) -> None:
+    def run_all(self, calls) -> int:
         """Run ``(fn, *args)`` tuples concurrently: all but the first
         go to the pool, the first runs on the CALLING thread, and while
         waiting the caller HELPS — it steals queued tasks and runs them
@@ -129,10 +129,14 @@ class WorkerPool:
         send inside a pooled fan-out send) never parks a worker slot
         waiting on work that needs a free worker — every waiter IS a
         worker, so the pool can saturate but never deadlock.
-        Re-raises the first failure after every call finished."""
+        Re-raises the first failure after every call finished; returns
+        how many queued tasks (its own later calls or anybody else's)
+        the caller stole and ran while it waited — the ``stolen`` of a
+        ``wire.fragment`` span."""
         calls = list(calls)
         if not calls:
-            return
+            return 0
+        n_stolen = 0
         tasks = [self.submit(fn, *args) for fn, *args in calls[1:]]
         first = _Task(calls[0][0], tuple(calls[0][1:]))
         first.run()
@@ -146,9 +150,11 @@ class WorkerPool:
                 with self._lock:
                     self._pending -= 1
                 stolen.run()
+                n_stolen += 1
         for t in [first] + tasks:
             if t.error is not None:
                 raise t.error
+        return n_stolen
 
     def _work(self) -> None:
         while True:

@@ -208,9 +208,11 @@ def watch_compiles() -> None:
 def dump_spans(log, since: float = None) -> int:
     """Write the ring out as log records: ``"spans"`` records of at most
     ``DUMP_CHUNK`` interval spans each, then one ``"span counters"``
-    record (event counters, how many spans the ring dropped — a dump
-    with ``dropped`` above 0 is a window, not the run — and one reading
-    of both clocks).  ``since``: only spans that ended at or
+    record (event counters, and among them the process's CPU since the
+    registry's last ``reset_run()``, ``proc.cpu_ms`` / ``proc.cpu_sys_ms``,
+    read now; how many spans the ring dropped — a dump with ``dropped``
+    above 0 is a window, not the run — and one reading of both
+    clocks).  ``since``: only spans that ended at or
     after that ``time.monotonic()`` (a process that never resets its
     registry between runs).  Returns how many spans were written."""
     out = []
@@ -225,6 +227,7 @@ def dump_spans(log, since: float = None) -> int:
         log.info("spans", part=i + 1, of=parts,
                  spans=out[i * DUMP_CHUNK:(i + 1) * DUMP_CHUNK])
     counters = counter_totals()
+    counters.update(_telemetry.default().proc_cpu())
     log.info("span counters", counters=counters, spans=len(out),
              dropped=counters.get("telemetry.intervals_dropped", 0),
              mono=round(time.monotonic(), 6),

@@ -252,7 +252,10 @@ def small_stripes(monkeypatch):
     monkeypatch.setattr(tcp_mod, "STRIPE_MIN", 16 * 1024)
     monkeypatch.setattr(tcp_mod, "STRIPE_COUNT", 4)
     monkeypatch.setattr(tcp_mod, "STRIPE_PACED_MIN_RATE", 10 ** 6)
-    monkeypatch.setattr(threads, "_tx", threads.WorkerPool(1, "data-tx-one"))
+    # (not a data-plane name: the pool's worker outlives the test, and a
+    # stray ``data-*`` thread counts against tests/test_threads.py's
+    # ceiling when both files share a pytest worker)
+    monkeypatch.setattr(threads, "_tx", threads.WorkerPool(1, "pace-tx-one"))
 
 
 def spy_stripes(transport) -> set:

@@ -459,10 +459,13 @@ def test_leader_killed_mid_action_standby_completes_it(kind, monkeypatch):
         # Inherited: the armed rules survived the failover verbatim.
         assert new_leader.policy.table()["Rules"] == validate_policies(
             rules)
-        # The takeover resume audited the inheritance AT the new epoch.
-        assert any(a.get("Action") == "resume" and a.get("Epoch") == 1
-                   for a in new_leader.policy.table()["Audit"]), (
-            new_leader.policy.table()["Audit"])
+        # The takeover resume audited the inheritance AT the new epoch
+        # (``promoted`` is set before ``resume_from_takeover`` runs, so
+        # the audit row is waited for, not assumed).
+        _wait_for(lambda: any(
+            a.get("Action") == "resume" and a.get("Epoch") == 1
+            for a in new_leader.policy.table()["Audit"]),
+            what="the takeover's resume audit at epoch 1")
         _wait_for(lambda: getattr(new_leader.jobs.get(jid), "state", "")
                   == "done", what="inherited grow job completion")
         # The action closes out in the successor's audit on its next

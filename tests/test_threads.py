@@ -231,7 +231,9 @@ def test_data_thread_ceiling_under_concurrent_transfers(kind, tmp_path,
 
     def watch():
         while not stop.is_set():
-            peak["n"] = max(peak["n"], len(_data_threads()))
+            names = _data_threads()
+            if len(names) > peak["n"]:
+                peak["n"], peak["names"] = len(names), sorted(names)
             time.sleep(0.002)
 
     watcher = threading.Thread(target=watch, daemon=True)
@@ -261,7 +263,7 @@ def test_data_thread_ceiling_under_concurrent_transfers(kind, tmp_path,
     ceiling = threads.data_thread_ceiling()
     assert peak["n"] <= ceiling, (
         f"{peak['n']} data threads for {K} concurrent transfers "
-        f"exceeds the pool ceiling {ceiling}")
+        f"exceeds the pool ceiling {ceiling}: {peak.get('names')}")
     if kind == "tcp":
         # The pools were actually exercised (non-vacuous).
         assert peak["n"] > 0

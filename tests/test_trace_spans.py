@@ -216,6 +216,12 @@ def test_compilations_are_counted_through_jax_monitoring():
 
 # ----------------------------------------------------------- the vocabulary
 
+HOP_SPANS = ("wire.job", "wire.fragment", "wire.send", "wire.send.write",
+             "wire.serve")
+# Read by ``dump_spans`` at dump time: in no registry, so pinned here.
+PROC_COUNTERS = ("proc.cpu_ms", "proc.cpu_sys_ms")
+
+
 def _package_source():
     out = {}
     for root, dirs, names in os.walk(PKG):
@@ -240,6 +246,8 @@ def test_every_span_and_counter_name_is_pinned_to_a_call_site_and_documents():
     names = telemetry.SPAN_NAMES + telemetry.PHASE_NAMES
     missing = [n for n in names + telemetry.XLA_COUNTERS
                if f'"{n}"' not in blob]
+    missing += [n for n in PROC_COUNTERS
+                if f'"{n}"' not in source[defining]]
     assert not missing, f"no quoted call site in the package: {missing}"
     called = set(re.findall(
         r'trace\.(?:span|span_at|add_phase)\(\s*"([^"]+)"', blob))
@@ -252,16 +260,29 @@ def test_every_span_and_counter_name_is_pinned_to_a_call_site_and_documents():
         with open(os.path.join(REPO, doc)) as f:
             text = f.read()
         absent = [n for n in telemetry.SPAN_NAMES + telemetry.XLA_COUNTERS
-                  if f"`{n}`" not in text]
+                  + PROC_COUNTERS if f"`{n}`" not in text]
         assert not absent, f"{doc} does not list {absent}"
+    # the sending side and the hand-off (ISSUE 35) are spans of the one
+    # vocabulary, each with a row in PERF.md section 3's table of layers;
+    # the phase they replaced has neither a name nor a row any more
+    assert set(HOP_SPANS) <= set(telemetry.SPAN_NAMES)
+    with open(os.path.join(REPO, "PERF.md")) as f:
+        layers = f.read().split("## 3. Layers")[1].split("## 4. Cells")[0]
+    rows = [line for line in layers.splitlines() if line.startswith("| ")]
+    for name in HOP_SPANS + PROC_COUNTERS:
+        assert any(f"`{name}`" in row for row in rows), name
+    assert telemetry.PHASE_NAMES == ("codec_encode",)
+    assert not any("integrity_crc_send" in row for row in rows)
 
 
 def test_nothing_of_the_removed_tracing_is_left():
-    """What ISSUE 24 took away because nothing read it."""
+    """What ISSUE 24 took away because nothing read it, and the one
+    phase ISSUE 35 turned into a field (``wire.send``'s ``crc_s``)."""
     blob = "\n".join(_package_source().values())
     for gone in ("tcp.rx_frame_ms", "integrity_crc_recv",
                  "integrity_digest", "boot_stream_stage",
-                 "boot_stream_in_wire", "boot_precompile_in_wire"):
+                 "boot_stream_in_wire", "boot_precompile_in_wire",
+                 "integrity_crc_send"):
         assert gone not in blob, gone
 
 
