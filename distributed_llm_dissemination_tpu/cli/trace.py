@@ -395,14 +395,43 @@ def hop_lags(send_spans, recv_spans) -> list:
             for w, r in zip(ws, recvs.get(key, ()))]
 
 
+def serve_self_split(served, children) -> dict:
+    """Where a seat's ``wire.serve`` spans (``served``) spend what is
+    not their ``children``'s: per frame, the span's start → its first
+    ``wire.recv``'s start (``before_read``: pick-up, ``setblocking``,
+    the stripe bookkeeping, the sink's claim) and its last child's end
+    → its own end (``after_verify``: the telemetry row, the log line,
+    the ``put``, the re-arm).  A frame's children are those on its
+    thread inside its time; a frame that never read is left out."""
+    kids: dict = {}
+    for sp in children:
+        kids.setdefault(sp.get("thread"), []).append(sp)
+    before, after = [], []
+    for sv in served:
+        inner = [sp for sp in kids.get(sv.get("thread"), ())
+                 if sv["t0"] <= sp["t0"] and sp["t1"] <= sv["t1"]]
+        reads = [sp["t0"] for sp in inner if sp["name"] == "wire.recv"]
+        if reads:
+            before.append(min(reads) - sv["t0"])
+            after.append(sv["t1"] - max(sp["t1"] for sp in inner))
+    out = {}
+    for key, xs in (("before_read", before), ("after_verify", after)):
+        out[key + "_s"] = round(sum(xs), 6)
+        out[key + "_ms"] = {
+            "median": round(statistics.median(xs) * 1e3, 3) if xs else 0.0,
+            "p90": round(_quantile(xs, 0.9) * 1e3, 3) if xs else 0.0}
+    return out
+
+
 def wire_hop(records: Iterable[dict]) -> dict:
     """What the dumps of one delivery say of the hop between a sending
     thread and a free receive thread (docs/observability.md): per
     ``wire.job`` the commanded and the achieved rate and where its
     threads' seconds went; how many frames were being written and read
     at once, over the delivery; the lag between a frame's first byte
-    written and its first byte read; how full the receive pool ran; and
-    every seat's CPU.  Empty without a ``wire.job`` span."""
+    written and its first byte read; how full the receive pool ran and
+    where its self time lies (``serve_self_split``); and every seat's
+    CPU.  Empty without a ``wire.job`` span."""
     records = list(records)
     dumps = dumped_spans(records)
     spans = [sp for seat in dumps.values() for sp in seat]
@@ -476,7 +505,8 @@ def wire_hop(records: Iterable[dict]) -> dict:
             "occupancy": round(wall(served)
                                / (threads * max(w1 - w0, 1e-9)), 4),
             "queued_s": fsum(served, "queued_s"),
-            "self_s": round(wall(served) - wall(inner), 6)}
+            "self_s": round(wall(served) - wall(inner), 6),
+            **serve_self_split(served, inner)}
     hop["receive_pools"] = pools
     hop["cpu_ms"] = {
         str(rec.get("node", "?")): {
@@ -516,6 +546,11 @@ def print_wire_hop(hop: dict, file) -> None:
             "frames on {threads} data-rx threads = occupancy {occupancy}; "
             "queued {queued_s} s, self time {self_s} s".format(
                 seat=seat, **p))
+        say("    of the self time, before the first wire.recv "
+            "{before_read_s} s (a frame: median {b[median]} ms, p90 "
+            "{b[p90]} ms), after the last child {after_verify_s} s "
+            "(median {a[median]} ms, p90 {a[p90]} ms)".format(
+                b=p["before_read_ms"], a=p["after_verify_ms"], **p))
     for seat, c in sorted(hop["cpu_ms"].items()):
         say(f"  seat {seat}: proc.cpu_ms {c['cpu_ms']} "
             f"(system {c['sys_ms']})")

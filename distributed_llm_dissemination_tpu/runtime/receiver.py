@@ -3813,6 +3813,7 @@ class FlowRetransmitReceiverNode(RetransmitReceiverNode):
         dup_done = False
         foreign = False
         first_frag = False
+        received = None
         with self._lock:
             if lid in self.layers:
                 # A re-plan duplicate of a finished layer: drop the bytes
@@ -3881,12 +3882,19 @@ class FlowRetransmitReceiverNode(RetransmitReceiverNode):
                 # re-plan duplicate's ranges were journaled by their
                 # claim-holders already.
                 journal = self.ckpt is not None and bool(claims)
-                log.info(
-                    "layer fragment stored",
-                    layerID=lid, offset=frag.offset, size=frag.data_size,
-                    received=cov.covered_bytes(),
-                    total=msg.total_size,
-                )
+                received = cov.covered_bytes()
+        if received is not None:
+            # Logged AFTER the lock is let go: a log line is a
+            # ``json.dumps``, the logger's process-wide lock, a ``write``
+            # and a ``flush`` — a syscall, so a GIL drop, once a frame
+            # on each handler thread — and ``_lock`` is what every
+            # frame's claim in ``_layer_sink`` waits for.
+            log.info(
+                "layer fragment stored",
+                layerID=lid, offset=frag.offset, size=frag.data_size,
+                received=received,
+                total=msg.total_size,
+            )
         if first_frag:
             # Pair-lifecycle span (docs/observability.md): the wire is
             # live — dispatched→first_byte is the transfer's startup
@@ -3985,6 +3993,7 @@ class FlowRetransmitReceiverNode(RetransmitReceiverNode):
             # The journaled range's crc32 rides the meta journal so
             # resume re-verifies the DISK bytes (integrity hardening).
             frag_crc = integrity.fragment_crc(data)
+            crc_cap_hit = False
             with self._lock:
                 raced_completion = lid in self.layers
                 if not raced_completion:
@@ -3996,12 +4005,13 @@ class FlowRetransmitReceiverNode(RetransmitReceiverNode):
                     if crcs is not None:
                         crcs.append((off, len(data), frag_crc))
                         if len(crcs) > _JOURNAL_CRC_MAX_RECORDS:
-                            log.warn("journal CRC record cap hit; this "
-                                     "layer's journal falls back to the "
-                                     "un-verified legacy format",
-                                     layerID=lid)
+                            crc_cap_hit = True
                             crcs = self._durable_crcs[lid] = None
                     crcs_snapshot = list(crcs) if crcs is not None else None
+            if crc_cap_hit:
+                log.warn("journal CRC record cap hit; this layer's "
+                         "journal falls back to the un-verified legacy "
+                         "format", layerID=lid)
             if not raced_completion:
                 self.ckpt.write_meta(lid, durable, total,
                                      frag_crcs=crcs_snapshot)

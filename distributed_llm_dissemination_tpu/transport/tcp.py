@@ -930,16 +930,23 @@ class TcpTransport(Transport):
         logical payload (plain receivers expect whole messages), landing
         each stripe at ``stripe_off`` in one shared buffer."""
         t0 = time.monotonic()
-        with self._lock:
-            # First striped arrival arms the background sweeper — the
-            # TTL owner for ALL striped-receive state (groups,
-            # tombstones, relay records), including the last abandoned
-            # transfer that no later arrival would ever sweep.
-            if not self._stripe_sweeper_started:
-                self._stripe_sweeper_started = True
-                threading.Thread(target=self._stripe_sweep_loop,
-                                 name="tcp-stripe-sweep",
-                                 daemon=True).start()
+        # First striped arrival arms the background sweeper — the TTL
+        # owner for ALL striped-receive state (groups, tombstones, relay
+        # records), including the last abandoned transfer that no later
+        # arrival would ever sweep.  The flag is only ever set, so every
+        # later frame reads it without the lock; the one arrival that
+        # wins it starts the thread after letting the lock go (a
+        # ``Thread.start`` waits for the new thread, which the first
+        # frames of seven other receive threads would wait behind).
+        start_sweeper = False
+        if not self._stripe_sweeper_started:
+            with self._lock:
+                if not self._stripe_sweeper_started:
+                    self._stripe_sweeper_started = start_sweeper = True
+        if start_sweeper:
+            threading.Thread(target=self._stripe_sweep_loop,
+                             name="tcp-stripe-sweep",
+                             daemon=True).start()
         pipe_sock = self._stripe_pipe_sock(header, envelope)
         key = (header.src_id, header.layer_id, header.stripe_tid)
         landed = False

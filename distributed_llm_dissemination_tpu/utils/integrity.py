@@ -15,7 +15,12 @@ vocabulary of the integrity plane:
   (``hash_bench`` on the running host):
   xxh3-64 when the ``xxhash`` extension is importable — it is the only
   candidate that tracks the wire rate here (~6x stdlib ``zlib.crc32``)
-  — falling back to crc32 otherwise.  Negotiation is per frame,
+  — falling back to crc32 otherwise.  A frame-sized buffer is hashed
+  with the GIL RELEASED (``_xxh3_64``: the streaming object's
+  ``update`` from ``_XXH3_RELEASE_MIN`` bytes on; the one-shot
+  ``xxh3_64_intdigest`` never lets go, and held every other thread of
+  the process still for a millisecond a 16 MB frame), the same 64 bits
+  by either call.  Negotiation is per frame,
   omitted-field style: the header carries ``Xxh3`` or ``Crc``, and the
   receiver verifies whichever is present (a receiver without ``xxhash``
   treats an xxh3-stamped frame as unstamped — advisory, never a drop).
@@ -58,6 +63,17 @@ DIGEST_SIZE = 16
 
 _DIGEST_CHUNK = 8 << 20  # streaming-digest read granularity
 
+# Frame checksums of at least this many bytes go through the STREAMING
+# xxh3 object, whose ``update`` releases the GIL; shorter ones through the
+# one-shot call, which never does (``_xxh3_64``).  Measured on the chip's
+# host (docs/integrity.md "Which calls release the GIL"; PERF.md §6,
+# PR 36): four threads that hash side by side take 4.2-4.6 x one thread's
+# time through the one-shot call at every length; through ``update``
+# 144 x at 4 KiB, 8.4 x at 256 KiB, 4.2 x at 512 KiB (a release a call
+# costs a hand-off of the GIL, which short hashes do not outlast), 1.8 x
+# at 1 MiB, 1.2-1.3 x from 2 MiB on.
+_XXH3_RELEASE_MIN = 1 << 20
+
 
 def wire_crc_enabled() -> bool:
     """Per-fragment wire CRC (default ON; ``DLD_WIRE_CRC=0`` disables)."""
@@ -77,15 +93,29 @@ def fragment_crc(view) -> int:
     return zlib.crc32(view) & 0xFFFFFFFF
 
 
+def _xxh3_64(view) -> int:
+    """xxh3-64 of a buffer, the same 64 bits by either call: the
+    one-shot ``xxh3_64_intdigest`` holds the GIL for the whole buffer
+    (every thread that dropped it for a syscall or a taken lock queues
+    behind the hashers), ``xxh3_64().update`` releases it at any
+    length.  The input's length picks the call (``_XXH3_RELEASE_MIN``)."""
+    if memoryview(view).nbytes < _XXH3_RELEASE_MIN:
+        return _xxhash.xxh3_64_intdigest(view)
+    h = _xxhash.xxh3_64()
+    h.update(view)
+    return h.intdigest()
+
+
 def fragment_checksum(view) -> Tuple[str, int]:
     """The checksum a SENDER stamps on a frame: ``("xxh3", v)`` when the
-    ``xxhash`` extension is importable, else ``("crc32", v)``.  Both C
-    implementations release the GIL for large buffers, so concurrent
-    stripe receivers really verify in parallel — and xxh3 sustains ~6x
-    the crc32 rate on this host (``hash_bench``), which is what keeps
-    the per-stripe check off the wire's critical path."""
+    ``xxhash`` extension is importable, else ``("crc32", v)``.  Both
+    release the GIL for a frame-sized buffer (``zlib.crc32`` itself,
+    xxh3 through ``_xxh3_64``), so concurrent stripe senders and
+    receivers really hash in parallel — and xxh3 sustains ~6x the crc32
+    rate on this host (``hash_bench``), which is what keeps the
+    per-stripe check off the wire's critical path."""
     if _xxhash is not None:
-        return "xxh3", _xxhash.xxh3_64_intdigest(view)
+        return "xxh3", _xxh3_64(view)
     return "crc32", zlib.crc32(view) & 0xFFFFFFFF
 
 
@@ -96,7 +126,7 @@ def checksum_of(view, algo: str) -> Optional[int]:
     if algo == "crc32":
         return zlib.crc32(view) & 0xFFFFFFFF
     if algo == "xxh3" and _xxhash is not None:
-        return _xxhash.xxh3_64_intdigest(view)
+        return _xxh3_64(view)
     return None
 
 
@@ -108,7 +138,7 @@ def verify_stamp(view, crc: Optional[int] = None,
     with no ``xxhash`` here (advisory: unverifiable never reads as
     corrupt) — else whether the payload matches."""
     if xxh3 is not None and _xxhash is not None:
-        return _xxhash.xxh3_64_intdigest(view) == xxh3
+        return _xxh3_64(view) == xxh3
     if crc is not None:
         return (zlib.crc32(view) & 0xFFFFFFFF) == crc
     return None
@@ -364,7 +394,7 @@ def hash_bench(nbytes: int = 64 << 20) -> dict:
         "xxh3_128_gbps": 0.0,
     }
     if _xxhash is not None:
-        out["xxh3_64_gbps"] = rate(lambda b: _xxhash.xxh3_64_intdigest(b))
+        out["xxh3_64_gbps"] = rate(_xxh3_64)
         out["xxh3_128_gbps"] = rate(
             lambda b: _xxhash.xxh3_128_hexdigest(b))
     out["fragment_algo"] = fragment_checksum(buf[:16])[0]

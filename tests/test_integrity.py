@@ -144,6 +144,100 @@ def test_hash_bench_shape():
         assert rates[key] > 0
 
 
+_REL = integrity._XXH3_RELEASE_MIN
+
+
+def _as_container(kind: str, data: bytes):
+    if kind == "bytes":
+        return data
+    if kind == "bytearray":
+        return bytearray(data)
+    if kind == "memoryview":
+        # A slice at an odd offset of a larger buffer, as a frame's view
+        # into a reassembly buffer is.
+        return memoryview(bytearray(b"\x5a" * 3 + data + b"\xa5" * 5))[
+            3 : 3 + len(data)]
+    import numpy as np
+
+    return np.frombuffer(bytearray(data), dtype=np.uint8)
+
+
+@pytest.mark.parametrize("kind",
+                         ["bytes", "bytearray", "memoryview", "numpy"])
+@pytest.mark.parametrize(
+    "length", [0, 1, 4096, _REL - 1, _REL, _REL + 1, 16 << 20],
+    ids=["0", "1", "4KiB", "below", "threshold", "above", "16MiB"])
+def test_frame_checksum_same_bits_every_length(length, kind):
+    """The frame stamp is xxh3-64 of the bytes whichever call computes
+    it (one-shot below ``_XXH3_RELEASE_MIN``, the GIL-releasing
+    ``update`` from it on): a frame stamped by the old code verifies
+    under the new and the reverse."""
+    xxhash = pytest.importorskip("xxhash")
+    data = (layer_bytes(11, 4099) * (length // 4099 + 1))[:length]
+    v = _as_container(kind, data)
+    want = xxhash.xxh3_64_intdigest(data)
+    assert integrity.fragment_checksum(v) == ("xxh3", want)
+    assert integrity.checksum_of(v, "xxh3") == want
+    assert integrity.verify_stamp(v, xxh3=want) is True
+    if length:
+        flipped = bytearray(data)
+        flipped[length // 2] ^= 0x10
+        assert integrity.verify_stamp(
+            _as_container(kind, bytes(flipped)), xxh3=want) is False
+    else:
+        assert integrity.verify_stamp(v, xxh3=want ^ 1) is False
+
+
+def ticker_gap(fn):
+    """Run ``fn`` on this thread beside a ticker thread that spins in
+    pure Python: ``(fn's seconds, the ticker's longest gap)``.  A native
+    call that holds the GIL shows as a gap as long as the call; one that
+    releases it as about one switch interval (PERF.md §6, PR 36, has the
+    chip host's readings of this probe)."""
+    stop, started, gap = threading.Event(), threading.Event(), [0.0]
+
+    def tick():
+        last = time.monotonic()
+        started.set()
+        while not stop.is_set():
+            now = time.monotonic()
+            gap[0] = max(gap[0], now - last)
+            last = now
+
+    t = threading.Thread(target=tick, name="gil-ticker", daemon=True)
+    t.start()
+    started.wait()
+    time.sleep(0.02)  # the ticker is spinning, and holds the GIL
+    gap[0] = 0.0
+    t0 = time.monotonic()
+    fn()
+    took, longest = time.monotonic() - t0, gap[0]
+    stop.set()
+    t.join()
+    return took, longest
+
+
+def test_frame_checksum_lets_python_run():
+    """While one thread verifies a long frame, another thread keeps
+    running Python: the ticker's longest gap stays under half the hash's
+    time (the one-shot call stalled it for four fifths).  Best of three,
+    so a busy host's scheduler does not decide it."""
+    xxhash = pytest.importorskip("xxhash")
+    view = memoryview(bytearray(os.urandom(1 << 20)) * 256)
+    want = xxhash.xxh3_64_intdigest(view)
+    tries = []
+    for _ in range(3):
+        ok = []
+        took, gap = ticker_gap(
+            lambda: ok.append(integrity.verify_stamp(view, xxh3=want)))
+        assert ok == [True]
+        tries.append((gap / took, gap, took))
+        if gap < took / 2:
+            return
+    pytest.fail(f"ticker stalled for over half the hash in every try: "
+                f"{tries}")
+
+
 def test_fault_rules_deterministic():
     seed, rules = rules_from_spec("seed=2,corrupt=3,times=2")
     assert seed == 2

@@ -348,12 +348,12 @@ def two_seat_records():
            parent="wire.send", bytes=1 << 20, offset=1 << 20, cpu=0.5),
     ]
     dest = [
-        sp("wire.serve", 10.5, 11.75, 1, "data-rx-0", bytes=1 << 20,
+        sp("wire.serve", 10.375, 11.75, 1, "data-rx-0", bytes=1 << 20,
            offset=0, queued_s=0.125),
         sp("wire.recv", 10.5, 11.5, 1, "data-rx-0", parent="wire.serve",
            bytes=1 << 20, offset=0),
         sp("wire.crc", 11.5, 11.625, 1, "data-rx-0", parent="wire.serve"),
-        sp("wire.serve", 11.0, 12.5, 1, "data-rx-1", bytes=1 << 20,
+        sp("wire.serve", 10.75, 12.5, 1, "data-rx-1", bytes=1 << 20,
            offset=1 << 20, queued_s=0.25),
         sp("wire.recv", 11.0, 12.0, 1, "data-rx-1", parent="wire.serve",
            bytes=1 << 20, offset=1 << 20),
@@ -384,15 +384,25 @@ def test_the_hop_block_adds_a_delivery_up_from_both_ends():
     assert hop["reading"] == {"0": 0.5, "1-3": 1.5, "4-7": 0.0, "8+": 0.0}
     assert (hop["frames_joined"], hop["frames_read"]) == (2, 2)
     assert hop["lag_ms"] == {"median": 375.0, "p90": 500.0}
+    # a frame's self time splits at its first read: picked up an eighth
+    # and a quarter of a second before it, handed back an eighth (after
+    # the checksum) and a half (after the read: no checksum there) after
     assert hop["receive_pools"] == {"1": {
-        "threads": 2, "frames": 2, "busy_s": 2.75, "occupancy": 0.6875,
-        "queued_s": 0.375, "self_s": 0.625}}
+        "threads": 2, "frames": 2, "busy_s": 3.125, "occupancy": 0.7812,
+        "queued_s": 0.375, "self_s": 1.0,
+        "before_read_s": 0.375,
+        "before_read_ms": {"median": 187.5, "p90": 250.0},
+        "after_verify_s": 0.625,
+        "after_verify_ms": {"median": 312.5, "p90": 500.0}}}
     assert hop["cpu_ms"] == {"0": {"cpu_ms": 1400, "sys_ms": 600},
                              "1": {"cpu_ms": 3100, "sys_ms": 900}}
     out = io.StringIO()
     cli_trace.print_wire_hop(hop, out)
     text = out.getvalue()
-    assert "4.0 -> 1.0 MiB/s" in text and "occupancy 0.6875" in text
+    assert "4.0 -> 1.0 MiB/s" in text and "occupancy 0.7812;" in text
+    assert ("before the first wire.recv 0.375 s (a frame: median 187.5 ms, "
+            "p90 250.0 ms), after the last child 0.625 s (median 312.5 ms, "
+            "p90 500.0 ms)") in text
     assert "median 375.0 ms, p90 500.0 ms" in text
     assert "seat 0: proc.cpu_ms 1400 (system 600)" in text
     # logs without a flow job have no hop to show

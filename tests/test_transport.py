@@ -635,3 +635,96 @@ def test_data_pool_retries_stale_connection():
                      .layer_src.inmem_data) == b"second"
     finally:
         close_all(ts)
+
+
+class _OwnedLock:
+    """A ``threading.Lock`` that knows which thread holds it."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.owner = None
+
+    def acquire(self, *args, **kwargs):
+        got = self._lock.acquire(*args, **kwargs)
+        if got:
+            self.owner = threading.get_ident()
+        return got
+
+    def release(self):
+        self.owner = None
+        self._lock.release()
+
+    def locked(self):
+        return self._lock.locked()
+
+    def __enter__(self):
+        return self.acquire()
+
+    def __exit__(self, *exc):
+        self.release()
+
+
+def test_no_log_line_under_the_receivers_lock(small_stripes, monkeypatch):
+    """A log line is ``json.dumps``, the logger's process-wide lock, a
+    ``write`` and a ``flush`` — a syscall, so a GIL drop — and
+    ``ReceiverNode._lock`` is what every frame's claim (``_layer_sink``)
+    and commit (``handle_layer``) take: no thread may emit a line while
+    it holds it.  A striped mode-3 delivery over real TCP; every
+    fragment's ``"layer fragment stored"`` line still appears, with its
+    fields."""
+    from distributed_llm_dissemination_tpu.runtime import (
+        FlowRetransmitLeaderNode,
+        FlowRetransmitReceiverNode,
+        Node,
+    )
+    from distributed_llm_dissemination_tpu.runtime import send as send_mod
+    from distributed_llm_dissemination_tpu.utils import logging as dld_logging
+
+    size, frag = 1024 * 1024, 64 * 1024
+    # Stripe whatever rate the plan commands: a fragment is then four
+    # stripes of ``frag`` bytes, each its own frame through the sink.
+    monkeypatch.setattr(small_stripes, "STRIPE_PACED_MIN_RATE", 1)
+    monkeypatch.setattr(send_mod, "FLOW_FRAGMENT_BYTES", frag)
+    payload = bytes((7 * i + i // 251) % 256 for i in range(size))
+    ts = make_transports("tcp", 2)
+    owned = _OwnedLock()
+    under_lock, stored = [], []
+    real_emit = dld_logging.JsonLogger._emit
+
+    def checked_emit(self, level, message, **fields):
+        if owned.owner == threading.get_ident():
+            under_lock.append(message)
+        if message == "layer fragment stored":
+            stored.append(fields)
+        return real_emit(self, level, message, **fields)
+
+    monkeypatch.setattr(dld_logging.JsonLogger, "_emit", checked_emit)
+    leader = FlowRetransmitLeaderNode(
+        Node(0, 0, ts[0]), {0: _mem_layer(payload)}, {1: {0: LayerMeta()}},
+        node_network_bw={0: 10 ** 10, 1: 10 ** 10})
+    receiver = FlowRetransmitReceiverNode(Node(1, 0, ts[1]), {},
+                                          start_loop=False)
+    receiver._lock = owned
+    receiver.loop.start()
+    try:
+        receiver.announce()
+        leader.ready().get(timeout=10.0)
+        receiver.ready().get(timeout=10.0)
+        assert bytes(receiver.layers[0].inmem_data) == payload
+    finally:
+        leader.close()
+        receiver.close()
+        close_all(ts)
+    assert under_lock == []
+    # Every fragment logged its store, outside the lock: the lines tile
+    # the layer, ``received`` is the coverage at each commit and ends at
+    # the whole layer.
+    assert len(stored) == size // frag
+    assert all(set(f) >= {"layerID", "offset", "size", "received", "total"}
+               for f in stored)
+    assert all(f["total"] == size and 0 < f["received"] <= size
+               for f in stored)
+    spans = sorted((f["offset"], f["offset"] + f["size"]) for f in stored)
+    assert spans[0][0] == 0 and spans[-1][1] == size
+    assert all(a[1] == b[0] for a, b in zip(spans, spans[1:]))
+    assert max(f["received"] for f in stored) == size
