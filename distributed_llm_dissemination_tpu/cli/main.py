@@ -110,7 +110,9 @@ def build_parser() -> argparse.ArgumentParser:
                    help="receiver: after a successful boot, stay alive "
                         "this many seconds answering GenerateReqMsg "
                         "inference requests (cli.genreq) from the "
-                        "resident params; 0 = exit after boot as before")
+                        "resident params, and until no request has been "
+                        "in flight for as long; 0 = exit after boot as "
+                        "before")
     p.add_argument("-report", type=str, default="",
                    help="write RUN_REPORT.{json,md} at this path/prefix "
                         "when the run completes (cli/report.py): TTD/"
@@ -1040,7 +1042,17 @@ def run_receiver(args, conf: cfg.Config, node: Node, layers) -> int:
         ulog.log.info("serving generation requests",
                       window_s=args.serve)
         print(f"serving for {args.serve:g}s", flush=True)
-        time.sleep(args.serve)
+        # The window closes once it has been open for that long AND the
+        # node has been quiet for as long: a first request of a new
+        # shape compiles for longer than many a window, and its
+        # requester's next would find the door shut.
+        closes = time.monotonic() + args.serve
+        while True:
+            left = max(closes - time.monotonic(),
+                       args.serve - receiver.serve_quiet_s())
+            if left <= 0:
+                break
+            time.sleep(left)
     if args.daemon > 0:
         # Dissemination service (docs/service.md): the leader daemon
         # keeps admitting jobs, so this seat keeps receiving (and

@@ -22,8 +22,8 @@ Mapping:
   the wall clock by the ``"span counters"`` record's clock pair; the
   ``decode.stage`` spans' ``fast_bytes`` / ``slow_bytes``, the
   ``boot.assemble`` spans' ``kinds``, the ``serve.generate`` spans'
-  ``moe_slots`` / ``moe_held`` / ``moe_touched`` and the
-  ``fabric.publish`` spans' ``bytes`` / ``host_copy_bytes`` / ``pieces``
+  ``moe_slots`` / ``moe_held`` / ``moe_touched`` and ``kv_rows`` /
+  ``swa_evicted`` and the ``fabric.publish`` spans' ``bytes`` / ``host_copy_bytes`` / ``pieces``
   are added up and printed on stderr, and beside them the counters
   ``wire.buf.reused_bytes`` / ``wire.buf.fresh_bytes`` and
   ``wire.pace.job_bytes`` / ``wire.pace.wait_ms`` of the
@@ -295,6 +295,16 @@ def routed_slot_totals(events: List[dict]) -> dict:
     adds nothing) added up.  Empty for a family that routes nothing."""
     return _field_totals(events, "serve.generate",
                          ("moe_slots", "moe_held", "moe_touched"))
+
+
+def cache_row_totals(events: List[dict]) -> dict:
+    """What the logs' served requests kept of their positions
+    (``models/trinity.py``): the ``serve.generate`` slices' ``kv_rows``
+    (K/V rows the caches hold at a request's end, all layers) and
+    ``swa_evicted`` (positions a sliding-window layer's ring wrote over
+    or never kept) added up.  Empty for a family that counts neither."""
+    return _field_totals(events, "serve.generate",
+                         ("kv_rows", "swa_evicted"))
 
 
 def draft_totals(events: List[dict]) -> dict:
@@ -904,6 +914,11 @@ def main(argv: list[str] | None = None) -> int:
               "to experts held here, over {moe_touched} expert-reads a "
               "gathered dispatch would make ({spans} spans)"
               .format(**routed), file=sys.stderr)
+    rows = cache_row_totals(events)
+    if rows:
+        print("serve.generate left {kv_rows} K/V rows in its caches, "
+              "{swa_evicted} positions written over in sliding-window "
+              "rings ({spans} spans)".format(**rows), file=sys.stderr)
     drafted = draft_totals(events)
     if drafted:
         print("serve.generate decoded in {decode_steps} steps, "

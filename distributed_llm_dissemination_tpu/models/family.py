@@ -31,7 +31,8 @@ kind as the last argument of ``layer_param_specs`` and
 handed.  This table is the one place that says which kind a layer id is
 (``layer_kinds``), and it holds what follows from that: a blob's leaves
 by its id (``layer_param_specs``), the layers of each kind (``group``),
-the runs of one kind in the stack's order (``runs``), and how the
+the stack's order cut into stretches (``stretches``, which
+``scan_stack`` walks), and how the
 parameters and the serving state are held: stacked BY KIND,
 ``{kind: {leaf: [layers of the kind, ...]}}``.  A family without the
 hook is the case of one kind, and its tree stays what it always was —
@@ -45,12 +46,12 @@ family names such kinds in ``side_kinds(cfg)``; their layer ids come
 after the stack's.  Everything that handles BLOBS treats the kind as one
 more (its leaves, its decode program, its stack of parameters and of
 serving state: ``layer_kinds``, ``group``, ``stack``); what walks the
-STACK leaves it out (``runs``, ``run_slices``).  A family that has such
+STACK leaves it out (``stretches``, ``scan_stack``).  A family that has such
 a module and can draft with it says so in ``drafts(cfg)`` and answers
 ``draft(params, h, nxt, positions, cache, cfg, at)`` (``drafter``).
 
-``serde`` and ``quant`` (blob layout), ``llama.forward`` (a scan over
-each run of stacked layers), ``generate`` (prefill and decode) and
+``serde`` and ``quant`` (blob layout), ``llama.forward`` and ``generate``
+(prefill and decode; both through ``scan_stack``) and
 ``runtime/boot.py`` ask here; nothing else branches on a family.  The
 table imports a family module on first use, so this file imports none of
 them.
@@ -59,13 +60,14 @@ them.
 from __future__ import annotations
 
 import importlib
-from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 # family name -> module beside this file
 FAMILIES: Dict[str, str] = {"llama": ".llama", "longcat": ".longcat",
-                            "lfm2": ".lfm2", "joyai": ".joyai"}
+                            "lfm2": ".lfm2", "joyai": ".joyai",
+                            "trinity": ".trinity"}
 # The one kind of a family whose layers are all alike.
 ONE_KIND = "layer"
 
@@ -184,25 +186,37 @@ def group(cfg, layer_ids: Optional[Sequence[int]] = None
     return out
 
 
-def runs(cfg, layer_ids: Optional[Sequence[int]] = None
-         ) -> List[Tuple[str, int, int]]:
-    """The stack in order as runs of one kind: ``(kind, start, stop)``,
-    ``start:stop`` the run's place in its kind's stack.  A uniform family
-    is one run.  A blob beside the stack (``side_kinds``) is in no run."""
-    kinds = layer_kinds(cfg)
+def stretches(cfg, layer_ids: Optional[Sequence[int]] = None
+              ) -> List[List[Tuple[str, int]]]:
+    """The stack in order, cut into stretches that ``scan_stack`` scans
+    one at a time: each a list of ``(kind, place)``, a layer's kind and
+    its place in that kind's stack.  A run of one kind that is ALL of its
+    kind's stack is a stretch of its own (a uniform family is one); the
+    runs between such — kinds that alternate, each run a part of its
+    kind's stack — are one stretch together, however many periods they
+    go on for.  A blob beside the stack (``side_kinds``) is in none."""
     aside = side_kinds(cfg)
+    kinds = [k for k in (layer_kinds(cfg)[lid] for lid in _ids(cfg, layer_ids))
+             if k not in aside]
     seen: Dict[str, int] = {}
-    out: List[Tuple[str, int, int]] = []
-    for lid in _ids(cfg, layer_ids):
-        kind = kinds[lid]
-        if kind in aside:
-            continue
-        at = seen.get(kind, 0)
-        seen[kind] = at + 1
-        if out and out[-1][0] == kind:
-            out[-1] = (kind, out[-1][1], at + 1)
+    out: List[List[Tuple[str, int]]] = []
+    mixed = None  # the stretch of partial runs being gathered
+    at = 0
+    while at < len(kinds):
+        kind, end = kinds[at], at
+        while end < len(kinds) and kinds[end] == kind:
+            end += 1
+        run = [(kind, seen.get(kind, 0) + i) for i in range(end - at)]
+        seen[kind] = seen.get(kind, 0) + len(run)
+        if len(run) == kinds.count(kind):
+            out.append(run)
+            mixed = None
         else:
-            out.append((kind, at, at + 1))
+            if mixed is None:
+                mixed = []
+                out.append(mixed)
+            mixed.extend(run)
+        at = end
     return out
 
 
@@ -234,18 +248,101 @@ def stack(cfg, layer_ids: Sequence[int], leaves_of: Callable[[int], Dict],
     return of_kinds(cfg, out)
 
 
-def run_slices(cfg, trees: tuple, layer_ids: Optional[Sequence[int]] = None
-               ) -> Iterator[Tuple[str, int, int, tuple]]:
-    """``(kind, start, stop, slices)`` for each run of ``runs``: the run's
-    part of its kind's stack in each of ``trees`` (parameters, state; each
-    as the family holds it) — the stack itself where the run is all of
-    it, so a uniform family's one run is its trees untouched."""
-    import jax
+def scan_stack(cfg, step: Callable, x, params, state=None,
+               layer_ids: Optional[Sequence[int]] = None):
+    """``x`` through the blocks of ``layer_ids`` (default: the stack) in
+    order: ``step(x, layer_params, layer_state) -> (x, layer_state,
+    counters)`` once a layer, ``params`` and ``state`` (or None) each as
+    the family holds it.  Returns (x, the state after, the counters added
+    up over the layers).
 
-    kinds = [by_kind(cfg, tree) for tree in trees]
-    for kind, start, stop in runs(cfg, layer_ids):
-        n = jax.tree.leaves(kinds[0][kind])[0].shape[0]
-        yield kind, start, stop, tuple(
-            k[kind] if (start, stop) == (0, n)
-            else jax.tree.map(lambda a: a[start:stop], k[kind])
-            for k in kinds)
+    One ``lax.scan`` for each stretch of ``stretches``.  A stretch that
+    is all of one kind's stack scans over that stack (a uniform family's
+    one scan over its tree).  A stretch of kinds that alternate scans
+    over its layers with a ``lax.switch`` on the layer's kind: one traced
+    body a KIND however many runs there are.  A branch takes its layer's
+    leaves out of its kind's stack by index, as a scan does with what it
+    scans over, so no part of a stack is sliced out and none is copied;
+    the state rows of the stretch's kinds are taken out before the
+    switch and put back after it (a kind that the layer is not has its
+    row put back as it was), so no branch passes a whole stack of state
+    through."""
+    import jax
+    import jax.numpy as jnp
+
+    held = by_kind(cfg, params)
+    rows = None if state is None else dict(by_kind(cfg, state))
+    total: Dict[str, Any] = {}
+
+    def take(tree, at):
+        return jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, at, 0, False), tree)
+
+    for stretch in stretches(cfg, layer_ids):
+        kinds = list(dict.fromkeys(kind for kind, _ in stretch))
+        if len(kinds) == 1 and len(stretch) == jax.tree.leaves(
+                held[kinds[0]])[0].shape[0]:
+            def body(x, scanned):
+                x, new, counted = step(x, *scanned)
+                return x, (new, counted)
+
+            x, (new, counted) = jax.lax.scan(
+                body, x, (held[kinds[0]],
+                          None if rows is None else rows[kinds[0]]))
+            if rows is not None:
+                rows[kinds[0]] = new
+        else:
+            # Each kind's place at every layer of the stretch: the
+            # layer's own where it is of that kind, else the kind's
+            # nearest (its row is read and put back unchanged).
+            where = {k: [] for k in kinds}
+            near = {k: next(p for kind, p in stretch if kind == k)
+                    for k in kinds}
+            for kind, place in stretch:
+                near[kind] = place
+                for k in kinds:
+                    where[k].append(near[k])
+
+            def body(carry, layer, kinds=kinds):
+                x, mine = carry
+                which, at = layer
+                taken = (None if mine is None
+                         else {k: take(mine[k], at[k]) for k in kinds})
+
+                def one(kind, fill):
+                    def branch(x, taken):
+                        x, new, counted = step(
+                            x, take(held[kind], at[kind]),
+                            None if taken is None else taken[kind])
+                        return (x, None if taken is None
+                                else {**taken, kind: new},
+                                {**fill, **counted})
+                    return branch
+
+                # the kinds' counters, so that every branch answers with
+                # the same names (a kind that does not count one adds 0)
+                names = {}
+                for kind in kinds:
+                    names.update(jax.eval_shape(one(kind, {}), x, taken)[2])
+                fill = {n: jnp.zeros(c.shape, c.dtype)
+                        for n, c in names.items()}
+                x, taken, counted = jax.lax.switch(
+                    which, [one(kind, fill) for kind in kinds], x, taken)
+                if mine is not None:
+                    mine = {k: jax.tree.map(
+                        lambda a, row, k=k:
+                        jax.lax.dynamic_update_index_in_dim(a, row, at[k], 0),
+                        mine[k], taken[k]) for k in kinds}
+                return (x, mine), counted
+
+            mine = None if rows is None else {k: rows[k] for k in kinds}
+            (x, mine), counted = jax.lax.scan(
+                body, (x, mine),
+                (jnp.asarray([kinds.index(kind) for kind, _ in stretch]),
+                 {k: jnp.asarray(v) for k, v in where.items()}))
+            if rows is not None:
+                rows.update(mine)
+        for name, c in counted.items():
+            c = c.sum(0)
+            total[name] = total[name] + c if name in total else c
+    return x, (None if rows is None else of_kinds(cfg, rows)), total

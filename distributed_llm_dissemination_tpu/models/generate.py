@@ -94,32 +94,15 @@ def init_cache(cfg, batch: int, max_len: int) -> Cache:
 def _hidden_with_cache(params, tokens, positions, cache, cfg):
     """Stacked-layer forward that threads the cache; returns (the stack's
     last hidden state ``[b, s, d]``, updated cache, the blocks' counters
-    added up over the layers).  One ``lax.scan`` for each run of one kind
-    of layer (``family.run_slices``: a uniform family's one scan over its
-    whole tree), each over the run's part of the parameters and of the
-    state."""
+    added up over the layers): ``family.scan_stack`` over the parameters
+    and the state (a uniform family's one scan over its whole tree)."""
     fam = family.of(cfg)
-    x = fam.embed(params, tokens, cfg)
 
-    def body(x, scanned):
-        layer_p, layer_cache = scanned
-        x, layer_cache, counted = fam.layer_with_cache(
-            layer_p, x, positions, layer_cache, cfg
-        )
-        return x, (layer_cache, counted)
+    def step(x, layer_p, layer_cache):
+        return fam.layer_with_cache(layer_p, x, positions, layer_cache, cfg)
 
-    state = dict(family.by_kind(cfg, cache))
-    total: Counters = {}
-    for kind, start, stop, run in family.run_slices(
-            cfg, (params["layers"], cache)):
-        x, (new, counted) = jax.lax.scan(body, x, run)
-        state[kind] = jax.tree.map(
-            lambda old, part: part if part.shape == old.shape
-            else old.at[start:stop].set(part), state[kind], new)
-        for name, c in counted.items():
-            total[name] = (total[name] + c.sum(0) if name in total
-                           else c.sum(0))
-    return x, family.of_kinds(cfg, state), total
+    return family.scan_stack(cfg, step, fam.embed(params, tokens, cfg),
+                             params["layers"], cache)
 
 
 def _forward_with_cache(params, tokens, positions, cache, cfg):

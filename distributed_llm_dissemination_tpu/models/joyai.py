@@ -46,6 +46,10 @@ alike: a sum over the ranks counts it once), and a pick of an absent
 expert adds nothing.  Nothing here stands in for the other ranks or their
 exchange.
 
+The router and the share's dispatch are ``models/routed.py``'s
+(``models/trinity.py``'s too; this family's selection bias is the leaf
+``gate_bias``).
+
 Arithmetic as ``models/lfm2.py``: float32 between the products and INTO
 them — a product with weights takes the activations as two ``cfg.dtype``
 terms (``lfm2._mm``); the attention's own products, the router and the
@@ -64,7 +68,7 @@ from typing import Any, Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 
-from . import mla
+from . import mla, routed
 from .lfm2 import _mm
 from .llama import Spec
 from .mla import _rms
@@ -215,46 +219,6 @@ def init_head_params(cfg: JoyaiConfig, k_emb: jax.Array,
 
 # ------------------------------------------------------------------- blocks
 
-def _swiglu(xn, w1, w3, w2):
-    gate = jax.nn.silu(_mm("bsd,df->bsf", xn, w1))
-    return _mm("bsf,fd->bsd", gate * _mm("bsd,df->bsf", xn, w3), w2)
-
-
-def route(p, xn, cfg: JoyaiConfig):
-    """A token's picks among ALL router outputs and their weights:
-    ``(idx [b, s, top_k] int32, w [b, s, top_k] float32)``.  The bias
-    picks and does not weigh; the weights are renormalised over every
-    pick, whichever rank holds its expert."""
-    scores = jax.nn.sigmoid(
-        jnp.einsum("bsd,de->bse", xn.astype(jnp.float32),
-                   p["gate"].astype(jnp.float32), precision=_EXACT))
-    _, idx = jax.lax.top_k(scores + p["gate_bias"].astype(jnp.float32),
-                           cfg.top_k)
-    w = jnp.take_along_axis(scores, idx, axis=-1)
-    return idx, w / (w.sum(-1, keepdims=True) + 1e-20) * cfg.route_scale
-
-
-def routed_part(p, xn, idx, w, cfg: JoyaiConfig):
-    """This rank's experts' part of the routed block's output (float32),
-    by dense dispatch (every held expert runs over every token, unpicked
-    pairs weigh zero); a slot that picked an absent expert adds nothing.
-    Also what was counted: ``moe_slots`` (positions x top_k), ``moe_held``
-    (slots whose expert is here), ``moe_touched`` (distinct held experts
-    that got a slot in this call — what a gathered dispatch would
-    read)."""
-    held = (idx[..., None] - cfg.expert_first
-            == jnp.arange(cfg.experts_held))  # [b, s, top_k, held]
-    gate = (w[..., None] * held).sum(-2)  # [b, s, held]
-    g = jax.nn.silu(_mm("bsd,edf->besf", xn, p["ew1"]))
-    out = _mm("besf,efd->besd", g * _mm("bsd,edf->besf", xn, p["ew3"]),
-              p["ew2"])
-    mixed = jnp.einsum("besd,bse->bsd", out, gate, precision=_EXACT)
-    return mixed, {
-        "moe_slots": jnp.asarray(idx.size, jnp.int32),
-        "moe_held": jnp.sum(held, dtype=jnp.int32),
-        "moe_touched": jnp.sum(held.any((0, 1, 2)), dtype=jnp.int32)}
-
-
 def layer_with_cache(p, x, positions, cache, cfg: JoyaiConfig):
     """One layer of whichever kind ``p``'s leaves say (the module's block
     is a routed layer), float32 between its products; the result takes
@@ -287,14 +251,14 @@ def layer_with_cache(p, x, positions, cache, cfg: JoyaiConfig):
     counted = {}
     if "gate" in p:
         with jax.named_scope("model.moe.route"):
-            idx, w = route(p, xn, cfg)
+            idx, w = routed.route(p, xn, cfg, "gate_bias")
         with jax.named_scope("model.moe.experts"):
-            y, counted = routed_part(p, xn, idx, w, cfg)
+            y, counted = routed.routed_part(p, xn, idx, w, cfg)
         with jax.named_scope("model.moe.shared"):
-            y = y + _swiglu(xn, p["sw1"], p["sw3"], p["sw2"])
+            y = y + routed.swiglu(xn, p["sw1"], p["sw3"], p["sw2"])
     else:
         with jax.named_scope("model.ffn"):
-            y = _swiglu(xn, p["w1"], p["w3"], p["w2"])
+            y = routed.swiglu(xn, p["w1"], p["w3"], p["w2"])
     return (x32 + y).astype(x.dtype), cache, counted
 
 

@@ -371,18 +371,15 @@ def layer_with_cache(
 def apply_layers(layers, x: jax.Array, positions: jax.Array, cfg,
                  layer_ids=None) -> jax.Array:
     """The blocks of ``layer_ids`` (default: every layer) over ``x``, in
-    order and without a cache: one ``lax.scan`` for each run of one kind
-    in ``layers`` (``family.run_slices``) — one traced body a run
-    whatever its length, and for a uniform family the one scan over its
-    stacked tree."""
+    order and without a cache (``family.scan_stack``: one traced body
+    for each stretch of the stack whatever its length, and for a uniform
+    family the one scan over its stacked tree)."""
     fam = family.of(cfg)
 
-    def body(x, layer_p):
-        return fam.layer_apply(layer_p, x, positions, cfg), None
+    def step(x, layer_p, _):
+        return fam.layer_apply(layer_p, x, positions, cfg), None, {}
 
-    for _, _, _, (run,) in family.run_slices(cfg, (layers,), layer_ids):
-        x, _ = jax.lax.scan(body, x, run)
-    return x
+    return family.scan_stack(cfg, step, x, layers, None, layer_ids)[0]
 
 
 def forward(params: Dict[str, Any], tokens: jax.Array, cfg) -> jax.Array:

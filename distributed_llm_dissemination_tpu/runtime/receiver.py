@@ -244,6 +244,7 @@ class ReceiverNode:
         self._precompiled_sets: dict = {}
         self._precompile_inflight = 0
         self._serve_active = 0
+        self._serve_last_done = float("-inf")  # monotonic; serve_quiet_s
         self._precompile_done = threading.Event()
         self._precompile_done.set()
         # Startup marker for overlap accounting: precompiles and streamed
@@ -2598,11 +2599,23 @@ class ReceiverNode:
             finally:
                 with self._lock:
                     self._serve_active -= 1
+                    self._serve_last_done = _time.monotonic()
 
         threading.Thread(
             target=_run, daemon=True,
             name=f"genreq-{self.node.my_id}-{msg.req_id}",
         ).start()
+
+    def serve_quiet_s(self) -> float:
+        """How long this node has had no generation request: 0 while one
+        is in flight, else the seconds since the last was answered
+        (infinite before the first).  A ``-serve`` window closes on a
+        quiet node only: not on a first request that is still compiling
+        its programs, nor on a requester between two of its requests."""
+        with self._lock:
+            if self._serve_active:
+                return 0.0
+            return _time.monotonic() - self._serve_last_done
 
     def _serve_generate_req(self, msg: GenerateReqMsg,
                             t_arrived: float) -> None:

@@ -49,7 +49,8 @@ def test_a_kind_beside_the_stack_is_in_no_run_and_keeps_its_program_and_stack():
 
     cfg = joyai.CONFIGS["tiny-joyai"]
     assert family.side_kinds(cfg) == ("mtp",) and family.side_kinds(TINY) == ()
-    assert [k for k, _, _ in family.runs(cfg)] == ["dense", "moe"]
+    assert family.stretches(cfg) == [[("dense", 0)],
+                                     [("moe", 0), ("moe", 1)]]
     assert family.group(cfg)["mtp"] == [cfg.n_layers - 1]
     params = llama.init_params(cfg, jax.random.key(0))
     toks = jnp.arange(8, dtype=jnp.int32)[None]
@@ -58,8 +59,6 @@ def test_a_kind_beside_the_stack_is_in_no_run_and_keeps_its_program_and_stack():
         params["layers"], mtp=jax.tree.map(lambda a: a * 0,
                                            params["layers"]["mtp"])))
     assert np.array_equal(np.asarray(llama.forward(other, toks, cfg)), want)
-    assert [k for k, *_ in family.run_slices(cfg, (params["layers"],))] == [
-        "dense", "moe"]
     blobs = serde.blobs_from_params(cfg, params)
     dev = quant.stacked_from_device(
         cfg, [jnp.asarray(np.frombuffer(blobs[b], np.uint8))
@@ -345,3 +344,134 @@ def test_cli_train_refuses_the_family_and_exits_non_zero(tmp_path, resume):
     assert e.value.code not in (0, None)
     assert "cli.train cannot run 'tiny-longcat' of the longcat family" in str(
         e.value)
+
+
+# ---------------------------- kinds that alternate, and the shared router
+
+
+def _lowered_serving_programs(cfg):
+    """StableHLO of ``cfg``'s prefill (16 positions) and decode (8 new
+    tokens) as ``generate`` builds them, lowered for abstract inputs."""
+    params = jax.eval_shape(lambda: llama.init_params(cfg, jax.random.key(0)))
+    cache = jax.eval_shape(lambda: generate.init_cache(cfg, 1, 24))
+    prompt = jax.ShapeDtypeStruct((1, 16), jnp.int32)
+    prefill = generate._draft_prefill_fn(cfg, 16)
+    first, guess, rows, counted = jax.eval_shape(prefill, params, prompt,
+                                                 cache)
+    return (prefill.lower(params, prompt, cache).as_text(),
+            generate._draft_decode_fn(cfg, 16, 8).lower(
+                params, rows, first, guess, counted).as_text())
+
+
+def test_the_shared_router_gives_joyai_the_stablehlo_it_had(monkeypatch):
+    """``models/routed.py`` (the sigmoid router and the share's dense
+    dispatch, lifted out of ``models/joyai.py`` for two families) against
+    the functions as ``joyai.py`` had them before, kept here word for
+    word: JoyAI's prefill and decode at its tiny preset lower to the same
+    StableHLO, character for character — and to the text's digest taken
+    on the parent commit (PR 36), which also holds ``family.scan_stack``
+    to the scans ``generate`` made itself."""
+    from distributed_llm_dissemination_tpu.models import joyai, routed
+    from distributed_llm_dissemination_tpu.models.lfm2 import _mm
+
+    exact = jax.lax.Precision.HIGHEST
+
+    def swiglu(xn, w1, w3, w2):
+        gate = jax.nn.silu(_mm("bsd,df->bsf", xn, w1))
+        return _mm("bsf,fd->bsd", gate * _mm("bsd,df->bsf", xn, w3), w2)
+
+    def route(p, xn, cfg, bias):
+        scores = jax.nn.sigmoid(
+            jnp.einsum("bsd,de->bse", xn.astype(jnp.float32),
+                       p["gate"].astype(jnp.float32), precision=exact))
+        _, idx = jax.lax.top_k(scores + p["gate_bias"].astype(jnp.float32),
+                               cfg.top_k)
+        w = jnp.take_along_axis(scores, idx, axis=-1)
+        return idx, w / (w.sum(-1, keepdims=True) + 1e-20) * cfg.route_scale
+
+    def routed_part(p, xn, idx, w, cfg):
+        held = (idx[..., None] - cfg.expert_first
+                == jnp.arange(cfg.experts_held))  # [b, s, top_k, held]
+        gate = (w[..., None] * held).sum(-2)  # [b, s, held]
+        g = jax.nn.silu(_mm("bsd,edf->besf", xn, p["ew1"]))
+        out = _mm("besf,efd->besd", g * _mm("bsd,edf->besf", xn, p["ew3"]),
+                  p["ew2"])
+        mixed = jnp.einsum("besd,bse->bsd", out, gate, precision=exact)
+        return mixed, {
+            "moe_slots": jnp.asarray(idx.size, jnp.int32),
+            "moe_held": jnp.sum(held, dtype=jnp.int32),
+            "moe_touched": jnp.sum(held.any((0, 1, 2)), dtype=jnp.int32)}
+
+    tiny = joyai.CONFIGS["tiny-joyai"]
+    now = _lowered_serving_programs(
+        dataclasses.replace(tiny, name="tiny-joyai-shared"))
+    for name, fn in (("swiglu", swiglu), ("route", route),
+                     ("routed_part", routed_part)):
+        monkeypatch.setattr(routed, name, fn)
+    before = _lowered_serving_programs(
+        dataclasses.replace(tiny, name="tiny-joyai-as-it-was"))
+    assert now == before
+    assert "stablehlo.while" in now[1] and len(now[0]) > 10_000
+    assert [hashlib.sha256(text.encode()).hexdigest()
+            for text in _lowered_serving_programs(tiny)] == [
+        "afa4e3e32a6985acbd746d048438853bfd877343cd1271d35a000b7f4a768f57",
+        "f715fd2176c1d5f400c3fc0eb3b443ccec886f69d49f955f020ca53e9efe00fb"]
+
+
+def test_ten_runs_of_three_kinds_round_trip_through_the_stacks():
+    """Eighteen layers whose kinds alternate for four periods: ten runs
+    of three kinds are two stretches (the dense kind's whole stack, and
+    sixteen layers of two kinds under one ``lax.switch``); ``family.
+    stack`` puts each layer at its place in its kind's stack and
+    ``scan_stack`` visits them in the stack's order, each with its own
+    leaves and its own row of state, and puts each row back where it
+    took it."""
+    from distributed_llm_dissemination_tpu.models import trinity
+
+    period = ("sliding_attention",) * 3 + ("full_attention",)
+    cfg = dataclasses.replace(trinity.CONFIGS["tiny-trinity"], name="runs",
+                              layer_types=(period * 5)[:18])
+    kinds = family.layer_kinds(cfg)
+    runs = [k for i, k in enumerate(kinds) if i == 0 or kinds[i - 1] != k]
+    assert len(runs) == 10 and len(set(kinds)) == 3
+    dense, mixed = family.stretches(cfg)
+    assert dense == [("dense_sliding", 0), ("dense_sliding", 1)]
+    assert len(mixed) == 16 and {k for k, _ in mixed} == {
+        "routed_sliding", "routed_full"}
+    # a layer's one leaf holds its id; its state starts at zero
+    params = family.stack(cfg, range(18),
+                          lambda lid: {n: np.full((1,), lid, np.float32)
+                                       for n, _ in family.layer_param_specs(
+                                           cfg, lid)}, np.stack)
+    params = {kind: {"q_norm": jnp.asarray(leaves["q_norm"])}
+              for kind, leaves in params.items()}
+    assert {k: v["q_norm"][:, 0].tolist() for k, v in params.items()} == {
+        "dense_sliding": [0, 1], "routed_full": [3, 7, 11, 15],
+        "routed_sliding": [2, 4, 5, 6, 8, 9, 10, 12, 13, 14, 16, 17]}
+    state = jax.tree.map(jnp.zeros_like, params)
+
+    def step(x, p, row):
+        # x: (how many layers so far, the last layer's id)
+        ok = p["q_norm"][0] == x[0]
+        return (jnp.stack([x[0] + 1, p["q_norm"][0]]),
+                {"q_norm": row["q_norm"] + x[0] + 100.0},
+                {"in_order": ok.astype(jnp.int32)})
+
+    x, after, counted = jax.jit(lambda x, p, s: family.scan_stack(
+        cfg, step, x, p, s))(jnp.zeros((2,)), params, state)
+    assert x.tolist() == [18.0, 17.0] and int(counted["in_order"]) == 18
+    # each row of state was written once, by the layer it belongs to
+    assert jax.tree.map(lambda a, p: (a[:, 0] - 100 == p[:, 0]).all().item(),
+                        after, params) == jax.tree.map(lambda _: True, params)
+    # a stage's slice walks its own layers alone, by their places in the
+    # stacks it was handed
+    held = [6, 7, 8, 9]
+    part = {kind: {"q_norm": jnp.asarray([[float(i)] for i in ids])}
+            for kind, ids in family.group(cfg, held).items()}
+
+    def note(x, p, _):
+        # the ids in the order met, as the digits of a number
+        return x * 10 + p["q_norm"][0], None, {}
+
+    met, _, _ = family.scan_stack(cfg, note, jnp.zeros(()), part, None, held)
+    assert float(met) == 6789.0

@@ -28,6 +28,7 @@ from distributed_llm_dissemination_tpu.models import (
     longcat,
     mla,
     quant,
+    routed,
     serde,
 )
 from distributed_llm_dissemination_tpu.runtime import boot
@@ -98,14 +99,13 @@ def test_the_module_is_a_layer_blob_and_no_layer_of_the_stack():
     assert family.layer_kinds(TINY) == ("dense", "moe", "moe", "mtp")
     assert family.side_kinds(TINY) == ("mtp",)
     assert family.group(TINY) == {"dense": [0], "moe": [1, 2], "mtp": [3]}
-    assert family.runs(TINY) == [("dense", 0, 1), ("moe", 0, 2)]
-    assert family.runs(TINY, [2, 3]) == [("moe", 0, 1)]
-    assert family.runs(TINY, [3]) == []
+    assert family.stretches(TINY) == [[("dense", 0)],
+                                      [("moe", 0), ("moe", 1)]]
+    assert family.stretches(TINY, [2, 3]) == [[("moe", 0)]]
+    assert family.stretches(TINY, [3]) == []
     params = llama.init_params(TINY, jax.random.key(0))
     cache = generate.init_cache(TINY, 1, 8)
     assert set(params["layers"]) == set(cache) == {"dense", "moe", "mtp"}
-    assert [k for k, *_ in family.run_slices(
-        TINY, (params["layers"], cache))] == ["dense", "moe"]
     assert family.drafter(TINY) is joyai.draft
     bare = dataclasses.replace(TINY, name="tiny-joyai-bare", n_mtp=0)
     assert family.side_kinds(bare) == () and family.drafter(bare) is None
@@ -223,9 +223,9 @@ def test_four_shares_and_one_shared_expert_add_up_to_the_uncut_block():
     p = jax.tree.map(lambda a: a.astype(jnp.float32),
                      joyai.init_layer_params(cfg, jax.random.key(7), "moe"))
     xn = jax.random.normal(jax.random.key(8), (2, 9, cfg.d_model))
-    idx, w = joyai.route(p, xn, cfg)
+    idx, w = routed.route(p, xn, cfg, "gate_bias")
     assert np.allclose(np.asarray(w.sum(-1)), cfg.route_scale, rtol=1e-6)
-    whole, counted = joyai.routed_part(p, xn, idx, w, cfg)
+    whole, counted = routed.routed_part(p, xn, idx, w, cfg)
     assert int(counted["moe_held"]) == int(counted["moe_slots"]) == 2 * 9 * 4
     parts, held = 0.0, 0
     for first in (0, 4, 8, 12):
@@ -233,9 +233,9 @@ def test_four_shares_and_one_shared_expert_add_up_to_the_uncut_block():
                                    expert_first=first)
         mine = dict(p, **{k: p[k][first:first + 4]
                           for k in ("ew1", "ew3", "ew2")})
-        ridx, rw = joyai.route(mine, xn, rank)
+        ridx, rw = routed.route(mine, xn, rank, "gate_bias")
         assert np.array_equal(ridx, idx) and np.array_equal(rw, w)
-        part, c = joyai.routed_part(mine, xn, ridx, rw, rank)
+        part, c = routed.routed_part(mine, xn, ridx, rw, rank)
         parts, held = parts + part, held + int(c["moe_held"])
     assert held == 2 * 9 * 4  # every slot is held by exactly one rank
     assert np.allclose(np.asarray(parts), np.asarray(whole), atol=1e-5)

@@ -37,9 +37,11 @@ from distributed_llm_dissemination_tpu.transport import reset_registry
 from distributed_llm_dissemination_tpu.utils import trace
 
 TINY = lfm2.CONFIGS["tiny-lfm2"]  # conv_dense x2, attn_moe, conv_moe
-# Two periods after the dense layers: each routed kind's stack is read in
-# two runs, so a run is PART of a stack (the committed cut has no such
-# run; the published depth has nineteen).
+# Two periods after the dense layers: each routed kind's stack is read
+# twice, a layer of it at a time (``family.scan_stack`` scans over the
+# four layers with a switch on the kind and takes each layer out of its
+# kind's stack by index; the committed cut has no such stretch, the
+# published depth has one of thirty-eight layers).
 TWO = dataclasses.replace(
     TINY, name="tiny-lfm2-two",
     layer_types=("conv", "conv", "full_attention", "conv", "full_attention",
@@ -101,20 +103,24 @@ def test_the_table_says_which_kind_a_layer_is():
         "conv_dense", "conv_dense", "attn_moe", "conv_moe")
     assert family.group(TINY) == {"conv_dense": [0, 1], "attn_moe": [2],
                                   "conv_moe": [3]}
-    assert family.runs(TINY) == [("conv_dense", 0, 2), ("attn_moe", 0, 1),
-                                 ("conv_moe", 0, 1)]
-    assert family.runs(TWO) == [
-        ("conv_dense", 0, 2), ("attn_moe", 0, 1), ("conv_moe", 0, 1),
-        ("attn_moe", 1, 2), ("conv_moe", 1, 2)]
+    assert family.stretches(TINY) == [
+        [("conv_dense", 0), ("conv_dense", 1)], [("attn_moe", 0)],
+        [("conv_moe", 0)]]
+    # kinds that alternate, each run a PART of its kind's stack, are ONE
+    # stretch (one scan with a switch on the kind)
+    assert family.stretches(TWO) == [
+        [("conv_dense", 0), ("conv_dense", 1)],
+        [("attn_moe", 0), ("conv_moe", 0), ("attn_moe", 1), ("conv_moe", 1)]]
     # a stage's slice: places count among the layers HELD
     assert family.group(TWO, [3, 4, 5]) == {"conv_moe": [3, 5],
                                             "attn_moe": [4]}
-    assert family.runs(TWO, [3, 4, 5]) == [
-        ("conv_moe", 0, 1), ("attn_moe", 0, 1), ("conv_moe", 1, 2)]
+    assert family.stretches(TWO, [3, 4, 5]) == [
+        [("conv_moe", 0)], [("attn_moe", 0)], [("conv_moe", 1)]]
     # a family whose layers are alike is the case of one kind
     tiny = llama.CONFIGS["tiny"]
     assert family.layer_kinds(tiny) == (family.ONE_KIND,) * 4
-    assert family.runs(tiny) == [(family.ONE_KIND, 0, 4)]
+    assert family.stretches(tiny) == [
+        [(family.ONE_KIND, i) for i in range(4)]]
     tree = {"w": np.zeros((4, 2))}
     assert family.by_kind(tiny, tree) == {family.ONE_KIND: tree}
     assert family.of_kinds(tiny, {family.ONE_KIND: tree}) is tree

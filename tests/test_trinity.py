@@ -1,0 +1,708 @@
+"""The Trinity family (``models/trinity.py``) at ``tiny-trinity`` on the
+CPU, held to the architecture module's plain reference
+(``benchmark/archs/trinity_mini.py``: two implementations that share no
+code) on seeded weights: the full forward; prefill and decode through a
+ring and a grown cache side by side; the blockwise attention against the
+one plain softmax; the rotary in layers with a window alone; the pieces of
+the mathematics that must not be left out; the eight ranks' shares adding
+up to the uncut layer; the family through the table, serde, the boot and
+the entry points that refuse it by name.
+
+Tolerances.  ``EXACT`` = 1e-5 relative: float32 against float32 through
+different orders of the same sums (a blockwise softmax against a plain
+one, a scan over experts against a dense dispatch) differs by a few 1e-7
+a layer over six layers; one bfloat16 rounding anywhere is 4e-3 and
+fails it (``test_a_bfloat16_where_float32_is_stated_fails``).
+``TOLERANCE`` = 3e-2 is ``benchmark/run.py``'s, which the controls must
+exceed.
+"""
+
+import dataclasses
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_llm_dissemination_tpu.core.types import (
+    LayerLocation,
+    LayerMeta,
+    LayerSrc,
+    SourceType,
+)
+from distributed_llm_dissemination_tpu.models import (
+    family,
+    generate,
+    llama,
+    quant,
+    routed,
+    serde,
+    trinity,
+)
+from distributed_llm_dissemination_tpu.runtime import boot
+from distributed_llm_dissemination_tpu.transport import reset_registry
+from distributed_llm_dissemination_tpu.utils import trace
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+from benchmark import archs  # noqa: E402
+
+TINY = trinity.CONFIGS["tiny-trinity"]  # window 8; D D S F S S
+F32 = dataclasses.replace(TINY, name="tiny-trinity-f32", dtype=jnp.float32)
+ARCH = archs.load(os.path.join(REPO, "benchmark", "archs",
+                               "trinity_mini.py"))
+EXACT = 1e-5
+TOLERANCE = 3e-2
+PERIOD = ("sliding_attention",) * 3 + ("full_attention",)
+
+
+@pytest.fixture(autouse=True)
+def _clean():
+    reset_registry()
+    trace.reset_run()
+    yield
+    reset_registry()
+
+
+@pytest.fixture
+def blocks_of_four(monkeypatch):
+    """Blocks of 4 positions in the program and of 8 queries in the
+    reference: at tiny lengths the blockwise paths run, over windows of
+    8, whole, partial and padded blocks."""
+    monkeypatch.setattr(trinity, "BLOCK", 4)
+    monkeypatch.setattr(ARCH, "REF_BLOCK", 8)
+
+
+def dims_of(cfg) -> dict:
+    """The reference's sizes for a program configuration."""
+    return {"d": cfg.d_model, "h": cfg.n_heads, "kv": cfg.n_kv_heads,
+            "hd": cfg.head_dim, "f": cfg.d_ff, "fe": cfg.d_expert,
+            "fs": cfg.d_shared, "held": cfg.experts_held,
+            "first": cfg.expert_first, "routed": cfg.n_experts,
+            "top_k": cfg.top_k, "route_norm": True,
+            "route_scale": cfg.route_scale, "dense": cfg.n_dense,
+            "layers": cfg.n_layers, "types": list(cfg.layer_types),
+            "window": cfg.window, "vocab": cfg.vocab,
+            "theta": cfg.rope_theta, "eps": cfg.norm_eps}
+
+
+def seeded(cfg, seed: int, batch: int = 2, length: int = 29):
+    """``(params as the program holds them, {blob: {leaf: array}} as the
+    reference takes them, tokens)``: matrices normal at ``fan_in **
+    -0.5``, a live selection bias, and norm gains uniform in 0.5 .. 1.5
+    (a gain of exactly 1 would let a control swap two norms unseen)."""
+    rng = np.random.default_rng(seed)
+    model = {}
+    for b in range(cfg.n_layers + 1):
+        model[b] = {}
+        for name, shape in serde.blob_specs(cfg, b):
+            if name == "expert_bias":
+                leaf = rng.standard_normal(shape) * 0.05
+            elif len(shape) == 1:
+                leaf = rng.uniform(0.5, 1.5, shape)
+            else:
+                leaf = rng.standard_normal(shape) * shape[-2] ** -0.5
+            model[b][name] = leaf.astype(np.float32)
+    layers = family.stack(cfg, range(cfg.n_layers),
+                          lambda b: dict(model[b]), np.stack)
+    params = jax.tree.map(jnp.asarray, {"layers": layers,
+                                        **model[cfg.n_layers]})
+    return params, model, rng.integers(0, cfg.vocab, (batch, length))
+
+
+def ref_logits(cfg, model, toks) -> np.ndarray:
+    m = dims_of(cfg)
+    with jax.default_matmul_precision("highest"):
+        head = {k: jnp.asarray(v) for k, v in model[cfg.n_layers].items()}
+        h = ARCH.ref_in(jnp, m, head, jnp.asarray(toks))
+        for b in range(cfg.n_layers):
+            h = ARCH.ref_layer(jnp, jax, m, {
+                k: jnp.asarray(v) for k, v in model[b].items()}, h)
+        return np.asarray(ARCH.ref_out(jnp, m, head, h))
+
+
+def rel(got, want) -> float:
+    return float(np.linalg.norm(np.asarray(got) - np.asarray(want))
+                 / np.linalg.norm(np.asarray(want)))
+
+
+def served(cfg, params, toks, prompt: int):
+    """Logits at every position from ``prompt - 1`` on, by the prefill of
+    ``prompt`` positions and one-token steps through the caches; the
+    caches at the end; the counters added up."""
+    length = toks.shape[1]
+    cache = generate.init_cache(cfg, toks.shape[0], length)
+    step = jax.jit(lambda tok, at, cache: generate._forward_with_cache(
+        params, tok, at, cache, cfg))
+    with jax.default_matmul_precision("highest"):
+        got, cache, counted = generate._prefill_fn(cfg, prompt)(
+            params, jnp.asarray(toks[:, :prompt]), cache)
+        out = [np.asarray(got)]
+        total = {k: int(v) for k, v in counted.items()}
+        for t in range(prompt, length):
+            got, cache, more = step(jnp.asarray(toks[:, t:t + 1]),
+                                    jnp.asarray([t]), cache)
+            out.append(np.asarray(got))
+            for k, v in more.items():
+                total[k] += int(v)
+    return np.stack(out, axis=1), cache, total
+
+
+# ------------------------------------------------- the table and the blobs
+
+
+def test_the_table_says_which_kind_a_layer_is_and_cuts_the_stack():
+    assert family.layer_kinds(TINY) == (
+        "dense_sliding", "dense_sliding", "routed_sliding", "routed_full",
+        "routed_sliding", "routed_sliding")
+    assert family.group(TINY) == {"dense_sliding": [0, 1],
+                                  "routed_sliding": [2, 4, 5],
+                                  "routed_full": [3]}
+    # six layers: the one full layer is all of its kind's stack, so the
+    # windowed kind's two runs, PARTS of its stack, are a stretch each
+    dense = [("dense_sliding", 0), ("dense_sliding", 1)]
+    assert family.stretches(TINY) == [
+        dense, [("routed_sliding", 0)], [("routed_full", 0)],
+        [("routed_sliding", 1), ("routed_sliding", 2)]]
+    # the committed cut: ten runs of three kinds are two stretches, the
+    # second sixteen layers of two kinds that alternate
+    deep = dataclasses.replace(TINY, name="d18",
+                               layer_types=(PERIOD * 5)[:18])
+    assert family.stretches(deep)[0] == dense
+    (_, mixed), n = family.stretches(deep), {"routed_sliding": 0,
+                                             "routed_full": 0}
+    for (kind, place), op in zip(mixed, (PERIOD * 5)[2:18]):
+        assert kind == "routed_" + op.split("_")[0] and place == n[kind]
+        n[kind] += 1
+    assert n == {"routed_sliding": 12, "routed_full": 4}
+    # the published depth: thirty layers in the one stretch
+    whole = dataclasses.replace(TINY, name="d32", layer_types=PERIOD * 8)
+    assert [len(s) for s in family.stretches(whole)] == [2, 30]
+    # a preset may put a full layer among the dense ones
+    odd = dataclasses.replace(TINY, name="odd", n_dense=4)
+    assert family.layer_kinds(odd)[3] == "dense_full"
+    with pytest.raises(ValueError, match="layer_types"):
+        dataclasses.replace(TINY, layer_types=("conv",))
+    with pytest.raises(ValueError, match="experts"):
+        dataclasses.replace(TINY, expert_first=8)
+
+
+def test_a_blobs_leaves_say_its_kind_and_round_trip_through_serde():
+    names = {b: [n for n, _ in serde.blob_specs(TINY, b)] for b in range(7)}
+    attn = ["q_proj", "k_proj", "v_proj", "o_proj", "gate_proj", "q_norm",
+            "k_norm", "post_attn_norm", "pre_ffn_norm"]
+    moe = ["gate", "expert_bias", "sw1", "sw3", "sw2", "ew1", "ew3", "ew2"]
+    assert names[0] == ["window_norm"] + attn + ["w1", "w3", "w2",
+                                                 "post_ffn_norm"]
+    assert names[2] == names[4] == ["window_norm"] + attn + moe + [
+        "post_ffn_norm"]
+    # a full layer's leaves are a windowed one's but for the one NAME the
+    # block tells them apart by
+    assert names[3] == ["attn_norm"] + names[2][1:]
+    assert [s for _, s in serde.blob_specs(TINY, 3)] == [
+        s for _, s in serde.blob_specs(TINY, 2)]
+    assert names[6] == ["embed", "ln_f", "lm_head"]
+    shapes = dict(serde.blob_specs(TINY, 2))
+    assert shapes["gate_proj"] == (64, 64) and shapes["k_proj"] == (64, 32)
+    assert shapes["gate"] == (64, 16) and shapes["ew2"] == (16, 32, 64)
+    params = llama.init_params(TINY, jax.random.key(3))
+    blobs = serde.blobs_from_params(TINY, params)
+    assert sorted(blobs) == list(range(7))
+    for b in range(7):
+        assert len(blobs[b]) == serde.blob_nbytes(TINY, b)
+        assert bytes(blobs[b]) == serde.seeded_blob(TINY, b, 3)
+    back = serde.params_from_blobs(TINY, blobs)
+    assert jax.tree.structure(back) == jax.tree.structure(params)
+    for a, b in zip(jax.tree.leaves(back), jax.tree.leaves(params)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    for codec in ("int8", "int4"):
+        enc = quant.encode_blob(TINY, 2, bytes(blobs[2]), codec)
+        assert len(enc) == quant.blob_nbytes_codec(TINY, 2, codec)
+
+
+# --------------------------- the program against the reference, float32
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("blocks", ["one-block", "blockwise"])
+def test_the_full_forward_equals_the_reference(seed, blocks, request):
+    """Both sides float32; 29 positions under a window of 8; as one plain
+    softmax a layer, and with the program's attention and feed-forward in
+    blocks of 4 and the reference's in blocks of 8."""
+    if blocks == "blockwise":
+        request.getfixturevalue("blocks_of_four")
+    cfg = dataclasses.replace(F32, name=f"fwd-{seed}-{blocks}")
+    params, model, toks = seeded(cfg, seed)
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(llama.forward(params, jnp.asarray(toks), cfg))
+    assert got.shape == (2, 29, 256)
+    assert rel(got, ref_logits(cfg, model, toks)) < EXACT
+
+
+@pytest.mark.parametrize("prompt", [21, 5], ids=["wraps-in-prefill",
+                                                 "shorter-than-the-window"])
+@pytest.mark.parametrize("blocks", ["one-block", "blockwise"])
+def test_prefill_and_decode_through_ring_and_grown_cache_equal_the_reference(
+        prompt, blocks, request):
+    """A prompt of 21 (more than twice the window of 8: the ring wraps in
+    the prefill and again in the 8 decode steps) and one of 5 (the ring
+    fills, then wraps, in decode): the logits at every served position
+    are the reference's full forward's, position by position."""
+    if blocks == "blockwise":
+        request.getfixturevalue("blocks_of_four")
+    cfg = dataclasses.replace(F32, name=f"serve-{prompt}-{blocks}")
+    params, model, toks = seeded(cfg, 2)
+    want = ref_logits(cfg, model, toks)
+    got, cache, total = served(cfg, params, toks, prompt)
+    assert got.shape == (2, 29 - prompt + 1, 256)
+    assert rel(got, want[:, prompt - 1:]) < EXACT
+    assert {k: v["k"].shape[1:3] for k, v in cache.items()} == {
+        "dense_sliding": (2, 8), "routed_sliding": (2, 8),
+        "routed_full": (2, 29)}
+    # all 29 positions went through the stack: 5 rings of 8 rows and one
+    # grown cache, two sequences
+    assert total["kv_rows"] == 2 * (5 * 8 + 29)
+    assert total["swa_evicted"] == 2 * 5 * (29 - 8)
+    assert total["moe_slots"] == 2 * 29 * 4 * 4 == total["moe_held"]
+
+
+def test_however_the_positions_arrive_the_caches_end_alike():
+    """One prefill of 28 positions (kept rows rolled into place), a
+    prefill of 21 and 7 steps, a prefill of 5 and 23 steps (written at
+    ``position mod 8``): the same rings and the same grown rows."""
+    cfg = dataclasses.replace(F32, name="same-caches")
+    params, _, toks = seeded(cfg, 3)
+    toks = toks[:, :29]
+    ends = []
+    for prompt in (28, 21, 5):
+        ends.append(served(cfg, params, toks[:, :28], prompt)[1])
+    for other in ends[1:]:
+        for a, b in zip(jax.tree.leaves(ends[0]), jax.tree.leaves(other)):
+            assert np.allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+    ring = np.asarray(ends[0]["routed_sliding"]["k"])
+    assert ring.shape[2] == 8 and np.abs(ring).min(axis=(0, 1, 3, 4)).all()
+
+
+def test_a_ring_written_elsewhere_or_a_band_one_short_fails(monkeypatch):
+    """The two faults the benchmark's cell exists to catch, at tiny size:
+    a window one position short in the program, and a decode step that
+    writes its row at ``position mod 4`` instead of ``mod 8``."""
+    cfg = dataclasses.replace(F32, name="faulty-band", window=7)
+    params, model, toks = seeded(F32, 4)
+    want = ref_logits(F32, model, toks)
+    with jax.default_matmul_precision("highest"):
+        short = llama.forward(params, jnp.asarray(toks), cfg)
+    assert rel(short, want) > TOLERANCE
+    real = jax.lax.dynamic_update_slice
+
+    def misplaced(rows, row, at):
+        if rows.ndim == 4 and rows.shape[1] == 8 and row.shape[1] == 1:
+            at = (at[0], at[1] % 4, *at[2:])
+        return real(rows, row, at)
+
+    monkeypatch.setattr(jax.lax, "dynamic_update_slice", misplaced)
+    got, _, _ = served(dataclasses.replace(F32, name="faulty-ring"), params,
+                       toks, 5)
+    assert rel(got, want[:, 4:]) > TOLERANCE
+
+
+def test_a_bfloat16_where_float32_is_stated_fails():
+    """``EXACT`` is tight enough: K and V rows rounded to bfloat16
+    between the prefill and the decode miss it (and stay well inside the
+    3%, which is why the harness's tolerance alone would not see them)."""
+    cfg = dataclasses.replace(F32, name="rounded-rows")
+    params, model, toks = seeded(cfg, 5)
+    want = ref_logits(cfg, model, toks)[:, 21:]
+    cache = generate.init_cache(cfg, 2, 29)
+    with jax.default_matmul_precision("highest"):
+        _, cache, _ = generate._prefill_fn(cfg, 21)(
+            params, jnp.asarray(toks[:, :21]), cache)
+        rounded = jax.tree.map(
+            lambda a: a.astype(jnp.bfloat16).astype(jnp.float32), cache)
+        errs = {}
+        for name, rows in (("float32", cache), ("bfloat16", rounded)):
+            got, _, _ = generate._forward_with_cache(
+                params, jnp.asarray(toks[:, 21:22]), jnp.asarray([21]), rows,
+                cfg)
+            errs[name] = rel(got, want[:, 0])
+    assert errs["float32"] < EXACT < errs["bfloat16"] < TOLERANCE
+
+
+# ------------------------------------------- the attention, piece by piece
+
+
+@pytest.mark.parametrize("window", [None, 8, 5, 1])
+@pytest.mark.parametrize("length", [4, 13, 16])
+def test_the_blockwise_attention_is_the_plain_softmax(window, length,
+                                                      monkeypatch):
+    """Blocks of 4 against one block: whole, partial (13 = 3 x 4 + 1) and
+    single blocks; no window, one of two blocks, one that is no multiple
+    of a block, one that sees itself alone."""
+    rng = np.random.default_rng(length)
+    q = jnp.asarray(rng.standard_normal((2, length, 2, 2, 16)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((2, length, 2, 16)),
+                        jnp.float32) for _ in range(2))
+    want = trinity._attend_blocks(q, k, v, window)
+    monkeypatch.setattr(trinity, "BLOCK", 4)
+    got = trinity._attend_blocks(q, k, v, window)
+    assert got.shape == want.shape == (2, length, 2, 2, 16)
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-5
+
+
+def test_a_layer_with_a_window_has_the_rotary_and_a_full_layer_none():
+    """No positional encoding in a full layer: its output does not depend
+    on the positions it is told.  A layer with a window rotates queries
+    and keys: stretching the positions changes it — while moving them all
+    by a constant does not, the rotary being relative (which is why the
+    ring may hold rotated keys in any order)."""
+    params, _, _ = seeded(F32, 6)
+    x = jax.random.normal(jax.random.key(6), (2, 7, 64))
+    at = jnp.arange(7)
+    with jax.default_matmul_precision("highest"):
+        for kind, moved in (("routed_full", 0.0), ("routed_sliding", 0.05)):
+            p = jax.tree.map(lambda a: a[0], params["layers"][kind])
+            here = trinity.layer_apply(p, x, at, F32)
+            assert rel(trinity.layer_apply(p, x, at + 100, F32), here) < 1e-4
+            stretched = rel(trinity.layer_apply(p, x, at * 3, F32), here)
+            assert (stretched > moved) if moved else (stretched == 0.0)
+
+
+# -------------------- controls: a piece left out must fail the tolerance
+#
+# Each control leaves one piece out of the REFERENCE and is held against
+# the program on the same arrays (gains uniform in 0.5 .. 1.5, so that a
+# norm is never an identity).  Relative L2 beside the 3% on this file's
+# own runs on the CPU, float32, seeds 0-2:
+#
+#   output gate left out       76 - 87%
+#   input norm left out        85 - 88%      post-attention norm 118 - 123%
+#   pre-ffn norm left out      68 - 73%      post-ffn norm        74 - 77%
+#   q/k head norms left out    67 - 73%
+#   sqrt(d) multiplier         73 - 78%
+#   rotary in full layers too  93 - 95%      (the test after these)
+#   a window of 7 for one of 8 56 - 65%      (the faulty band, above)
+
+
+def _without_norm(*names):
+    def patch(real):
+        def norm(jnp, m, p, name, x):
+            return x if name in names else real(jnp, m, p, name, x)
+        return norm
+    return "_norm", patch
+
+
+CONTROLS = {
+    "output gate": ("_gated", lambda _: lambda jnp, jax, p, x, attn: attn),
+    "input norm": _without_norm("window_norm", "attn_norm"),
+    "post-attention norm": _without_norm("post_attn_norm"),
+    "pre-ffn norm": _without_norm("pre_ffn_norm"),
+    "post-ffn norm": _without_norm("post_ffn_norm"),
+    "head norms": _without_norm("q_norm", "k_norm"),
+    "sqrt(d) multiplier": ("_mup", lambda _: lambda dims: 1.0),
+}
+
+
+@pytest.mark.parametrize("control", sorted(CONTROLS))
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_a_piece_of_the_mathematics_left_out_fails_the_tolerance(
+        control, seed, monkeypatch):
+    params, model, toks = seeded(F32, seed)
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(llama.forward(params, jnp.asarray(toks), F32))
+    assert rel(got, ref_logits(F32, model, toks)) < EXACT
+    name, patch = CONTROLS[control]
+    monkeypatch.setattr(ARCH, name, patch(getattr(ARCH, name)))
+    assert rel(got, ref_logits(F32, model, toks)) > TOLERANCE, control
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_a_rotary_in_a_full_layer_fails_the_tolerance(seed):
+    """The other way about: the reference told that every layer has a
+    window (as wide as the sequence, so the band changes nothing) rotates
+    in the full layer too."""
+    params, model, toks = seeded(F32, seed)
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(llama.forward(params, jnp.asarray(toks), F32))
+    wide = dataclasses.replace(F32, name="wide", window=64,
+                               layer_types=("sliding_attention",) * 6)
+    renamed = {b: {("window_norm" if k == "attn_norm" else k): v
+                   for k, v in p.items()} for b, p in model.items()}
+    # ... and telling it so for the windowed layers alone changes nothing
+    # but the band
+    assert rel(got, ref_logits(wide, renamed, toks)) > TOLERANCE
+
+
+# ------------------------------ eight shares and one shared expert: a layer
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("blob", [2, 3], ids=["window", "full"])
+def test_the_eight_shares_add_up_to_the_uncut_references_layer(seed, blob):
+    """``model-configs`` guide, section 4: the eight ranks' routed parts
+    (experts 2r, 2r + 1 of 16), with what every rank computes alike — the
+    attention and the shared expert — counted ONCE, go through the
+    post-ffn norm to what the uncut reference gives for the whole layer;
+    each rank's part by the PROGRAM's dispatch is the reference's; a rank
+    alone is no layer."""
+    _, model, _ = seeded(F32, seed)
+    m = dims_of(F32)
+    p = {k: jnp.asarray(v) for k, v in model[blob].items()}
+    h = jnp.asarray(np.random.default_rng(seed).standard_normal(
+        (2, 11, 64)).astype(np.float32))
+    with jax.default_matmul_precision("highest"):
+        whole = np.asarray(ARCH.ref_layer(jnp, jax, m, p, h))
+        after, n = ARCH._after_attention(jnp, jax, m, p, h)
+        shared = ARCH._shared(jnp, jax, m, p, n)
+        idx, w = routed.route(p, n, F32, "expert_bias")
+        total, alone, held = shared, [], 0  # the shared expert: once
+        for first in range(0, 16, 2):
+            rank = dataclasses.replace(F32, name=f"rank{first}",
+                                       experts_held=2, expert_first=first)
+            mine = dict(p, **{k: p[k][first:first + 2]
+                              for k in ("ew1", "ew3", "ew2")})
+            part = ARCH._routed(jnp, jax, dims_of(rank), mine, n)
+            ours, counted = routed.routed_part(mine, n, idx, w, rank)
+            assert rel(ours, part) < EXACT
+            held += int(counted["moe_held"])
+            total = total + part
+            alone.append(rel(ARCH.ref_layer(jnp, jax, dims_of(rank), mine,
+                                            h), whole))
+        summed = after + ARCH._norm(jnp, m, p, "post_ffn_norm", total)
+        twice = after + ARCH._norm(jnp, m, p, "post_ffn_norm",
+                                   total + 7 * shared)
+    assert held == 2 * 11 * 4  # every slot is held by exactly one rank
+    assert rel(summed, whole) < EXACT
+    assert min(alone) > TOLERANCE
+    assert rel(twice, whole) > TOLERANCE  # the shared expert eight times
+
+
+def test_the_slot_counters_equal_the_references_picks():
+    """One rank of eight (experts 4, 5): a prefill's ``moe_slots``,
+    ``moe_held`` and ``moe_touched`` are what the reference's own picks,
+    layer by layer, say."""
+    cfg = dataclasses.replace(F32, name="rank-4-5", experts_held=2,
+                              expert_first=4)
+    params, model, toks = seeded(cfg, 7, batch=1, length=16)
+    m = dims_of(cfg)
+    cache = generate.init_cache(cfg, 1, 17)
+    want = {"moe_slots": 0, "moe_held": 0, "moe_touched": 0}
+    with jax.default_matmul_precision("highest"):
+        _, _, counted = generate._prefill_fn(cfg, 16)(
+            params, jnp.asarray(toks), cache)
+        head = {k: jnp.asarray(v) for k, v in model[6].items()}
+        h = ARCH.ref_in(jnp, m, head, jnp.asarray(toks))
+        for b in range(6):
+            p = {k: jnp.asarray(v) for k, v in model[b].items()}
+            pick = ARCH.picks(jnp, jax, m, p, h)
+            if pick is not None:
+                pick = np.asarray(pick)
+                here = (pick >= 4) & (pick < 6)
+                want["moe_slots"] += pick.size
+                want["moe_held"] += int(here.sum())
+                want["moe_touched"] += len(np.unique(pick[here]))
+            h = ARCH.ref_layer(jnp, jax, m, p, h)
+    assert {k: int(counted[k]) for k in want} == want
+    assert 0 < want["moe_held"] < want["moe_slots"] == 16 * 4 * 4
+
+
+# ---------------------------------------- generate, the boot, the refusals
+
+
+def test_greedy_decode_through_the_caches_equals_the_full_forward():
+    """bfloat16, as served: ``generate`` answers with the argmax of the
+    full forward over what it has answered so far, the ring wrapping
+    under the answer (prompt 12 + 8 over a window of 8)."""
+    params = llama.init_params(TINY, jax.random.key(1))
+    prompt = (jnp.arange(12, dtype=jnp.int32) * 7 % TINY.vocab)[None]
+    toks, counted = generate.generate_counted(params, prompt, TINY, 8)
+    seq = jnp.concatenate([prompt, toks], axis=1)
+    full = np.asarray(llama.forward_jit(params, seq[:, :-1], TINY))
+    top2 = np.sort(full[0, 11:], axis=-1)[:, -2:]
+    clear = top2[:, 1] - top2[:, 0] > 0.05
+    assert clear.sum() >= 4
+    assert (full[0, 11:].argmax(-1) == np.asarray(toks[0]))[clear].all()
+    assert int(counted["kv_rows"]) == 5 * 8 + 19
+    assert int(counted["swa_evicted"]) == 5 * (19 - 8)
+    stepwise = generate.generate_stepwise(lambda: (params, ""), prompt, TINY,
+                                          8)
+    assert np.array_equal(np.asarray(stepwise), np.asarray(toks))
+
+
+def blob_layer(data: bytes) -> LayerSrc:
+    return LayerSrc(inmem_data=bytearray(data), data_size=len(data),
+                    meta=LayerMeta(location=LayerLocation.INMEM,
+                                   source_type=SourceType.MEM))
+
+
+def test_a_full_boot_serves_what_generate_serves_and_a_slice_boots_a_stage():
+    layers = {b: blob_layer(serde.seeded_blob(TINY, b, 4))
+              for b in range(TINY.n_layers + 1)}
+    assert boot.classify_held_blobs(TINY, layers) == (list(range(6)), True)
+    res = boot.boot_from_layers(TINY, layers, generate_tokens=4)
+    params = llama.init_params(TINY, jax.random.key(4))
+    assert res.kind == "full"
+    assert jax.tree.structure(res.params) == jax.tree.structure(params)
+    zeros = jnp.zeros((1, 16), jnp.int32)
+    assert np.array_equal(np.asarray(res.logits),
+                          np.asarray(llama.forward_jit(params, zeros, TINY)))
+    assert np.array_equal(np.asarray(res.tokens), np.asarray(
+        generate.generate(params, zeros, TINY, 4)))
+    span, = [s for s in trace.spans() if s["name"] == "boot.assemble"]
+    assert span["fields"]["kinds"] == 3
+    # layers 3..5: a full layer, then a PART of the windowed kind's stack
+    stage = boot.boot_from_layers(TINY, {b: layers[b] for b in (3, 4, 5)})
+    assert stage.kind == "stage" and stage.activations.shape == (1, 16, 64)
+    assert {k: v["q_proj"].shape[0] for k, v in stage.params.items()} == {
+        "routed_full": 1, "routed_sliding": 2}
+
+
+def test_a_streamed_boot_over_the_inmem_transport_serves_through_its_rings():
+    """Dissemination end to end: the leader seeds the seven blobs of
+    three kinds, node 1 stages each as it lands, boots and answers a
+    request from a third seat with ``generate``'s tokens — a prompt of
+    20 under a window of 8 — and says what its caches kept; while the
+    request is in flight the node is not quiet, and after its answer it
+    is (what a ``-serve`` window waits for)."""
+    from distributed_llm_dissemination_tpu.cli import trace as cli_trace
+    from distributed_llm_dissemination_tpu.runtime import (
+        LeaderNode,
+        Node,
+        ReceiverNode,
+    )
+    from distributed_llm_dissemination_tpu.runtime.client import GenRequester
+    from distributed_llm_dissemination_tpu.transport import InmemTransport
+
+    params = llama.init_params(TINY, jax.random.key(0))
+    blobs = serde.blobs_from_params(TINY, params)
+    ts = {i: InmemTransport(str(i)) for i in range(3)}
+    leader = LeaderNode(Node(0, 0, ts[0]),
+                        {b: blob_layer(d) for b, d in blobs.items()},
+                        {1: {b: LayerMeta() for b in blobs}})
+    dest = ReceiverNode(Node(1, 0, ts[1]), {}, boot_cfg=TINY)
+    try:
+        dest.announce()
+        assert leader.start_distribution().get(timeout=60.0)
+        assert leader.ready().get(timeout=60.0)
+        dest.ready().get(timeout=60.0)
+        assert set(leader.boot_ready().get(timeout=60.0)) == {1}
+        assert dest._boot_stager.staged_count == len(blobs) == 7
+        assert dest.serve_quiet_s() == float("inf")  # nothing asked yet
+        requester = GenRequester(ts[2])
+        quiet = []
+        real = dest._serve_generate_req
+
+        def watched(msg, t_arrived):
+            quiet.append(dest.serve_quiet_s())
+            return real(msg, t_arrived)
+
+        dest._serve_generate_req = watched
+        try:
+            prompt = [(7 * i) % TINY.vocab for i in range(20)]
+            got = requester.request(1, prompt, max_new=6, timeout=60.0)
+        finally:
+            requester.close()
+        want = generate.generate(params, jnp.asarray([prompt], jnp.int32),
+                                 TINY, 6)
+        assert got == np.asarray(want)[0].tolist()
+        assert quiet == [0.0] and 0.0 < dest.serve_quiet_s() < 60.0
+        served, = [s["fields"] for s in trace.spans()
+                   if s["name"] == "serve.generate"]
+        # 25 positions through five rings of 8 rows and one grown cache
+        assert served["kv_rows"] == 5 * 8 + 25
+        assert served["swa_evicted"] == 5 * (25 - 8)
+        assert served["moe_slots"] == served["moe_held"] == 25 * 4 * 4
+        events = [{"ph": "X", "name": s["name"],
+                   "args": {"fields": s["fields"]}} for s in trace.spans()]
+        assert cli_trace.cache_row_totals(events) == {
+            "spans": 1, "kv_rows": 65, "swa_evicted": 85}
+        staged = [s["fields"]["kind"] for s in trace.spans()
+                  if s["name"] == "decode.stage"]
+        assert sorted(staged) == ["dense_sliding"] * 2 + ["head"] + [
+            "routed_full"] + ["routed_sliding"] * 3
+    finally:
+        leader.close()
+        dest.close()
+        for t in ts.values():
+            t.close()
+
+
+def _pod_conf(tmp_path):
+    from distributed_llm_dissemination_tpu.core import config as pcfg
+
+    path = tmp_path / "pod.json"
+    path.write_text(json.dumps({
+        "Model": "tiny-trinity", "ModelSeed": 0,
+        "Nodes": [{"Id": 0, "Addr": "0", "IsLeader": True,
+                   "Sources": {"1": 0}, "InitialLayers": {"1": {"0": {}}}},
+                  {"Id": 1, "Addr": "1", "InitialLayers": {}}],
+        "Assignment": {"1": {"0": {}}}, "LayerSize": 1,
+        "Mesh": {"AxisNames": ["pp"], "AxisSizes": [2],
+                 "PipelineAxis": "pp"}}))
+    return pcfg.read_json(str(path))
+
+
+def _refused_by_sharded(tmp_path):
+    from distributed_llm_dissemination_tpu.models import sharded
+
+    with pytest.raises(family.FamilyNotSupported) as e:
+        sharded.build_pp_forward(TINY, None, "pp")
+    return str(e.value), "models/sharded.py"
+
+
+def _refused_by_train_ckpt(tmp_path):
+    from distributed_llm_dissemination_tpu.models import train_ckpt
+
+    with pytest.raises(family.FamilyNotSupported) as e:
+        train_ckpt.restore_train_state(str(tmp_path), TINY, None)
+    return str(e.value), "models/train_ckpt.py"
+
+
+def _refused_by_podrun(tmp_path):
+    from distributed_llm_dissemination_tpu.cli.podrun import run_pod
+
+    with pytest.raises(SystemExit) as e:
+        run_pod(_pod_conf(tmp_path), boot="tiny-trinity")
+    assert e.value.code not in (0, None)
+    return str(e.value), "cli.podrun.run_pod"
+
+
+def _refused_by_train(tmp_path):
+    from distributed_llm_dissemination_tpu.cli import train
+
+    _pod_conf(tmp_path)
+    with pytest.raises(SystemExit) as e:
+        train.main(["-f", str(tmp_path / "pod.json"), "-steps", "1"])
+    assert e.value.code not in (0, None)
+    return str(e.value), "cli.train"
+
+
+@pytest.mark.parametrize("refused", [
+    _refused_by_sharded, _refused_by_train_ckpt, _refused_by_podrun,
+    _refused_by_train], ids=lambda f: f.__name__[12:])
+def test_an_entry_point_that_has_not_learnt_the_family_refuses_it_by_name(
+        refused, tmp_path, cpu_devices):
+    said, here = refused(tmp_path)
+    assert f"{here} cannot run 'tiny-trinity' of the trinity family" in said
+    assert "(it knows llama): " in said and len(said.split(": ", 1)[1]) > 40
+
+
+def test_hf_config_from_dir_refuses_the_family_by_name(tmp_path):
+    from distributed_llm_dissemination_tpu.models import hf
+
+    (tmp_path / "config.json").write_text(json.dumps(
+        {"architectures": ["AfmoeForCausalLM"], "hidden_size": 2048}))
+    with pytest.raises(family.FamilyNotSupported,
+                       match="cannot load the trinity family"):
+        hf.config_from_dir(str(tmp_path))
+
+
+def test_cli_main_knows_the_family_by_its_configurations_names():
+    from distributed_llm_dissemination_tpu.cli.main import boot_config
+
+    assert boot_config("tiny-trinity") is TINY
+    assert "tiny-trinity" in family.known()
