@@ -22,8 +22,8 @@ Mapping:
   the wall clock by the ``"span counters"`` record's clock pair; the
   ``decode.stage`` spans' ``fast_bytes`` / ``slow_bytes``, the
   ``boot.assemble`` spans' ``kinds``, the ``serve.generate`` spans'
-  ``moe_slots`` / ``moe_held`` / ``moe_touched`` and ``kv_rows`` /
-  ``swa_evicted`` and the ``fabric.publish`` spans' ``bytes`` / ``host_copy_bytes`` / ``pieces``
+  ``moe_slots`` / ``moe_held`` / ``moe_touched`` / ``moe_rows`` and
+  ``kv_rows`` / ``swa_evicted`` and the ``fabric.publish`` spans' ``bytes`` / ``host_copy_bytes`` / ``pieces``
   are added up and printed on stderr, and beside them the counters
   ``wire.buf.reused_bytes`` / ``wire.buf.fresh_bytes`` and
   ``wire.pace.job_bytes`` / ``wire.pace.wait_ms`` of the
@@ -295,6 +295,16 @@ def routed_slot_totals(events: List[dict]) -> dict:
     adds nothing) added up.  Empty for a family that routes nothing."""
     return _field_totals(events, "serve.generate",
                          ("moe_slots", "moe_held", "moe_touched"))
+
+
+def expert_row_totals(events: List[dict]) -> dict:
+    """The expert rows the logs' served requests computed
+    (``models/trinity.py``): the ``serve.generate`` slices' ``moe_rows``
+    added up — per routed layer and call ``b·s·experts_held`` where every
+    held expert ran over every position, the grouped loop's items times
+    its rows an item where each ran over its own slots.  Empty for a
+    family that counts none."""
+    return _field_totals(events, "serve.generate", ("moe_rows",))
 
 
 def cache_row_totals(events: List[dict]) -> dict:
@@ -910,10 +920,13 @@ def main(argv: list[str] | None = None) -> int:
               file=sys.stderr)
     routed = routed_slot_totals(events)
     if routed:
+        computed = expert_row_totals(events)
+        computed = (f" ({computed['moe_rows']} expert rows computed)"
+                    if computed else "")
         print("serve.generate routed {moe_slots} slots, {moe_held} of them "
-              "to experts held here, over {moe_touched} expert-reads a "
-              "gathered dispatch would make ({spans} spans)"
-              .format(**routed), file=sys.stderr)
+              "to experts held here{computed}, over {moe_touched} "
+              "expert-reads a gathered dispatch would make ({spans} spans)"
+              .format(**routed, computed=computed), file=sys.stderr)
     rows = cache_row_totals(events)
     if rows:
         print("serve.generate left {kv_rows} K/V rows in its caches, "

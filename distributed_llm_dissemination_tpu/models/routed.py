@@ -1,6 +1,8 @@
-"""The sigmoid router and a rank's share of a routed block by dense
-dispatch, for the families that have them (``models/joyai.py``,
-``models/trinity.py``).
+"""The sigmoid router and a rank's share of a routed block, for the
+families that have them (``models/joyai.py``, ``models/trinity.py``):
+by dense dispatch (``experts``) or by grouped dispatch (``grouped``),
+whichever computes fewer expert rows at the call's static shape
+(``dispatch``).
 
     s = sigmoid(float32(x)·float32(gate))     over EVERY router output
     pick = top-k of (s + bias)                the bias picks, does not weigh
@@ -11,8 +13,9 @@ dispatch, for the families that have them (``models/joyai.py``,
                                               picked an absent expert adds
                                               nothing
 
-A configuration gives ``top_k``, ``route_scale``, ``experts_held`` and
-``expert_first`` under the same names in both families; the leaves are
+A configuration gives ``n_experts`` (the router's outputs), ``top_k``,
+``route_scale``, ``experts_held`` and ``expert_first`` under the same
+names in both families; the leaves are
 ``gate`` and the selection bias (whose name the caller gives: the
 checkpoints differ) and the held experts' stacks ``ew1``, ``ew3``,
 ``ew2``.  Products with weights are ``lfm2._mm``'s (the activations as
@@ -67,6 +70,63 @@ def experts(p, xn, w, held):
     return jnp.einsum("besd,bse->bsd", out, gate, precision=_EXACT)
 
 
+def _rows_an_item(n, cfg):
+    """``C``, the rows of one item of ``grouped``'s loop over ``n``
+    slots: the rows a held expert expects, ``n / n_experts``, rounded up
+    to a multiple of 128."""
+    return -(-n // (cfg.n_experts * 128)) * 128
+
+
+def grouped(p, xn, idx, w, cfg):
+    """``experts``' output (float32) by grouped dispatch, and the expert
+    rows it computed (an int32 scalar): each held expert runs over the
+    slots that picked it alone.
+
+    The call's ``[b, s, top_k]`` slots are sorted by their local expert,
+    stably, the slots of experts held elsewhere last; each expert's run
+    of sorted rows is cut into items of ``C`` rows, and a ``while_loop``
+    over the items gathers an item's rows of ``xn``, runs its expert's
+    ``swiglu`` on them, weighs each row by its slot's ``w`` (0 past the
+    run's end) and adds it into its position's row.  Every held slot is
+    computed once; nothing is dropped.  ``C`` is ``_rows_an_item``; the
+    loop's length is a device scalar under a static bound of
+    ``ceil(b·s·top_k / C) + experts_held`` items."""
+    b, s, d = xn.shape
+    n, held = idx.size, cfg.experts_held
+    chunk = _rows_an_item(n, cfg)
+    local = idx.reshape(n) - cfg.expert_first
+    group = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.bincount(group, length=held + 1)[:held]
+    starts = jnp.cumsum(sizes) - sizes
+    chunks = -(-sizes // chunk)
+    ends = jnp.cumsum(chunks)  # one past each expert's last item
+    t = jnp.arange(-(-n // chunk) + held)
+    of = jnp.minimum(jnp.sum(t[:, None] >= ends, axis=1), held - 1)
+    first = starts[of] + (t - ends[of] + chunks[of]) * chunk
+    stop = starts[of] + sizes[of]
+    # padded so that an item's C rows never run past the end
+    token = jnp.pad(order // idx.shape[-1], (0, chunk))
+    weight = jnp.pad(w.reshape(n)[order], (0, chunk))
+    rows = xn.reshape(b * s, d)
+
+    def item(carry):
+        i, out = carry
+        at = jax.lax.dynamic_slice_in_dim(token, first[i], chunk)
+        live = first[i] + jnp.arange(chunk) < stop[i]
+        wt = jnp.where(live, jax.lax.dynamic_slice_in_dim(
+            weight, first[i], chunk), 0.0)
+        y = swiglu(rows[at][None], *(
+            jax.lax.dynamic_index_in_dim(p[name], of[i], keepdims=False)
+            for name in ("ew1", "ew3", "ew2")))[0]
+        return i + 1, out.at[at].add(y * wt[:, None])
+
+    _, out = jax.lax.while_loop(
+        lambda carry: carry[0] < ends[-1], item,
+        (jnp.int32(0), jnp.zeros((b * s, d), jnp.float32)))
+    return out.reshape(b, s, d), ends[-1] * chunk
+
+
 def counts(idx, held):
     """What a call routed: ``moe_slots`` (positions x top_k), ``moe_held``
     (slots whose expert is here), ``moe_touched`` (distinct held experts
@@ -77,7 +137,24 @@ def counts(idx, held):
             "moe_touched": jnp.sum(held.any((0, 1, 2)), dtype=jnp.int32)}
 
 
+def dispatch(p, xn, idx, w, mine, cfg):
+    """The held experts' part (float32) and the expert rows it computed,
+    by the dispatch that computes fewer rows at this call's static shape:
+    ``experts`` (``b·s·experts_held`` rows) unless ``grouped``'s bound,
+    ``(ceil(b·s·top_k / C) + experts_held)·C`` rows, is below that.  A
+    call of 128 positions or fewer (a decode step, JoyAI's and Trinity's
+    boot forward of 16) is always dense; at Trinity's cell (128 router
+    outputs, 16 held, top-8) a call turns grouped from 265 positions on.
+    ``mine`` is ``held(idx, cfg)``."""
+    b, s, _ = xn.shape
+    chunk = _rows_an_item(idx.size, cfg)
+    dense = b * s * cfg.experts_held
+    if (-(-idx.size // chunk) + cfg.experts_held) * chunk < dense:
+        return grouped(p, xn, idx, w, cfg)
+    return experts(p, xn, w, mine), jnp.asarray(dense, jnp.int32)
+
+
 def routed_part(p, xn, idx, w, cfg):
-    """``experts`` over the whole call, and its ``counts``."""
+    """``dispatch`` over the whole call, and its ``counts``."""
     mine = held(idx, cfg)
-    return experts(p, xn, w, mine), counts(idx, mine)
+    return dispatch(p, xn, idx, w, mine, cfg)[0], counts(idx, mine)

@@ -45,9 +45,12 @@ here stands in for the other ranks or their exchange.
 block of ``BLOCK`` queries at a time (``_attend_blocks``: a scan over
 query blocks, an online softmax over the key blocks each may see — a
 layer with a window visits those inside its band alone); up to ``BLOCK``
-positions it is the one plain softmax.  The feed-forward runs ``BLOCK``
-positions at a time too (``_in_blocks``), so no array of a layer grows
-with more than the sequence's length times a width.
+positions it is the one plain softmax.  The dense feed-forward and the
+shared expert run ``BLOCK`` positions at a time too
+(``_swiglu_in_blocks``); the held experts run over the whole call by
+``routed.dispatch``, each over the slots that picked it alone in a long
+prompt (``routed.grouped``), every one over every position in a short
+call (``routed.experts``).
 
 **Serving state**, float32 as ``models/lfm2.py``'s: ``{"k", "v"}: [b,
 rows, n_kv_heads, head_dim]`` a layer — ``rows = max_len`` in a full
@@ -62,7 +65,9 @@ POSITION 0 (``generate``'s only other use): it attends inside itself
 under the band and leaves its last ``rows`` positions behind.  Counted a
 call and layer, over the sequences of the batch: ``kv_rows`` (rows the
 state holds that it did not before) and ``swa_evicted`` (positions
-written over, or never kept: what a ring's layer no longer holds).
+written over, or never kept: what a ring's layer no longer holds);
+in a routed layer also ``moe_rows`` (the expert rows computed, padding
+included: ``_feed_forward``).
 
 Arithmetic as ``models/lfm2.py`` and ``models/joyai.py``: float32 between
 the products and INTO them (``lfm2._mm``'s two ``cfg.dtype`` terms),
@@ -88,7 +93,8 @@ from .mla import _rms
 HF_ARCHITECTURE = "Afmoe"  # models/hf.py refuses it by name
 _EXACT = jax.lax.Precision.HIGHEST
 # Queries (and keys) a block of the blockwise attention, and positions a
-# block of the feed-forward: a power of two, fixed here.
+# block of the dense feed-forward and the shared expert: a power of two,
+# fixed here.
 BLOCK = 512
 
 
@@ -346,45 +352,39 @@ def _attention(p, xn, positions, cache, window: Optional[int],
 
 # ------------------------------------------------------------- feed-forward
 
-def _in_blocks(fn, *arrays):
-    """``fn(*arrays)`` — every array ``[b, s, ...]``, ``fn`` a function of
-    each position alone — ``BLOCK`` positions at a time where the
-    sequence has more."""
-    s = arrays[0].shape[1]
+def _swiglu_in_blocks(x, w1, w3, w2):
+    """``routed.swiglu`` over ``x [b, s, d]`` — a function of each
+    position alone — ``BLOCK`` positions at a time where the sequence has
+    more."""
+    b, s, d = x.shape
     if s <= BLOCK:
-        return fn(*arrays)
+        return routed.swiglu(x, w1, w3, w2)
     n = -(-s // BLOCK)
-
-    def blocks(a):
-        a = jnp.pad(a, ((0, 0), (0, n * BLOCK - s)) + ((0, 0),)
-                    * (a.ndim - 2))
-        return jnp.moveaxis(a.reshape(a.shape[0], n, BLOCK, *a.shape[2:]),
-                            1, 0)
-
-    out = jax.lax.map(lambda xs: fn(*xs), tuple(blocks(a) for a in arrays))
-    return jnp.moveaxis(out, 0, 1).reshape(
-        out.shape[1], n * BLOCK, *out.shape[3:])[:, :s]
+    x = jnp.pad(x, ((0, 0), (0, n * BLOCK - s), (0, 0)))
+    out = jax.lax.map(lambda xb: routed.swiglu(xb, w1, w3, w2),
+                      jnp.moveaxis(x.reshape(b, n, BLOCK, d), 1, 0))
+    return jnp.moveaxis(out, 0, 1).reshape(b, n * BLOCK, d)[:, :s]
 
 
 def _feed_forward(p, xn, cfg: TrinityConfig):
-    """(output, counters): dense, or the router over the whole call
-    (``moe_touched`` is a call's) and then this rank's experts and the
-    shared one a block of positions at a time."""
+    """(output, counters): dense a block of positions at a time, or the
+    router over the whole call (``moe_touched`` is a call's), this rank's
+    experts by ``routed.dispatch`` over the whole call (dense for a
+    decode step or a short prompt, grouped for a long prompt) and the
+    shared one a block at a time.  ``moe_rows`` counts the expert rows
+    computed: ``b·s·experts_held`` dense, the grouped loop's items times
+    its rows an item."""
     if "gate" not in p:
         with jax.named_scope("model.ffn"):
-            return _in_blocks(lambda x: routed.swiglu(
-                x, p["w1"], p["w3"], p["w2"]), xn), {}
+            return _swiglu_in_blocks(xn, p["w1"], p["w3"], p["w2"]), {}
     with jax.named_scope("model.moe.route"):
         idx, w = routed.route(p, xn, cfg, "expert_bias")
         mine = routed.held(idx, cfg)
-
-    def block(x, w, mine):
-        with jax.named_scope("model.moe.experts"):
-            y = routed.experts(p, x, w, mine)
-        with jax.named_scope("model.moe.shared"):
-            return y + routed.swiglu(x, p["sw1"], p["sw3"], p["sw2"])
-
-    return _in_blocks(block, xn, w, mine), routed.counts(idx, mine)
+    with jax.named_scope("model.moe.experts"):
+        y, rows = routed.dispatch(p, xn, idx, w, mine, cfg)
+    with jax.named_scope("model.moe.shared"):
+        y = y + _swiglu_in_blocks(xn, p["sw1"], p["sw3"], p["sw2"])
+    return y, {**routed.counts(idx, mine), "moe_rows": rows}
 
 
 # ------------------------------------------------------------------- blocks

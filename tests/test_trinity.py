@@ -510,6 +510,172 @@ def test_the_slot_counters_equal_the_references_picks():
     assert 0 < want["moe_held"] < want["moe_slots"] == 16 * 4 * 4
 
 
+# ------------------------------------------- grouped against dense dispatch
+
+
+def _routing(case: str, cfg, b: int, s: int, rng):
+    """``(idx, w)`` of a call: the picks of ``case`` among the router's
+    ``n_experts`` outputs, distinct a position, with positive weights."""
+    n_out, top_k = cfg.n_experts, cfg.top_k
+    mine = np.arange(cfg.expert_first, cfg.expert_first + cfg.experts_held)
+    others = np.setdiff1d(np.arange(n_out), mine)
+    pool = {"random": np.arange(n_out), "all-held": mine,
+            "none-held": others, "one-expert": others}[case]
+    idx = np.stack([rng.choice(pool, top_k, replace=False)
+                    for _ in range(b * s)])
+    if case == "one-expert":  # every position's first pick: one expert
+        idx[:, 0] = mine[1]
+    w = rng.uniform(0.1, 1.0, (b * s, top_k))
+    return (jnp.asarray(idx.reshape(b, s, top_k), jnp.int32),
+            jnp.asarray(w.reshape(b, s, top_k), jnp.float32))
+
+
+@pytest.mark.parametrize("case,b,s", [
+    ("random", 1, 600), ("all-held", 1, 300), ("none-held", 1, 300),
+    ("one-expert", 1, 700), ("random", 1, 1037), ("random", 2, 333)],
+    ids=["random", "all-held", "none-held", "one-expert-most-items",
+         "length-no-multiple-of-block-or-chunk", "batch-of-two"])
+def test_grouped_dispatch_equals_dense_dispatch(case, b, s):
+    """``routed.grouped`` against ``routed.experts`` on the same inputs
+    (float32, one rank's six experts of sixteen, top-4): the same output
+    within float32's summation order, and as many rows as its items hold
+    — one item a started ``C`` rows of each expert's held slots."""
+    cfg = dataclasses.replace(F32, name="grouped", experts_held=6,
+                              expert_first=5)
+    rng = np.random.default_rng(len(case) * 100 + s)
+    d, fe, e = cfg.d_model, cfg.d_expert, cfg.experts_held
+    p = {name: jnp.asarray(rng.standard_normal(shape).astype(np.float32)
+                           * shape[-2] ** -0.5)
+         for name, shape in (("ew1", (e, d, fe)), ("ew3", (e, d, fe)),
+                             ("ew2", (e, fe, d)))}
+    xn = jnp.asarray(rng.standard_normal((b, s, d)).astype(np.float32))
+    idx, w = _routing(case, cfg, b, s, rng)
+    with jax.default_matmul_precision("highest"):
+        want = routed.experts(p, xn, w, routed.held(idx, cfg))
+        got, rows = jax.jit(lambda p, xn, idx, w: routed.grouped(
+            p, xn, idx, w, cfg))(p, xn, idx, w)
+    chunk = -(-(b * s * cfg.top_k) // (cfg.n_experts * 128)) * 128
+    per_expert = [int((np.asarray(idx) == 5 + j).sum()) for j in range(e)]
+    assert int(rows) == sum(-(-n // chunk) for n in per_expert) * chunk
+    if case == "none-held":
+        assert int(rows) == 0 and not np.asarray(got).any()
+        return
+    assert rel(got, want) < 1e-6
+    if case == "one-expert":  # one expert's b·s slots: the most items
+        assert int(rows) >= -(-(b * s) // chunk) * chunk
+
+
+def _grouped_rows_at_most(positions: int, cfg) -> int:
+    """The rows ``routed.grouped``'s loop may compute over a call of
+    ``positions``: ``ceil(n / C) + experts_held`` items of ``C`` rows,
+    ``n`` the call's slots and ``C`` their share of one router output,
+    rounded up to 128."""
+    n = positions * cfg.top_k
+    chunk = -(-n // (cfg.n_experts * 128)) * 128
+    return (-(-n // chunk) + cfg.experts_held) * chunk
+
+
+def test_the_dispatch_is_chosen_from_the_calls_length():
+    """A call runs every held expert over every position (``moe_rows`` =
+    positions x experts held) unless the grouped loop's bound of rows is
+    below that, and then each held expert over the slots that picked it
+    (``moe_rows`` = the grouped items' rows); the output is the same
+    function either side.  At the tiny preset (16 of 16 experts held,
+    top-4) the turn is at 177 positions: 1, 16 and 176 are dense, 177 and
+    513 grouped."""
+    cfg = F32
+    params, _, _ = seeded(cfg, 3, batch=1)
+    p = jax.tree.map(lambda a: a[0], params["layers"]["routed_sliding"])
+    rng = np.random.default_rng(3)
+    assert _grouped_rows_at_most(176, cfg) >= 176 * 16
+    assert _grouped_rows_at_most(177, cfg) < 177 * 16
+    for s in (1, 16, 176, 177, 513):
+        xn = jnp.asarray(rng.standard_normal(
+            (1, s, cfg.d_model)).astype(np.float32))
+        with jax.default_matmul_precision("highest"):
+            got, counted = jax.jit(lambda p, xn: trinity._feed_forward(
+                p, xn, cfg))(p, xn)
+            idx, w = routed.route(p, xn, cfg, "expert_bias")
+            want = routed.experts(p, xn, w, routed.held(idx, cfg)) + (
+                routed.swiglu(xn, p["sw1"], p["sw3"], p["sw2"]))
+        assert rel(got, want) < EXACT
+        here = np.bincount(np.asarray(idx).ravel(), minlength=16)
+        assert int(counted["moe_held"]) == here.sum() == s * 4
+        chunk = -(-(s * 4) // (16 * 128)) * 128
+        grouped = sum(-(-n // chunk) for n in here) * chunk
+        assert int(counted["moe_rows"]) == (
+            s * 16 if s < 177 else grouped)
+    assert grouped < 513 * 16
+
+
+@pytest.mark.parametrize("s", [16, 300], ids=["short-dense", "long-grouped"])
+def test_joyai_takes_the_same_rule_through_the_shared_router(s):
+    """``routed.routed_part`` — JoyAI's routed block — goes through the
+    same ``routed.dispatch``: at 16 positions its program holds no loop
+    (the dense dispatch, whose StableHLO ``tests/test_family.py`` pins),
+    at 300 the grouped loop (16 of 16 experts held, top-4: past the
+    tiny presets' turn at 177); the output is ``routed.experts``' either
+    way."""
+    from distributed_llm_dissemination_tpu.models import joyai
+
+    cfg = joyai.CONFIGS["tiny-joyai"]
+    rng = np.random.default_rng(s)
+    d, fe, e = cfg.d_model, cfg.d_expert, cfg.experts_held
+    p = {name: jnp.asarray(rng.standard_normal(shape).astype(np.float32)
+                           * shape[-2] ** -0.5)
+         for name, shape in (("ew1", (e, d, fe)), ("ew3", (e, d, fe)),
+                             ("ew2", (e, fe, d)))}
+    xn = jnp.asarray(rng.standard_normal((1, s, d)).astype(np.float32))
+    idx, w = _routing("random", cfg, 1, s, rng)
+
+    def part(p, xn, idx, w):
+        return routed.routed_part(p, xn, idx, w, cfg)
+
+    with jax.default_matmul_precision("highest"):
+        (got, counted), want = jax.jit(part)(p, xn, idx, w), routed.experts(
+            p, xn, w, routed.held(idx, cfg))
+    assert rel(got, want) < 1e-6
+    assert int(counted["moe_slots"]) == s * cfg.top_k
+    assert ("while" in str(jax.make_jaxpr(part)(p, xn, idx, w))) is (
+        s > 176)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_a_prefill_past_the_block_is_grouped_and_is_the_references(dtype):
+    """The tiny preset at the real ``BLOCK``: a prompt of 600 positions
+    takes the grouped dispatch in its prefill, then three decode steps
+    the dense one.  Float32: the logits are the reference's within
+    float32's summation order; bfloat16 as served: within the harness's
+    3%, and the served tokens are the reference's argmax wherever its
+    margin is clear."""
+    cfg = dataclasses.replace(TINY if dtype == "bfloat16" else F32,
+                              name=f"past-the-block-{dtype}")
+    params, model, toks = seeded(cfg, 5, batch=1, length=603)
+    if dtype == "bfloat16":
+        params = jax.tree.map(lambda a: a.astype(jnp.bfloat16), params)
+        model = {b: {k: np.asarray(jnp.asarray(v, jnp.bfloat16),
+                                   np.float32) for k, v in leaves.items()}
+                 for b, leaves in model.items()}
+    want = ref_logits(cfg, model, toks)[:, 599:]
+    got, _, total = served(cfg, params, toks, 600)
+    routed_layers = cfg.n_layers - cfg.n_dense
+    assert total["moe_slots"] == 603 * routed_layers * cfg.top_k
+    # the prefill: 2,400 held slots a layer in items of C = 256 rows,
+    # between ten and 26 items; the decode steps: all 16 experts a step
+    prefill = total["moe_rows"] - routed_layers * 3 * 16
+    assert prefill % 256 == 0
+    assert routed_layers * 10 * 256 <= prefill <= routed_layers * 26 * 256
+    assert prefill < routed_layers * 600 * 16  # the dense dispatch's
+    if dtype == "float32":
+        assert rel(got, want) < EXACT
+        return
+    assert rel(got, want) < TOLERANCE
+    top2 = np.sort(want[0], axis=-1)[:, -2:]
+    clear = top2[:, 1] - top2[:, 0] > 0.05
+    assert clear.sum() >= 2
+    assert (got[0].argmax(-1) == want[0].argmax(-1))[clear].all()
+
+
 # ---------------------------------------- generate, the boot, the refusals
 
 
@@ -620,6 +786,11 @@ def test_a_streamed_boot_over_the_inmem_transport_serves_through_its_rings():
                    "args": {"fields": s["fields"]}} for s in trace.spans()]
         assert cli_trace.cache_row_totals(events) == {
             "spans": 1, "kv_rows": 65, "swa_evicted": 85}
+        # 25 positions of 20 and 1 are dense dispatch: all 16 experts of
+        # the four routed layers over each
+        assert served["moe_rows"] == 25 * 16 * 4
+        assert cli_trace.expert_row_totals(events) == {
+            "spans": 1, "moe_rows": 1600}
         staged = [s["fields"]["kind"] for s in trace.spans()
                   if s["name"] == "decode.stage"]
         assert sorted(staged) == ["dense_sliding"] * 2 + ["head"] + [
@@ -629,6 +800,36 @@ def test_a_streamed_boot_over_the_inmem_transport_serves_through_its_rings():
         dest.close()
         for t in ts.values():
             t.close()
+
+
+@pytest.mark.parametrize("rows", [True, False],
+                         ids=["trinity", "a-family-without-rows"])
+def test_cli_trace_prints_the_expert_rows_beside_the_held_slots(
+        rows, tmp_path, capsys):
+    """``cli.trace``'s ``serve.generate`` line says how many expert rows
+    the held experts computed where the spans carry ``moe_rows``, and
+    nothing of rows where they do not (JoyAI, LFM2, LongCat)."""
+    import io
+
+    from distributed_llm_dissemination_tpu.cli import trace as cli_trace
+    from distributed_llm_dissemination_tpu.utils.logging import JsonLogger
+
+    counted = {"moe_slots": 400, "moe_held": 400, "moe_touched": 97}
+    for more in ({"moe_rows": 1600}, {"moe_rows": 600}):
+        with trace.span("serve.generate", node=1,
+                        **counted, **(more if rows else {})):
+            pass
+    buf = io.StringIO()
+    trace.dump_spans(JsonLogger(node="1", stream=buf))
+    log = tmp_path / "dest.jsonl"
+    log.write_text(buf.getvalue())
+    assert cli_trace.main([str(log), "-o", str(tmp_path / "t.json")]) == 0
+    line, = [text for text in capsys.readouterr().err.splitlines()
+             if text.startswith("serve.generate routed")]
+    assert line.startswith("serve.generate routed 800 slots, 800 of them "
+                           "to experts held here")
+    assert ("(2200 expert rows computed)" in line) is rows
+    assert ("expert rows" in line) is rows and "(2 spans)" in line
 
 
 def _pod_conf(tmp_path):
